@@ -1,7 +1,7 @@
 """Multi-replica router microbenchmark: the ROADMAP scale-out numbers.
 
-CPU-runnable (the relay-down policy decode_bench.py set): a
-repeated-system-prompt workload — every request carries one of a few
+CPU-runnable, like decode_bench.py (its times on a CPU are not device
+metrics): a repeated-system-prompt workload — every request carries one of a few
 shared system prompts plus a small unique tail — through the real
 subprocess serving tier (``cli.router``'s building blocks: one
 ``serve/router.py`` Router over N ``serve/replica.py`` workers), swept
@@ -237,7 +237,12 @@ def run_sweep(n_replicas: int, args, spec_path: str) -> dict:
         sorted(step_p50s)[len(step_p50s) // 2] if step_p50s else None
     )
     router.shutdown()
+    # Named by the replicas themselves (their ready lines): this parent
+    # never asks jax for a device — the chips belong to the workers.
+    device = next((l.device for l in router.links if l.device), None) or {}
     return {
+        "device": f"{device.get('platform')}:{device.get('kind')}",
+        "device_kind": device.get("kind"),
         "replicas": n_replicas,
         "requests": len(reqs),
         "answered": len(answered),
@@ -253,7 +258,10 @@ def run_sweep(n_replicas: int, args, spec_path: str) -> dict:
         "measured_step_p50_ms": (
             round(step_p50_s * 1e3, 6) if step_p50_s else None
         ),
-        "roofline_ratio": roofline_ratio(step_bytes, step_p50_s or 0.0),
+        # None on a device with no entry in the peak table (the CPU).
+        "roofline_ratio": roofline_ratio(
+            step_bytes, step_p50_s or 0.0, device.get("kind")
+        ),
         "per_replica": per_replica,
     }
 
@@ -567,10 +575,14 @@ def main() -> None:
                         "('' = print them to stderr)")
     args = p.parse_args()
 
+    # This parent uses JAX for host work only (cost-model traces, the
+    # upgrade soak's checkpoints): pin ITS platform to the CPU so every chip
+    # stays free for the replica processes, one process per chip.
+    # jax.config does not reach the children's environment.
     import jax
 
-    dev = jax.devices()[0]
-    device = f"{dev.platform}:{dev.device_kind}"
+    jax.config.update("jax_platforms", "cpu")
+    device = None  # named by the first sweep's replicas
     fd, spec_path = tempfile.mkstemp(suffix=".json")
     with os.fdopen(fd, "w") as f:
         json.dump(SPEC, f)
@@ -585,9 +597,7 @@ def main() -> None:
             assert result["measured_step_p50_ms"], (
                 f"no measured pool-step p50 from the fleet: {result}"
             )
-            assert result["roofline_ratio"], (
-                f"roofline_ratio missing: {result}"
-            )
+            device = device or result["device"]
             hit_rates = [
                 r["prefix_hit_rate"]
                 for r in result["per_replica"].values()

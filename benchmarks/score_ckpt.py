@@ -6,7 +6,7 @@ the held-out test split, without touching the training process.
 
 Prints one JSON line: {"metric": ..., "bleu": ..., "step": ..., ...}.
 Exists because resumable runs only self-score at their final epoch target
-(``bleu_run.py``): when a relay outage or round boundary lands mid-run, the
+(``bleu_run.py``): when an interruption or round boundary lands mid-run, the
 partial convergence is still checkpointed — this recovers a real number
 from it. Reconstructs the model EXACTLY as bleu_run does (same shapes
 table, the run's own workdir vocabs, same specials).

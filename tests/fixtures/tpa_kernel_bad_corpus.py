@@ -12,7 +12,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_ARB = pltpu.TPUCompilerParams(dimension_semantics=("arbitrary",))
+_ARB = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 # -- TPA301: bf16 accumulator scratch (init/flush discipline is correct) ----

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_ARB = pltpu.TPUCompilerParams(dimension_semantics=("arbitrary",))
+_ARB = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 # -- twin of acc_bf16: accumulator widened to fp32 --------------------------

@@ -72,42 +72,37 @@ class TestProfiler:
 
 
 class TestCompilationCache:
-    def test_sets_config_and_persists(self, tmp_path):
-        from transformer_tpu.utils import enable_compilation_cache
+    @pytest.fixture
+    def restore_cache_config(self):
+        from jax.experimental.compilation_cache import compilation_cache
 
         old_dir = jax.config.jax_compilation_cache_dir
         old_min = jax.config.jax_persistent_cache_min_compile_time_secs
-        old_size = jax.config.jax_persistent_cache_min_entry_size_bytes
-        try:
-            d = enable_compilation_cache(str(tmp_path / "cache"))
-            assert d == str(tmp_path / "cache")
-            assert jax.config.jax_compilation_cache_dir == d
-            # Sub-second compiles are cheaper to redo than to hash + load;
-            # drop both floors here so the smoke jit below persists.
-            assert jax.config.jax_persistent_cache_min_compile_time_secs == 1.0
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-            x = np.arange(8.0, dtype=np.float32)
-            np.testing.assert_allclose(
-                jax.jit(lambda v: v * 3.0 + 1.0)(x), x * 3.0 + 1.0
-            )
-            assert os.path.isdir(d) and os.listdir(d)  # entry written
-        finally:
-            jax.config.update("jax_compilation_cache_dir", old_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", old_min)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", old_size)
+        yield
+        jax.config.update("jax_compilation_cache_dir", old_dir)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", old_min)
+        compilation_cache.reset_cache()
 
-    def test_env_override(self, tmp_path, monkeypatch):
+    def test_env_dir_is_left_alone(self, tmp_path, monkeypatch, restore_cache_config):
+        """With JAX_COMPILATION_CACHE_DIR set, JAX reads it; the code sets
+        no directory of its own (here: the config keeps what it had)."""
         from transformer_tpu.utils import enable_compilation_cache
 
-        old_dir = jax.config.jax_compilation_cache_dir
-        old_min = jax.config.jax_persistent_cache_min_compile_time_secs
-        try:
-            monkeypatch.setenv("TRANSFORMER_TPU_JAX_CACHE", str(tmp_path / "env"))
-            assert enable_compilation_cache() == str(tmp_path / "env")
-        finally:
-            jax.config.update("jax_compilation_cache_dir", old_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", old_min)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+        jax.config.update("jax_compilation_cache_dir", "sentinel")
+        assert enable_compilation_cache() == str(tmp_path / "env")
+        assert jax.config.jax_compilation_cache_dir == "sentinel"
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 1.0
+
+    def test_default_is_fixed_path_in_checkout(self, monkeypatch, restore_cache_config):
+        from transformer_tpu.utils import enable_compilation_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        d = enable_compilation_cache()
+        assert d == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == d
+        assert enable_compilation_cache() == d  # no pid/time in the name
 
 
 class TestStepTimer:

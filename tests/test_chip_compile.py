@@ -1,0 +1,157 @@
+"""AOT compiles for a described (not attached) TPU v5e: the kernels of the
+main path, and the serving step that fuses them, at real widths.
+
+Interpret mode and the abstract kernel verifier (``analysis kernels``) both
+passed a paged-decode kernel the chip's compiler refused, so the compiler is
+asked directly. The TPU compiler ships with the installed libtpu and needs
+no chip: ``get_topology_desc`` describes a ``v5e:2x2`` host and ``jit(...)
+.lower(shapes).compile()`` raises whatever the chip would raise. Nothing
+runs, so these say nothing about results or times.
+
+Only one process may load the TPU library, so the topology is described
+inside a module-scoped fixture (never at import or collection), every
+compile runs in this process, and every such test lives in this one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps the compiler away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip (the next run warns and compiles
+    again), so the cache stays off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args, **jit_kwargs):
+    compiled = jax.jit(fn, **jit_kwargs).lower(*args).compile()
+    # The Mosaic custom call, not an interpret-mode emulation of the kernel.
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _placed(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree
+    )
+
+
+@pytest.mark.parametrize("seq,batch", [(64, 8), (4096, 1)])
+def test_flash_attention_fwd_and_grad(one_chip, seq, batch):
+    from transformer_tpu.kernels.flash_attention import flash_attention
+
+    qkv = jax.ShapeDtypeStruct((batch, seq, 8, 64), BF16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("s_q", [1, 3])
+@pytest.mark.parametrize(
+    "variant,h_kv,block_tokens",
+    [("bf16", 8, 16), ("int8", 8, 32), ("gqa", 2, 16)],
+)
+def test_paged_flash_attention(one_chip, variant, h_kv, block_tokens, s_q):
+    from transformer_tpu.kernels.paged_flash import paged_flash_attention
+
+    n, h, d, num_blocks, nmax = 8, 8, 64, 64, 8
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    quantized = variant == "int8"
+    pool = sds((num_blocks, block_tokens, h_kv, d), jnp.int8 if quantized else BF16)
+    args = [sds((n, s_q, h, d), BF16), pool, pool, sds((n, nmax), jnp.int32),
+            sds((n,), jnp.int32)]
+    if quantized:
+        scale = sds((num_blocks, block_tokens, h_kv, 1), jnp.float32)
+        args += [scale, scale]
+
+    def fn(q, k_pool, v_pool, table, lengths, k_scale=None, v_scale=None):
+        return paged_flash_attention(
+            q, k_pool, v_pool, table, lengths,
+            k_scale=k_scale, v_scale=v_scale, interpret=False,
+        )
+
+    _compile(fn, *args)
+
+
+def test_fused_ln_ffn(one_chip):
+    from transformer_tpu.ops.ffn import ffn_init, fused_ln_ffn
+    from transformer_tpu.ops.nn import layernorm_init
+
+    d, dff = 512, 2048
+    ffn = jax.eval_shape(lambda: ffn_init(jax.random.PRNGKey(0), d, dff))
+    ln = jax.eval_shape(lambda: layernorm_init(d))
+    x = jax.ShapeDtypeStruct((8, d), BF16, sharding=one_chip)
+
+    def fn(ln_params, ffn_params, x):
+        return fused_ln_ffn(ln_params, ffn_params, x, interpret=False)
+
+    _compile(fn, _placed(ln, one_chip), _placed(ffn, one_chip), x)
+
+
+def test_pool_step_paged_flash_at_base_widths(one_chip):
+    """The whole decode step ``--decode_kernel paged_flash`` dispatches:
+    6 layers x (paged attention + fused LN/FFN) at the base preset's widths,
+    sized as ``--serve_slots 8 --kv_layout paged --prefix_block 16``."""
+    from transformer_tpu.config import ModelConfig
+    from transformer_tpu.models.transformer import transformer_init
+    from transformer_tpu.serve import scheduler as sched
+
+    cfg = ModelConfig(
+        num_layers=6, d_model=512, num_heads=8, dff=2048,
+        input_vocab_size=26880, target_vocab_size=26880, max_position=64,
+        decoder_only=True, dtype="bfloat16",
+    )
+    slots, max_total, block = 8, 65, 16
+    pool_blocks = 1 + slots * -(-max_total // block)
+    key = jax.ShapeDtypeStruct((2,), np.uint32)
+    params = jax.eval_shape(lambda k: transformer_init(k, cfg), key)
+    pool, table, index = sched.abstract_paged_pool(
+        cfg, slots, max_total, pool_blocks, block
+    )
+    toks = jax.ShapeDtypeStruct((slots,), np.int32)
+    step = sched._pool_step_paged_flash.__wrapped__
+
+    compiled = _compile(
+        lambda p, c, tb, ix, t: step(p, c, tb, ix, t, cfg, block, False),
+        *_placed((params, pool, table, index, toks), one_chip),
+        donate_argnums=(1,),
+    )
+    assert compiled.as_text().count("tpu_custom_call") >= 2 * cfg.num_layers

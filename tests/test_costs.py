@@ -158,7 +158,7 @@ def test_kv_bytes_int8_and_window():
 
 def test_collective_inventory_attribution():
     from transformer_tpu.analysis.sharding import _mesh_1d
-    from transformer_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _mesh_1d("seq", 2)
@@ -185,7 +185,7 @@ def test_collective_inventory_attribution():
 
 def test_scan_weighting_multiplies_collective_counts():
     from transformer_tpu.analysis.sharding import _mesh_1d
-    from transformer_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _mesh_1d("seq", 2)
@@ -378,7 +378,7 @@ def test_canary_stray_all_gather_is_detected():
     """A stray all_gather smuggled into the pool step must fail the shipped
     baseline's (empty) collective set for that program."""
     from transformer_tpu.analysis.sharding import _mesh_1d
-    from transformer_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from transformer_tpu.serve import scheduler as sched
     from transformer_tpu.serve.scheduler import abstract_pool_caches
     from transformer_tpu.analysis.costs import _abstract_model

@@ -64,18 +64,13 @@ def test_dp2_parity_smoke():
     """A 2-device data-parallel train step reproduces the single-device loss
     (the full 8-device parity matrix is slow-tier; this pins the shard_map +
     psum path itself into the fast tier)."""
-    # Lazy import: transformer_tpu.parallel needs jax.shard_map, which older
-    # jax spells differently — a version skew there must skip THIS test, not
-    # take the whole module's collection (and the flash/prefill smokes) down.
-    # exc_type: the failure here is a plain ImportError (the module exists;
-    # the jax attribute doesn't), which importorskip only deprecatedly skips.
-    parallel = pytest.importorskip(
-        "transformer_tpu.parallel", exc_type=ImportError
+    from transformer_tpu.parallel import (
+        create_sharded_state,
+        make_mesh,
+        make_sharded_steps,
+        put_batch,
     )
-    create_sharded_state = parallel.create_sharded_state
-    make_mesh = parallel.make_mesh
-    make_sharded_steps = parallel.make_sharded_steps
-    put_batch = parallel.put_batch
+
     model = ModelConfig(
         num_layers=1, d_model=16, num_heads=2, dff=32,
         input_vocab_size=32, target_vocab_size=32, max_position=16,

@@ -30,7 +30,7 @@ FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures")
 BAD = os.path.join(FIXTURES, "tpa_kernel_bad_corpus.py")
 GOOD = os.path.join(FIXTURES, "tpa_kernel_good_corpus.py")
 
-_ARB = pltpu.TPUCompilerParams(dimension_semantics=("arbitrary",))
+_ARB = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 def _copy_entry(block_q=8, out_map=None):
@@ -220,7 +220,7 @@ class TestRuleTwins:
                     in_specs=[pl.BlockSpec((8, 128), lambda i: (i, 0))],
                     out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
                     out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
-                    compiler_params=pltpu.TPUCompilerParams(
+                    compiler_params=pltpu.CompilerParams(
                         dimension_semantics=("parallel",)
                     ),
                     interpret=True,

@@ -63,7 +63,7 @@ class TestProbeKeepBest:
         assert s.stopped_epoch is None
 
     def test_persistence_across_instances(self, tmp_path):
-        """The resumable-run pattern: each relay window is a fresh process;
+        """The resumable-run pattern: each invocation is a fresh process;
         the decision state must ride the JSON, not the object."""
         path = str(tmp_path / "p.json")
         s = ProbeKeepBest(path, patience=2)

@@ -141,7 +141,7 @@ def test_backoff_deterministic_and_jittered():
     assert backoff_ms(20.0, 0, order=8) != a     # spread across orders
 
 
-def test_error_taxonomy_classification():
+def test_error_code_classification():
     assert classify_error(InjectedFault("serve.prefill", 1)) == "transient"
     assert classify_error(ValueError("bad")) == "validation"
     assert classify_error(RuntimeError("boom")) == "internal"

@@ -531,3 +531,55 @@ def test_parse_router_line_matches_serve_parity():
         parse_router_line('{"fill": "y"}')
     with pytest.raises(ValueError, match="needs 'src'"):
         parse_router_line('{"beam": 4}')
+
+
+# --------------------------------------------------------------------------
+# one process per chip: the parent stays off JAX, replica i gets chip i
+
+
+def test_router_parent_imports_leave_jax_out():
+    """A parent that has touched JAX holds the chip its replicas need, so
+    the router tier must not even import it (the scheduler, the prefix
+    cache and the drafters do — they load on first use, in the workers)."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "import transformer_tpu.cli.router, transformer_tpu.serve.router\n"
+        "import transformer_tpu.serve.supervisor, transformer_tpu.serve.standby\n"
+        "print([m for m in ('jax', 'jaxlib') if m in sys.modules])\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "[]", out.stdout
+
+
+def test_replica_chip_env_assigns_and_refuses():
+    """Replica i takes chips [i*m, (i+1)*m) through libtpu's variables; a
+    fleet that outgrows the host is refused at spawn with both counts (a
+    second process on a taken chip would hang instead)."""
+    from transformer_tpu.serve.router import replica_chip_env
+
+    assert replica_chip_env(3, 1, host_chips=0) == {}  # off a TPU host
+    seen = set()
+    for i in range(4):
+        env = replica_chip_env(i, 1, host_chips=4)
+        assert env["TPU_VISIBLE_CHIPS"] == str(i)
+        assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        seen.add(env["TPU_VISIBLE_CHIPS"])
+    assert len(seen) == 4
+    pair = replica_chip_env(1, 2, host_chips=4)  # --mesh 2: its own group
+    assert pair["TPU_VISIBLE_CHIPS"] == "2,3"
+    assert pair["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,2,1"
+    with pytest.raises(ValueError, match=r"5 replica\(s\) x 1 chip.*has 4"):
+        replica_chip_env(4, 1, host_chips=4)
+    with pytest.raises(ValueError, match=r"2 replica\(s\) x 4 chip.*has 4"):
+        replica_chip_env(1, 4, host_chips=4)
+    with pytest.raises(ValueError, match="can span"):
+        replica_chip_env(0, 3, host_chips=4)

@@ -877,6 +877,28 @@ class TestCheckpoint:
 
 
 class TestTrainStep:
+    def test_tied_tables_start_in_their_own_buffers(self):
+        """``tie_embeddings`` hands the decoder the encoder's table itself;
+        a donated train step on a TPU refuses one buffer donated twice
+        (INVALID_ARGUMENT — the CPU ignores donation, so only this check
+        guards it here). The train state holds every leaf in its own
+        buffer, equal in value."""
+        import dataclasses
+
+        cfg = dataclasses.replace(TINY, tie_embeddings=True, tie_output=True)
+        params = transformer_init(jax.random.PRNGKey(0), cfg)
+        assert (
+            params["encoder"]["embedding"]["table"]
+            is params["decoder"]["embedding"]["table"]
+        )
+        state = create_train_state(jax.random.PRNGKey(0), cfg, TCFG)
+        leaves = jax.tree.leaves(state)
+        assert len({id(x) for x in leaves}) == len(leaves)
+        enc = state.params["encoder"]["embedding"]["table"]
+        dec = state.params["decoder"]["embedding"]["table"]
+        assert enc.unsafe_buffer_pointer() != dec.unsafe_buffer_pointer()
+        np.testing.assert_array_equal(enc, dec)
+
     def test_overfit_one_batch(self):
         """Integration: loss falls by >60% in 150 steps on a fixed batch."""
         tcfg = TrainConfig(

@@ -66,6 +66,7 @@ from typing import Callable, Iterable
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
 import numpy as np
 
 from transformer_tpu.analysis.configs import TINY_TRAIN, matrix
@@ -380,9 +381,9 @@ def _walk_eqns(jaxpr) -> Iterable:
 
 
 def _as_jaxprs(v) -> Iterable:
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, Jaxpr):
         yield v
     elif isinstance(v, (list, tuple)):
         for item in v:

@@ -185,9 +185,9 @@ def _itemsize(aval) -> int:
 
 
 def _is_var(v) -> bool:
-    import jax
+    from jax.extend.core import Literal
 
-    return not isinstance(v, jax.core.Literal)
+    return not isinstance(v, Literal)
 
 
 def _peak_extra(jaxpr) -> int:

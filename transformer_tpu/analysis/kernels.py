@@ -930,9 +930,7 @@ def _iter_pallas_eqns(jaxpr, depth: int = 0):
 
 
 def _eqn_kernel_name(eqn) -> str:
-    nsi = eqn.params.get("name_and_src_info")
-    name = getattr(nsi, "name", None) or str(nsi or "")
-    return name.split(" at ")[0].strip()
+    return eqn.params["jaxpr"].debug_info.func_name
 
 
 def _eqn_key(eqn):

@@ -103,11 +103,11 @@ _SPEC_CTORS = frozenset({"P", "PartitionSpec"})
 def _sub_jaxprs(value: Any) -> Iterable[Any]:
     """Yield raw Jaxprs nested in an eqn param value (ClosedJaxpr, Jaxpr,
     or lists/tuples of either)."""
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, Jaxpr):
         yield value
     elif isinstance(value, (list, tuple)):
         for item in value:
@@ -236,9 +236,9 @@ def canned_sharded_programs() -> tuple[dict[str, tuple], list[str]]:
 
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from transformer_tpu.parallel.compat import shard_map
     from transformer_tpu.parallel.ring_attention import (
         ring_attention,
         ulysses_attention,

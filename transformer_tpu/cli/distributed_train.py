@@ -36,7 +36,7 @@ def _reject_cpu_virtual_bf16(jax, dtype: str) -> None:
     XLA:CPU's collective rendezvous aborts the whole process (not a Python
     exception) when a single-process, multi-virtual-device mesh runs the
     full fit machinery in bfloat16 (bisected in round 4; fp32 and the
-    pytest/dryrun shard_map paths are unaffected — docs/ROUND4.md). The
+    pytest/dryrun shard_map paths are unaffected). The
     reference's precedent is its batch-divisibility ``ValueError``
     (``distributed_train.py:154-158``): fail with a message, never abort.
     ``TRANSFORMER_TPU_ALLOW_CPU_BF16=1`` re-enables the path for probing
@@ -53,7 +53,7 @@ def _reject_cpu_virtual_bf16(jax, dtype: str) -> None:
         raise app.UsageError(
             "dtype=bfloat16 on a single-process multi-device CPU mesh "
             f"({len(jax.devices())} virtual devices) aborts in XLA:CPU's "
-            "collective rendezvous (known backend bug, docs/ROUND4.md). "
+            "collective rendezvous (known XLA:CPU bug). "
             "Pass --dtype=float32 for CPU runs, or set "
             "TRANSFORMER_TPU_ALLOW_CPU_BF16=1 to try anyway."
         )

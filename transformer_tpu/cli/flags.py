@@ -444,9 +444,8 @@ def flags_to_mesh_config(n_devices: int) -> MeshConfig:
 
 def maybe_force_platform() -> None:
     """``--platform`` override, plus the persistent compilation cache
-    (every CLI process re-pays full XLA compiles otherwise; opt out or
-    relocate via ``$TRANSFORMER_TPU_JAX_CACHE``, see
-    ``utils.enable_compilation_cache``)."""
+    (every CLI process re-pays full XLA compiles otherwise; see
+    ``utils.enable_compilation_cache`` for where it lives)."""
     if FLAGS.platform:
         import jax
 

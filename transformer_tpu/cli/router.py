@@ -473,6 +473,12 @@ def main(argv) -> None:
         FLAGS.ha = False
 
     n = max(1, FLAGS.replicas)
+    # Refuse a fleet that outgrows the host's chips before any worker
+    # starts (each spawn checks its own index again, for scale-ups).
+    from transformer_tpu.serve.router import count_tpu_chips, replica_chip_env
+    from transformer_tpu.serve.sharded import parse_mesh_spec
+
+    replica_chip_env(n - 1, parse_mesh_spec(FLAGS.mesh) or 1, count_tpu_chips())
     links = []
     for i in range(n):
         role = "both"

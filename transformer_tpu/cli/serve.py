@@ -409,7 +409,7 @@ def serve_continuous(q: queue.Queue, sched, model_cfg, telemetry=None) -> None:
             try:
                 req = _route_lm_request(line, model_cfg)
             except _RoutingError as e:
-                # Error-taxonomy codes (docs/ROBUSTNESS.md) ride along; the
+                # Structured error codes (docs/ROBUSTNESS.md) ride along; the
                 # `error` string stays byte-identical to the grouped path's.
                 sched.submit_done({"error": str(e), "code": "routing"})
                 continue

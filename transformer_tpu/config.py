@@ -12,9 +12,10 @@ from __future__ import annotations
 
 import dataclasses
 import json
-from typing import Any, Mapping
+from typing import TYPE_CHECKING, Any, Mapping
 
-import jax.numpy as jnp
+if TYPE_CHECKING:
+    import jax.numpy as jnp
 
 # Special-token convention, matching the reference pipeline (``utils.py:137-143``):
 # pad = 0; BOS = subword_vocab_size; EOS = subword_vocab_size + 1, so a model's
@@ -196,10 +197,16 @@ class ModelConfig:
 
     @property
     def compute_dtype(self) -> jnp.dtype:
+        # jax is imported here, not at module top: the config dataclasses are
+        # read by processes that must stay off JAX (the router parent).
+        import jax.numpy as jnp
+
         return jnp.dtype(self.dtype)
 
     @property
     def params_dtype(self) -> jnp.dtype:
+        import jax.numpy as jnp
+
         return jnp.dtype(self.param_dtype)
 
 

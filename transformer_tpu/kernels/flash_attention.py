@@ -111,17 +111,7 @@ def _block_and_padded_len(seq_len: int, requested: int) -> tuple[int, int]:
 
 
 def _compiler_params(dimension_semantics: tuple[str, ...]):
-    # jax renamed TPUCompilerParams -> CompilerParams; accept either spelling
-    # so the kernel compiles across the jax versions the repo meets.
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cls is None:  # pragma: no cover - exotic pallas build
-        return None
-    try:
-        return cls(dimension_semantics=dimension_semantics)
-    except TypeError:  # pragma: no cover - older/newer field spellings
-        return None
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
 def _gated(cfg: _FlashConfig) -> bool:

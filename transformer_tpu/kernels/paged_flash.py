@@ -107,12 +107,14 @@ def _paged_kernel(
         kt = jnp.swapaxes(k, 0, 1)  # (H_kv, B, D)
         vt = jnp.swapaxes(v, 0, 1)
         q = q_ref[0]  # (H_kv, GS, D)
-        # Scores exactly as the XLA oracle computes them: dot in the compute
-        # dtype, cast to fp32, then scale — per (row, key) values are
-        # independent of blocking, so they match the gather path bitwise.
+        # Mosaic accepts only a 32-bit matmul accumulator, so accumulate in
+        # fp32 and round where the XLA oracle's compute-dtype dot rounds (its
+        # result) before the fp32 scale — per (row, key) values stay
+        # independent of blocking and match the gather path.
         scores = jax.lax.dot_general(
-            q, kt, (((2,), (2,)), ((0,), (0,)))
-        ).astype(jnp.float32) * scale  # (H_kv, GS, B)
+            q, kt, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ).astype(dtype).astype(jnp.float32) * scale  # (H_kv, GS, B)
 
         # Per-row offset causality: folded row r = g * S_q + i holds query
         # index i = r % S_q at absolute position length - S_q + i; pool

@@ -472,14 +472,6 @@ def summarize_events(events: list[dict]) -> dict:
     if perf.get("programs"):
         report["perf"] = perf
 
-    # ---- bench attribution ----------------------------------------------
-    bench = [e for e in events if str(e.get("kind", "")).startswith("bench.")]
-    if bench:
-        counts: dict[str, int] = {}
-        for e in bench:
-            counts[e["kind"]] = counts.get(e["kind"], 0) + 1
-        report["bench"] = counts
-
     # ---- tracing (span volume only; `obs trace` renders the timeline) ----
     spans = [e for e in events if e.get("kind") == "trace.span"]
     if spans:
@@ -745,12 +737,6 @@ def render_text(report: dict) -> str:
                     "" if r.get("in_band", True) else " OUT OF BAND"
                 )
             lines.append(line)
-    bench = report.get("bench")
-    if bench:
-        lines.append(
-            "bench: " + ", ".join(f"{k.split('.', 1)[1]} x{v}"
-                                  for k, v in sorted(bench.items()))
-        )
     tracing = report.get("tracing")
     if tracing:
         lines.append(
@@ -781,10 +767,7 @@ def render_text(report: dict) -> str:
 
 def render_roofline_text(report: dict) -> str:
     rows = report.get("programs", [])
-    lines = [
-        f"{len(rows)} measured program(s); roofline peak "
-        f"{report.get('peak_bytes_per_s', 0):.4g} B/s"
-    ]
+    lines = [f"{len(rows)} measured program(s)"]
     for r in rows:
         line = (
             f"  {r['program']}: p50 {r['p50_ms']:.3f}ms "
@@ -795,9 +778,10 @@ def render_roofline_text(report: dict) -> str:
         if r.get("predicted_bytes_moved"):
             line += (
                 f"; predicted {r['predicted_bytes_moved']}B moved -> "
-                f"{r['effective_bytes_per_s']:.4g} B/s effective, "
-                f"roofline {r['roofline_ratio']}"
+                f"{r['effective_bytes_per_s']:.4g} B/s effective"
             )
+        if r.get("roofline_ratio") is not None:
+            line += f", roofline {r['roofline_ratio']}"
         if r.get("measured_over_predicted_tokens") is not None:
             line += (
                 f"; measured/predicted tokens/s "
@@ -1122,10 +1106,7 @@ def main(argv: list[str] | None = None) -> int:
                 predictions_by_program(costs_doc)
                 if costs_doc else dict(prior.get("programs") or {})
             )
-            doc = write_baseline(
-                args.baseline, measured, predictions=preds,
-                peak_bytes_per_s=prior.get("peak_bytes_per_s"),
-            )
+            doc = write_baseline(args.baseline, measured, predictions=preds)
             print(
                 f"banked {len(doc['programs'])} program(s) -> {args.baseline}"
             )

@@ -6,7 +6,7 @@ package) because breaker state is an observability export — gauges and
 the protected subsystems: ``cli/flags.py`` wires a breaker into
 ``EventLog`` without importing the serve stack. The serving-facing surface
 re-exports it from ``transformer_tpu.serve.resilience``, which owns the
-rest of the fault-tolerance story (fault plane, error taxonomy,
+rest of the fault-tolerance story (fault plane, error codes,
 docs/ROBUSTNESS.md).
 """
 

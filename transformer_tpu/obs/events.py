@@ -77,9 +77,6 @@ The kinds this repo emits (schema in docs/OBSERVABILITY.md):
   discipline as ``slo.burn``.
 - ``metrics.snapshot`` — periodic full registry dump (histograms as
   count/sum/min/max/p50/p95/p99).
-- ``bench.relay_probe`` / ``bench.fallback_row`` / ``bench.attempt`` —
-  bench-infra attribution (bench.py), so a flaky relay is diagnosable from
-  the log after the fact.
 
 The machine-readable mirror of this list is :data:`EVENT_CATALOGUE`
 below; a tier-1 AST sweep (tests/test_perf_observatory.py) fails if any
@@ -123,10 +120,6 @@ fault_hook = None
 #: here AND in docs/OBSERVABILITY.md — add the entry (and the doc schema)
 #: in the same change that adds an emit site.
 EVENT_CATALOGUE = {
-    "bench.attempt": "bench-infra: one per relay attempt (bench.py rows)",
-    "bench.fallback_row": "bench-infra: CPU-fallback row attribution",
-    "bench.no_value": "bench-infra: a probe that produced no value",
-    "bench.relay_probe": "bench-infra: relay liveness probe outcome",
     "ckpt.fallback": "trainer restored an older checkpoint after a bad one",
     "flight.dump": "non-automatic flight-recorder dump (signal/request/close)",
     "metrics.snapshot": "periodic full metrics-registry dump",
@@ -193,7 +186,7 @@ class EventLog:
 
     def emit(self, kind: str, **fields) -> None:
         """Append one event. ``fields`` must be JSON-serializable; a ``ts``
-        stamp is added unless the caller supplies one (bench.py backfills).
+        stamp is added unless the caller supplies one.
         Safe to call from any thread: the line is serialized outside the
         lock, the single ``write`` happens inside it."""
         if self._broken:

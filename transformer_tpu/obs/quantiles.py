@@ -5,8 +5,8 @@ and the obs :class:`~transformer_tpu.obs.registry.Histogram` both wrap this
 class rather than keeping their own percentile code. Design constraints:
 
 - **Dependency-free** (stdlib ``math`` only): the obs package must be
-  importable from anywhere — ``bench.py``'s wrapper process, the summarize
-  CLI, test helpers — without paying a jax/numpy import.
+  importable from anywhere — the router parent, the summarize CLI, test
+  helpers — without paying a jax/numpy import.
 - **O(1) memory, O(1) observe**: geometric buckets over ``[lo, hi)`` with a
   fixed growth factor; a serving process recording one sample per decode
   step must never grow state with traffic.

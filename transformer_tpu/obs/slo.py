@@ -27,7 +27,7 @@ The four spec kinds map onto what the serving tier records
 ``availability``    bad = the request answered with an error
 ``ttft_p95``        bad = ``ttft_s`` above ``threshold_s`` (objective
                     0.95 = the p95 target; generalizes to any quantile)
-``deadline_miss``   bad = the answer's taxonomy code is ``deadline``
+``deadline_miss``   bad = the answer's error code is ``deadline``
 ``acceptance_rate`` weighted: bad = rejected draft tokens, total =
                     drafted (objective = the acceptance-rate floor)
 ==================  =====================================================

@@ -715,9 +715,7 @@ class DistributedTrainer(Trainer):
         super().__init__(model_cfg, train_cfg, state, **kwargs)
         # Replace the plain-jit steps built by Trainer.__init__ with the
         # sharded versions (always jitted: eager SPMD doesn't exist),
-        # honouring the caller's donate_state choice (tied-weight configs
-        # must not donate: one buffer aliased into two consumers fails at
-        # TPU execution time).
+        # honouring the caller's donate_state choice.
         donate = kwargs.get("donate_state", True)
         self.train_step_fn, self.eval_step_fn = make_sharded_steps(
             mesh, model_cfg, train_cfg, shardings, shard_seq, donate=donate

@@ -133,15 +133,8 @@ def initialize_distributed(
     raises otherwise, so this function probes initialization state without
     touching the backend and re-raises real bring-up failures instead of
     silently degrading to a single-host run."""
-    is_initialized = getattr(jax.distributed, "is_initialized", None)
-    if is_initialized is not None:
-        if is_initialized():
-            return  # already initialized (e.g. by the launcher)
-    else:  # older JAX without the public probe
-        from jax._src import distributed as _dist
-
-        if getattr(_dist.global_state, "client", None) is not None:
-            return
+    if jax.distributed.is_initialized():
+        return  # already initialized (e.g. by the launcher)
     if coordinator_address is None and num_processes is None and process_id is None:
         # Auto-detection: only meaningful where a cluster environment exists
         # (TPU pod metadata, SLURM, ...). Absent one, stay single-process.

@@ -39,7 +39,7 @@ from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
-from transformer_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Params = Any

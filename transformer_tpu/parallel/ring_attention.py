@@ -37,9 +37,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from transformer_tpu.parallel.compat import shard_map
 
 from transformer_tpu.kernels.flash_attention import (
     _MASKED,

@@ -22,9 +22,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from transformer_tpu.parallel.compat import shard_map
 
 
 @dataclasses.dataclass(frozen=True)

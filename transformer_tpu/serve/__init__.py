@@ -15,48 +15,41 @@ plane: router-coordinated rolling checkpoint swaps with canary gating and
 SLO-driven auto-rollback (``transformer_tpu/serve/upgrade.py``,
 docs/SERVING.md "Live-weights rollout")."""
 
-from transformer_tpu.serve.prefix_cache import (
-    PrefixCache,
-    PrefixCorruptionError,
-    PrefixHit,
-)
-from transformer_tpu.serve.resilience import (
-    CircuitBreaker,
-    FaultPlane,
-    InjectedFault,
-    TransientError,
-)
-from transformer_tpu.serve.router import (
-    ReplicaLink,
-    ReplicaProcess,
-    Router,
-)
-from transformer_tpu.serve.scheduler import ContinuousScheduler, SlotPool
-from transformer_tpu.serve.upgrade import UpgradeCoordinator, UpgradeError
-from transformer_tpu.serve.speculative import (
-    ModelDrafter,
-    NgramDrafter,
-    drafter_from_flags,
-    speculative_generate,
-)
+import importlib
 
-__all__ = [
-    "CircuitBreaker",
-    "ContinuousScheduler",
-    "FaultPlane",
-    "InjectedFault",
-    "PrefixCache",
-    "PrefixCorruptionError",
-    "PrefixHit",
-    "ReplicaLink",
-    "ReplicaProcess",
-    "Router",
-    "SlotPool",
-    "TransientError",
-    "UpgradeCoordinator",
-    "UpgradeError",
-    "ModelDrafter",
-    "NgramDrafter",
-    "drafter_from_flags",
-    "speculative_generate",
-]
+# Resolved on first attribute access (PEP 562), not at import: the router
+# parent imports ``transformer_tpu.serve.router`` and must stay off JAX —
+# one process per chip, and a parent that has touched JAX holds the chip —
+# while the scheduler, the prefix cache and the drafters all import jax.
+_EXPORTS = {
+    "CircuitBreaker": "resilience",
+    "ContinuousScheduler": "scheduler",
+    "FaultPlane": "resilience",
+    "InjectedFault": "resilience",
+    "ModelDrafter": "speculative",
+    "NgramDrafter": "speculative",
+    "PrefixCache": "prefix_cache",
+    "PrefixCorruptionError": "prefix_cache",
+    "PrefixHit": "prefix_cache",
+    "ReplicaLink": "router",
+    "ReplicaProcess": "router",
+    "Router": "router",
+    "SlotPool": "scheduler",
+    "TransientError": "resilience",
+    "UpgradeCoordinator": "upgrade",
+    "UpgradeError": "upgrade",
+    "drafter_from_flags": "speculative",
+    "speculative_generate": "speculative",
+}
+
+__all__ = sorted(_EXPORTS)
+
+
+def __getattr__(name: str):
+    try:
+        module = _EXPORTS[name]
+    except KeyError:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}") from None
+    value = getattr(importlib.import_module(f"{__name__}.{module}"), name)
+    globals()[name] = value
+    return value

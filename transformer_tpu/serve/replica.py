@@ -43,7 +43,8 @@ router -> replica:
     {"type": "shutdown"}                                  drain + exit
 
 replica -> router:
-    {"type": "ready", "replica": name, "slots": N
+    {"type": "ready", "replica": name, "slots": N,
+     "device": {"platform", "kind", "chips", "devices"}
      [, "control_port": P]}                               P with --ha only
     {"type": "hb", "backlog": B, "free": F, "active": A}  heartbeat (the
                                                           least-loaded gauges)
@@ -383,8 +384,10 @@ def main(argv=None) -> None:
     # --mesh bootstrap must precede the FIRST jax-importing line: on the
     # CPU platform the worker grows its own virtual device count (the
     # conftest/analysis trick), which only takes effect before jax
-    # initializes. The flag only affects CPU hosts — on TPU it is inert —
-    # and an operator-provided device-count flag always wins.
+    # initializes. The XLA flag only affects CPU hosts; on a TPU host the
+    # router's spawn gives this process exactly its N chips
+    # (serve/router.py replica_chip_env). An operator-provided
+    # device-count flag always wins.
     from transformer_tpu.serve.sharded import (
         normalize_mesh_spec,
         parse_mesh_spec,
@@ -421,6 +424,12 @@ def main(argv=None) -> None:
             flight_path_for(args.metrics_jsonl), autodump_s=0.5
         )
         flight.install_signal_handlers()
+
+    # Before the first compile, so a respawned replica (and every replica
+    # after the first) loads its programs instead of compiling them cold.
+    from transformer_tpu.utils.profiling import enable_compilation_cache
+
+    enable_compilation_cache()
 
     if args.model_spec:
         with open(args.model_spec) as f:
@@ -511,9 +520,20 @@ def main(argv=None) -> None:
             target=_control_server, args=(listener, q), daemon=True,
             name="replica-control-accept",
         ).start()
+    import jax
+
+    held = jax.devices()[: mesh_n or 1]  # serving_mesh takes the first N
     ready = {
         "type": "ready", "replica": args.replica_name,
         "slots": args.serve_slots, "role": args.role,
+        # What this replica runs on: the chips the router's spawn assigned
+        # it (None off a TPU host) and the devices JAX reports for them.
+        "device": {
+            "platform": held[0].platform,
+            "kind": held[0].device_kind,
+            "chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            "devices": [str(d) for d in held],
+        },
     }
     if control_port is not None:
         ready["control_port"] = control_port
@@ -525,6 +545,8 @@ def main(argv=None) -> None:
         # BEFORE the replica takes traffic.
         ready["mesh"] = mesh_shape
     out.send(ready)
+    # The same line for the operator: which chip did this replica land on?
+    print(f"replica ready: {json.dumps(ready)}", file=sys.stderr, flush=True)
 
     hb_s = max(args.heartbeat_ms, 1.0) / 1e3
     last_hb = 0.0

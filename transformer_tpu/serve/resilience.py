@@ -314,7 +314,7 @@ def fired(point: str) -> bool:
 
 
 # --------------------------------------------------------------------------
-# structured error taxonomy (the continuous scheduler's answer contract)
+# structured error codes (the continuous scheduler's answer contract)
 
 #: code -> meaning; docs/ROBUSTNESS.md carries the full table. Every error
 #: the continuous scheduler answers carries one of these under ``"code"``
@@ -336,7 +336,7 @@ ERROR_CODES = {
 
 
 def classify_error(exc: BaseException) -> str:
-    """Exception -> taxonomy code for admission-time failures."""
+    """Exception -> error code for admission-time failures."""
     if isinstance(exc, TransientError):
         return "transient"
     if isinstance(exc, (ValueError, TypeError, KeyError)):

@@ -78,8 +78,10 @@ untouched.
 from __future__ import annotations
 
 import dataclasses
+import glob
 import hashlib
 import json
+import os
 import queue
 import subprocess
 import sys
@@ -226,6 +228,10 @@ class ReplicaLink:
         # check reads this at admission — the router itself never sees
         # device topology beyond the string.
         self.mesh: str | None = None
+        # What the replica's ready line says it runs on: platform,
+        # device_kind and the devices it holds (benchmarks name their
+        # device from this — the parent never asks jax).
+        self.device: dict | None = None
         self.control_port: int | None = None  # --ha takeover socket
         self.final_stats: dict | None = None  # replica's shutdown report
         self.final_perf: dict | None = None   # profiler rows in that report
@@ -258,6 +264,51 @@ class ReplicaLink:
         return self.role == "both" or self.role == stage
 
 
+# One process per chip: a TPU chip belongs to one process at a time, so the
+# router parent stays off JAX and hands replica i its own chip (or, under
+# --mesh N, its own group of N chips) through the variables libtpu reads at
+# start-up. The chips are counted by their device nodes, so the parent never
+# loads the TPU runtime. (PCI is no guide: a machine that is handed one chip
+# of a four-chip host shows four PCI devices and one node — measured on the
+# v5e, PR 21.)
+# TPU_CHIPS_PER_PROCESS_BOUNDS by chips per replica, on a 2x2 or 2x4 host.
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+
+
+def count_tpu_chips() -> int:
+    """TPU chips this host can open: ``/dev/accel<N>`` nodes (v4 and
+    earlier drivers) or numbered ``/dev/vfio/<N>`` groups (v5e and later;
+    ``/dev/vfio/vfio`` is the control node). 0 off a TPU host."""
+    nodes = glob.glob("/dev/accel[0-9]*") + glob.glob("/dev/vfio/[0-9]*")
+    return len(nodes)
+
+
+def replica_chip_env(index: int, chips_per_replica: int, host_chips: int) -> dict:
+    """The environment that gives replica ``index`` chips
+    ``[index * m, (index + 1) * m)`` of this host as a one-process slice.
+    Empty off a TPU host (CPU workers share the host platform). Raises when
+    the fleet outgrows the host — a second process on a taken chip would
+    hang at start-up instead of failing."""
+    if not host_chips:
+        return {}
+    m = chips_per_replica
+    if m not in _CHIP_BOUNDS:
+        raise ValueError(
+            f"a replica can span {sorted(_CHIP_BOUNDS)} chips, not {m}"
+        )
+    if (index + 1) * m > host_chips:
+        raise ValueError(
+            f"replica {index} needs chips {index * m}..{(index + 1) * m - 1}: "
+            f"{index + 1} replica(s) x {m} chip(s) each = {(index + 1) * m} "
+            f"chips, but this host has {host_chips}"
+        )
+    return {
+        "TPU_VISIBLE_CHIPS": ",".join(str(c) for c in range(index * m, (index + 1) * m)),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": _CHIP_BOUNDS[m],
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+
+
 class ReplicaProcess(ReplicaLink):
     """A replica worker as a subprocess speaking JSONL over its pipes.
 
@@ -267,32 +318,47 @@ class ReplicaProcess(ReplicaLink):
     state surface between the two is exactly the synchronized queue."""
 
     def __init__(self, index: int, name: str, argv: list[str],
-                 role: str = "both"):
+                 role: str = "both", env: "dict | None" = None):
         super().__init__(index, name, role=role)
         self._proc = subprocess.Popen(
             argv, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-            stderr=sys.stderr, text=True, bufsize=1,
+            stderr=sys.stderr, text=True, bufsize=1, env=env,
         )
 
     @classmethod
     def spawn(cls, index: int, worker_args: list[str], role: str = "both",
               name: str | None = None) -> "ReplicaProcess":
         """Launch ``python -m transformer_tpu.serve.replica`` with
-        ``worker_args`` plus the replica's identity flags."""
+        ``worker_args`` plus the replica's identity flags, on the chips
+        its index assigns it (a respawn at the same index lands on the
+        same chips)."""
+        from transformer_tpu.serve.sharded import parse_mesh_spec
+
         name = name or f"replica{index}"
         argv = [
             sys.executable, "-m", "transformer_tpu.serve.replica",
             "--replica_name", name, "--role", role, *worker_args,
         ]
-        link = cls(index, name, argv, role=role)
-        # Remember where the worker's flight dumps will land (both
-        # `--metrics_jsonl PATH` and `--metrics_jsonl=PATH` spellings):
-        # the Supervisor salvages <path>.flight.json after a hard kill.
-        for i, arg in enumerate(worker_args):
-            if arg == "--metrics_jsonl" and i + 1 < len(worker_args):
-                link.metrics_jsonl = worker_args[i + 1] or None
-            elif arg.startswith("--metrics_jsonl="):
-                link.metrics_jsonl = arg.split("=", 1)[1] or None
+
+        def flag(key: str) -> "str | None":
+            # Both `--key VALUE` and `--key=VALUE` spellings.
+            for i, arg in enumerate(worker_args):
+                if arg == key and i + 1 < len(worker_args):
+                    return worker_args[i + 1]
+                if arg.startswith(key + "="):
+                    return arg.split("=", 1)[1]
+            return None
+
+        chips = replica_chip_env(
+            index, parse_mesh_spec(flag("--mesh")) or 1, count_tpu_chips()
+        )
+        link = cls(
+            index, name, argv, role=role,
+            env={**os.environ, **chips} if chips else None,
+        )
+        # Remember where the worker's flight dumps will land: the
+        # Supervisor salvages <path>.flight.json after a hard kill.
+        link.metrics_jsonl = flag("--metrics_jsonl") or None
         return link
 
     def start_reader(self, inbox: "queue.Queue") -> None:
@@ -894,6 +960,7 @@ class Router:
                 # Captured BEFORE on_ready: the supervisor's wrong-shape
                 # refusal judges the replica's announced mesh.
                 link.mesh = msg["mesh"]
+            link.device = msg.get("device")
             if self._sup is not None and link.warming:
                 self._sup.on_ready(link)
         elif kind in ("upgrade_staged", "upgraded"):

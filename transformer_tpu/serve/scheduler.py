@@ -1078,6 +1078,8 @@ class ContinuousScheduler:
         # can join measured against predicted. The program this scheduler
         # dispatches is fixed at construction by layout + kernel choice.
         self._profiler = getattr(telemetry, "profiler", None)
+        if self._profiler is not None:
+            self._profiler.device_kind = jax.devices()[0].device_kind
         _kind = (
             "_paged_flash"
             if self.paged and self.decode_kernel == "paged_flash"
@@ -1594,7 +1596,7 @@ class ContinuousScheduler:
             extra = {}
             if "error" in resp:
                 extra["error"] = resp["error"]
-                if "code" in resp:  # taxonomy code, like every error root
+                if "code" in resp:  # error code, like every error root
                     extra["code"] = resp["code"]
             root.end(order=order, **extra)
         if self._tel is not None:

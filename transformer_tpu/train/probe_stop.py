@@ -11,9 +11,9 @@ and stop after ``patience`` consecutive non-improving probes.
 
 All state is persisted as one small JSON next to the run's checkpoints, so
 the decision survives the resumable-run pattern (``benchmarks/bleu_run.py``
-re-invoked per relay window with ``--epoch_budget``): a stop decided in one
-invocation is still a stop in the next, and a best probe recorded three
-windows ago is still the best.
+re-invoked with ``--epoch_budget``): a stop decided in one invocation is
+still a stop in the next, and a best probe recorded three invocations ago
+is still the best.
 
 The reference has no analogue — it trains a fixed epoch count and keeps only
 rotated last-N checkpoints (``train.py:159``, ``max_to_keep=5``), so its

@@ -89,10 +89,29 @@ def make_optimizer(model_cfg: ModelConfig, train_cfg: TrainConfig) -> optax.Grad
     return tx
 
 
+def _own_buffers(tree: Any) -> Any:
+    """Give every leaf its own buffer. ``tie_embeddings`` hands the decoder
+    the encoder's table itself — one array referenced twice — and a donated
+    train step takes each leaf's buffer: the TPU runtime refuses to donate
+    one buffer twice (INVALID_ARGUMENT at the first step, measured on the
+    v5e in PR 21; the CPU ignores donation, so no CPU run sees it). The
+    optimizer updates the two leaves separately from the first step on, so
+    a copy changes no result."""
+    seen: set[int] = set()
+
+    def own(x):
+        if id(x) in seen:
+            return jnp.copy(x)
+        seen.add(id(x))
+        return x
+
+    return jax.tree.map(own, tree)
+
+
 def create_train_state(
     rng: jax.Array, model_cfg: ModelConfig, train_cfg: TrainConfig
 ) -> TrainState:
-    params = transformer_init(rng, model_cfg)
+    params = _own_buffers(transformer_init(rng, model_cfg))
     tx = make_optimizer(model_cfg, train_cfg)
     return TrainState(
         step=jnp.zeros((), jnp.int32),

@@ -649,6 +649,8 @@ class Trainer:
             # the roofline sentinel's train.step stream.
             from transformer_tpu.obs.profile import profile_call
 
+            profiler.device_kind = jax.devices()[0].device_kind
+
             self.train_step = profile_call(
                 self.train_step, profiler, "train.step"
             )
