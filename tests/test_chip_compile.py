@@ -14,6 +14,7 @@ compile runs in this process, and every such test lives in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -154,4 +155,27 @@ def test_pool_step_paged_flash_at_base_widths(one_chip):
         *_placed((params, pool, table, index, toks), one_chip),
         donate_argnums=(1,),
     )
-    assert compiled.as_text().count("tpu_custom_call") >= 2 * cfg.num_layers
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2 * cfg.num_layers
+    # Each Mosaic call carries its kernel's own name (``pallas_call(name=)``):
+    # what the profiler's device events, and the benchmark's kernel metrics,
+    # are told apart by. Without it every call is %<jitted function>.<n>.
+    for kernel in ("paged_flash_attention", "fused_ln_ffn"):
+        named = re.findall(rf"%{kernel}(?:\.\d+)* = [^\n]*custom-call\(", text)
+        assert len(named) == cfg.num_layers, (kernel, len(named))
+
+
+def test_flash_attention_kernels_are_named(one_chip):
+    from transformer_tpu.kernels.flash_attention import flash_attention
+
+    qkv = jax.ShapeDtypeStruct((2, 256, 8, 64), BF16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv).as_text()
+    # Under ``grad`` the transformations wrap the name (%jvp_<name>_.<n>,
+    # %transpose_jvp_<name>__.<n>); the kernel's own stays in it.
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
+        assert re.search(rf"%\w*{kernel}[\w.]* = [^\n]*custom-call\(", text), kernel

@@ -10,7 +10,7 @@ from transformer_tpu.config import ModelConfig
 from transformer_tpu.data.tokenizer import SubwordTokenizer
 from transformer_tpu.models import transformer_init
 from transformer_tpu.serve import ContinuousScheduler
-from transformer_tpu.train.decode import generate
+from transformer_tpu.train.decode import generate, prefill_len_for
 
 
 @pytest.fixture(scope="module")
@@ -262,3 +262,203 @@ def test_serve_continuous_loop(lm, capsys):
     # grouped path's kind-mismatch answer.
     assert lines[3]["error"] == "LM export serves 'prompt', not 'src'"
     assert lines[4]["error"] == "LM export serves 'prompt', not 'src'"
+
+
+# --------------------------------------------------------------------------
+# spans inside step() and admit(), kept in the in-memory buffer (no Telemetry)
+
+STEP_PHASES = (
+    "step.prepare", "step.build", "step.dispatch", "step.fetch",
+    "step.bookkeep",
+)
+
+
+def _buffered_run(lm, reqs, **kw):
+    """Run ``reqs`` on a telemetry-free scheduler; returns (answers, the
+    spans the run left in the process-wide buffer, request span dicts)."""
+    from transformer_tpu.obs.trace import buffer
+
+    params, cfg, tok = lm
+    buffer().clear()
+    tapped = []
+    sched = ContinuousScheduler(
+        params, cfg, tok, span_tap=tapped.append, **kw
+    )
+    out = sched.run([dict(r) for r in reqs])
+    return out, buffer().snapshot(), tapped
+
+
+def _end(span):
+    return span["t0_mono"] + span["dur_s"]
+
+
+def _children(spans, parent):
+    return sorted(
+        (s for s in spans if s.get("parent") == parent["span"]),
+        key=lambda s: s["t0_mono"],
+    )
+
+
+@pytest.mark.parametrize(
+    "kw",
+    [
+        dict(num_slots=2),
+        dict(num_slots=3, prefill_chunk=2),
+        dict(num_slots=2, kv_layout="paged", kv_block=4),
+    ],
+    ids=["dense", "chunked", "paged"],
+)
+def test_step_spans_phases_and_counts(lm, kw):
+    """Every ``scheduler.step`` span holds the five phases inside itself,
+    and its counts add up: a stepped slot either emitted a token or walked
+    a prompt-tail token, and the run's output tokens are the steps'
+    ``emitted`` plus the tokens picked at admission."""
+    # Every request asks for a token or more and none can end early (no EOS
+    # in a random model's greedy output is not guaranteed, so check below).
+    reqs = [
+        {"prompt": "ab cd ef gh ij", "max_new": 6},
+        {"prompt": "kl", "max_new": 3},
+        {"prompt": "ab cd", "max_new": 8, "temperature": 0.9, "seed": 3},
+        {"prompt": "mn ef cd", "max_new": 4},
+        {"prompt": "gh ij kl mn", "max_new": 5},
+    ]
+    out, spans, tapped = _buffered_run(lm, reqs, **kw)
+    assert all("continuation" in a for a in out)
+    steps = [s for s in spans if s["name"] == "scheduler.step"]
+    assert steps and all(s["lane"] == "scheduler" for s in steps)
+    assert all("parent" not in s for s in steps)  # roots
+    ended_early = any(
+        t["new_tokens"] < r["max_new"]
+        for t, r in zip(sorted(tapped, key=lambda t: t["order"]), reqs)
+    )
+    for step in steps:
+        kids = _children(spans, step)
+        names = [k["name"] for k in kids]
+        for phase in STEP_PHASES:
+            assert phase in names, (phase, names)
+        for k in kids:
+            assert k["t0_mono"] >= step["t0_mono"]
+            assert _end(k) <= _end(step) + 1e-6
+            assert k["lane"] == "scheduler" and k["trace"] == step["trace"]
+        # The phases come in order; dispatch and fetch alternate, a pair a
+        # sampling group.
+        df = [n for n in names if n in ("step.dispatch", "step.fetch")]
+        assert df == ["step.dispatch", "step.fetch"] * (len(df) // 2)
+        assert names[0] == "step.prepare" and names[1] == "step.build"
+        assert names[-1] == "step.bookkeep"
+        assert step["continued"] <= step["emitted"]
+        assert step["emitted"] + step["walked"] <= step["active"]
+        if not ended_early:
+            assert step["emitted"] + step["walked"] == step["active"]
+        dispatched = [k for k in kids if k["name"] == "step.dispatch"]
+        assert dispatched[0]["programs"] == 2
+        assert all(k["programs"] == 1 for k in dispatched[1:])
+    admits = [s for s in spans if s["name"] == "serve.admit"]
+    assert len(admits) == len(reqs)
+    first_picks = sum(
+        1 for s in spans if s["name"] == "admit.first_pick"
+    )
+    total_out = sum(t["new_tokens"] for t in tapped)
+    assert sum(s["emitted"] for s in steps) + first_picks == total_out
+    retired = sum(
+        k["retired"] for s in steps for k in _children(spans, s)
+        if k["name"] == "step.bookkeep"
+    )
+    # A request that answers at its admission's first pick never steps.
+    assert retired <= len(reqs)
+
+
+def test_sampling_groups_alternate_dispatch_and_fetch(lm):
+    """Greedy and sampled requests side by side make two pick groups: the
+    step dispatches and fetches one after the other, in that order."""
+    reqs = [
+        {"prompt": "ab cd ef", "max_new": 6},
+        {"prompt": "gh ij", "max_new": 6, "temperature": 0.8, "seed": 5},
+    ]
+    _, spans, _ = _buffered_run(lm, reqs, num_slots=2)
+    both = [
+        s for s in spans if s["name"] == "scheduler.step" and s["active"] == 2
+    ]
+    assert both
+    for step in both:
+        names = [
+            k["name"] for k in _children(spans, step)
+            if k["name"] in ("step.dispatch", "step.fetch")
+        ]
+        assert names == ["step.dispatch", "step.fetch"] * 2
+
+
+def test_admit_spans_and_request_gaps(lm):
+    """``serve.admit`` holds its phases; ``prefill_s`` ends after the first
+    pick's sync; ``itl_max_s`` lies between the shortest and the longest
+    gap between the steps that emitted the request's tokens."""
+    # A prompt of a power of two of tokens (BOS included) is prefilled whole
+    # and picks its first token at admission; any other walks its tail.
+    tok = lm[2]
+    prompt = next(
+        p for p in ("ab cd ef", "ab cd ef gh", "ab cd ef gh ij", "ab cd")
+        if prefill_len_for(1 + len(tok.encode(p))) == 1 + len(tok.encode(p)) >= 4
+    )
+    reqs = [{"prompt": prompt, "max_new": 6}]
+    _, spans, tapped = _buffered_run(lm, reqs, num_slots=1)
+    (admit,) = [s for s in spans if s["name"] == "serve.admit"]
+    kids = _children(spans, admit)
+    assert [k["name"] for k in kids] == [
+        "admit.encode", "admit.prefill_dispatch", "admit.first_pick",
+    ]
+    for k in kids:
+        assert k["t0_mono"] >= admit["t0_mono"] and _end(k) <= _end(admit) + 1e-6
+    assert admit["prompt_tokens"] == admit["prefill_tokens"] >= 4
+    (req,) = tapped
+    assert req["new_tokens"] >= 3
+    # Admission to the prompt in cache: no earlier than the first pick's end.
+    first_pick = kids[-1]
+    assert req["prefill_s"] >= (_end(first_pick) - admit["t0_mono"]) - 0.05
+    assert req["prefill_s"] <= admit["dur_s"] + 1e-3
+    (root,) = [s for s in spans if s["name"] == "serve.request"]
+    assert root["itl_max_s"] == req["itl_max_s"] > 0
+    # A prompt that walks its tail gets every token from a step. Token
+    # stamps are taken inside a step's bookkeeping, so a gap between the
+    # tokens of two consecutive steps lies between these two distances.
+    _, spans, tapped = _buffered_run(
+        lm, [{"prompt": prompt + " ij", "max_new": 6}], num_slots=1
+    )
+    assert "admit.first_pick" not in {s["name"] for s in spans}
+    emitting = [
+        s for s in spans if s["name"] == "scheduler.step" and s["emitted"]
+    ]
+    books = sorted(
+        (
+            k for s in emitting for k in _children(spans, s)
+            if k["name"] == "step.bookkeep"
+        ),
+        key=lambda s: s["t0_mono"],
+    )
+    shortest = min(b["t0_mono"] - _end(a) for a, b in zip(books, books[1:]))
+    longest = max(_end(b) - a["t0_mono"] for a, b in zip(books, books[1:]))
+    assert shortest <= tapped[0]["itl_max_s"] <= longest
+    # A paged admission also allocates its blocks under a span of its own.
+    _, spans, _ = _buffered_run(
+        lm, reqs, num_slots=1, kv_layout="paged", kv_block=4
+    )
+    (admit,) = [s for s in spans if s["name"] == "serve.admit"]
+    assert [k["name"] for k in _children(spans, admit)] == [
+        "admit.encode", "admit.blocks", "admit.prefill_dispatch",
+        "admit.first_pick",
+    ]
+    # One output token has no gap to report.
+    _, spans, tapped = _buffered_run(
+        lm, [{"prompt": "ab", "max_new": 1}], num_slots=1
+    )
+    assert "itl_max_s" not in tapped[0]
+
+
+def test_idle_pool_leaves_no_step_span(lm):
+    from transformer_tpu.obs.trace import buffer
+
+    params, cfg, tok = lm
+    buffer().clear()
+    sched = ContinuousScheduler(params, cfg, tok, num_slots=2)
+    for _ in range(5):
+        sched.step()
+    assert len(buffer()) == 0

@@ -28,9 +28,12 @@ from transformer_tpu.obs.merge import (
     parse_duration,
 )
 from transformer_tpu.obs.trace import (
+    SpanBuffer,
     SpanContext,
     Tracer,
+    buffer,
     chrome_trace,
+    default_tracer,
     span_tree,
     traced_call,
 )
@@ -153,6 +156,109 @@ def test_traced_call_wraps_and_records():
 
 # --------------------------------------------------------------------------
 # Chrome trace-event export
+
+
+def test_buffer_is_bounded_and_counts_drops():
+    buf = SpanBuffer(capacity=4)
+    for i in range(7):
+        buf.append({"name": f"s{i}"})
+    assert len(buf) == 4 and buf.capacity == 4 and buf.dropped == 3
+    assert [s["name"] for s in buf.snapshot()] == ["s3", "s4", "s5", "s6"]
+    buf.clear()
+    assert len(buf) == 0 and buf.dropped == 0
+    # The process-wide one is the same class at its documented size.
+    assert isinstance(buffer(), SpanBuffer) and buffer().capacity == 65536
+
+
+def test_tracer_without_emit_buffers_and_emits_nothing():
+    buffer().clear()
+    tracer = Tracer()  # emit=None: buffer only
+    with tracer.span("outer", lane="scheduler", n=1) as outer:
+        tracer.start_span("inner").end(k=2)
+    assert tracer.open_count == 0 and tracer.stats["ended"] == 2
+    inner, got_outer = buffer().snapshot()
+    assert (inner["name"], got_outer["name"]) == ("inner", "outer")
+    # Every field of the trace.span event, plus t0_mono.
+    assert got_outer["kind"] == "trace.span" and got_outer["lane"] == "scheduler"
+    assert got_outer["n"] == 1 and inner["k"] == 2
+    assert inner["parent"] == got_outer["span"] == outer.ctx.span_id
+    assert {"ts", "t0", "dur_s", "trace", "t0_mono"} <= set(got_outer)
+    assert default_tracer() is default_tracer()
+    assert default_tracer()._emit is None
+
+
+def test_t0_mono_is_perf_counter_at_start():
+    import time
+
+    buffer().clear()
+    tracer = Tracer()
+    before = time.perf_counter()
+    with tracer.span("a"):
+        mid = time.perf_counter()
+        with tracer.span("b"):
+            pass
+    after = time.perf_counter()
+    b, a = buffer().snapshot()
+    assert before <= a["t0_mono"] <= mid <= b["t0_mono"] <= after
+    assert a["t0_mono"] + a["dur_s"] <= after + 1e-6
+    # An attribute may not shadow it, like every reserved field.
+    tracer.start_span("c").end(t0_mono=0.0)
+    assert buffer().snapshot()[-1]["t0_mono"] >= after
+    assert tracer.stats["dropped_attrs"] == 1
+
+
+def test_annotate_mirrors_span_contexts_only():
+    """The profiler mirror: the factory is entered and left once per
+    ``tracer.span`` (also when the body raises), never for ``start_span``."""
+    log = []
+
+    class Mirror:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    tracer = Tracer(annotate=Mirror)
+    with tracer.span("outer"):
+        with tracer.span("inner"):
+            pass
+        tracer.start_span("long.lived").end()
+    assert log == [("enter", "outer"), ("enter", "inner"), ("exit", "inner"),
+                   ("exit", "outer")]
+    del log[:]
+    with pytest.raises(KeyError):
+        with tracer.span("boom"):
+            raise KeyError("x")
+    assert log == [("enter", "boom"), ("exit", "boom")]
+    assert tracer.open_count == 0
+
+
+def test_telemetry_always_traces_but_logs_spans_only_on_request():
+    buffer().clear()
+    sink = io.StringIO()
+    tel = Telemetry(events=EventLog(sink), interval=0.0)  # trace=False
+    with tel.tracer.span("quiet"):
+        pass
+    tel.emit("other.event", x=1)
+    tel.events.flush()
+    kinds = [json.loads(line)["kind"] for line in sink.getvalue().splitlines()]
+    assert "other.event" in kinds and "trace.span" not in kinds
+    assert [s["name"] for s in buffer().snapshot()] == ["quiet"]
+    assert tel.health()["spans"]["buffered"] == 1
+    loud = io.StringIO()
+    tel2 = Telemetry(events=EventLog(loud), interval=0.0, trace=True)
+    with tel2.tracer.span("loud"):
+        pass
+    tel2.events.flush()
+    events = [json.loads(line) for line in loud.getvalue().splitlines()]
+    (span,) = [e for e in events if e["kind"] == "trace.span"]
+    # The JSONL schema is what it was: the buffer's extra field stays out.
+    assert span["name"] == "loud" and "t0_mono" not in span
+    assert [s["name"] for s in buffer().snapshot()] == ["quiet", "loud"]
 
 
 def test_chrome_trace_schema_and_lanes():
@@ -387,7 +493,19 @@ def test_traced_scheduler_byte_identity_and_complete_trees(lm):
         {"prompt": "mn ef", "max_new": 3},
         {"prompt": "gh", "max_new": 1},
     ]
+    buffer().clear()
     plain = _scheduler(lm, None).run(reqs)
+    # Spans are recorded whatever the telemetry: without a bundle...
+    by_default = buffer().snapshot()
+    assert {"scheduler.step", "serve.request", "step.fetch"} <= {
+        s["name"] for s in by_default
+    }
+    # ...and with one that does not log them, answers byte-identical.
+    quiet = io.StringIO()
+    tel = Telemetry(events=EventLog(quiet), interval=0.0)
+    assert _scheduler(lm, tel).run(reqs) == plain
+    assert '"trace.span"' not in quiet.getvalue()
+    assert len(buffer()) == 2 * len(by_default)
     traced, events, tracer = _traced_run(lm, reqs)
     assert plain == traced  # tracing must be invisible in the answers
     trees = _assert_tree_complete(events, tracer)
@@ -570,13 +688,15 @@ def test_chaos_subset_trees_complete_and_attributed(lm, tmp_path):
             assert {"serve.request", "serve.queue", "serve.admit"} <= by_trace[trace]
 
 
-def test_traced_scheduler_zero_recompiles(lm):
+@pytest.mark.parametrize("trace", [True, False], ids=["logged", "buffer_only"])
+def test_traced_scheduler_zero_recompiles(lm, trace):
     """Tracing on the steady-state decode path costs zero recompiles —
-    the retrace-sentinel acceptance criterion with spans enabled."""
+    the retrace-sentinel acceptance criterion with spans enabled, written
+    to the event log or kept in the buffer alone."""
     from transformer_tpu.analysis.retrace import RetraceSentinel
     from transformer_tpu.serve import scheduler as sched_mod
 
-    tel = Telemetry(interval=0.0, trace=True)
+    tel = Telemetry(interval=0.0, trace=trace)
     warm = _scheduler(lm, tel)
     warm.run([{"prompt": "ab cd", "max_new": 3}])
     sentinel = RetraceSentinel()
@@ -585,7 +705,7 @@ def test_traced_scheduler_zero_recompiles(lm):
     sentinel.watch("_pick_pool", sched_mod._pick_pool, budget=0)
     sentinel.snapshot()
     for _ in range(3):
-        tel2 = Telemetry(interval=0.0, trace=True)
+        tel2 = Telemetry(interval=0.0, trace=trace)
         s = _scheduler(lm, tel2)
         out = s.run([{"prompt": "ab cd", "max_new": 3}])
         assert "continuation" in out[0]
@@ -650,6 +770,15 @@ def test_traced_trainer_step_and_checkpoint_spans(tmp_path):
     assert len(by_name["train.step"]) == 8
     assert {e["parent"] for e in by_name["train.step"]} == {fit["span"]}
     assert {e["trace"] for e in spans} == {fit["trace"]}  # ONE tree
+    # One train.data_wait per batch handed over, and one per epoch for the
+    # next() that found it exhausted; all under fit, mirrored in the buffer.
+    waits = by_name["train.data_wait"]
+    assert len([w for w in waits if not w.get("end")]) == 8
+    assert len([w for w in waits if w.get("end")]) == 2
+    assert {e["parent"] for e in waits} == {fit["span"]}
+    buffered = [s for s in buffer().snapshot() if s["trace"] == fit["trace"]]
+    assert len(buffered) == len(spans)
+    assert all("t0_mono" in s for s in buffered)
     # Eval + checkpoint spans nest under the fit span too.
     assert by_name["train.eval"]
     assert by_name["ckpt.save"] and by_name["ckpt.restore"]
@@ -661,6 +790,41 @@ def test_traced_trainer_step_and_checkpoint_spans(tmp_path):
         if e["ph"] == "M" and e["name"] == "thread_name"
     }
     assert lanes == {"train"}
+
+
+def test_trainer_without_telemetry_buffers_its_spans():
+    """``Trainer(telemetry=None)`` (what a benchmark builds) records into
+    the process's default tracer: one ``train.data_wait`` a batch."""
+    import jax
+    import numpy as np
+
+    from transformer_tpu.config import ModelConfig, TrainConfig
+    from transformer_tpu.train import Trainer, create_train_state
+
+    cfg = ModelConfig(
+        num_layers=1, d_model=16, num_heads=2, dff=32,
+        input_vocab_size=64, target_vocab_size=64, max_position=64,
+        dropout_rate=0.0, dtype="float32", decoder_only=True,
+    )
+    tcfg = TrainConfig(
+        batch_size=2, sequence_length=8, epochs=1, warmup_steps=10,
+        log_every_steps=0, eval_every_steps=0,
+    )
+
+    class DS:
+        def batches(self, epoch):
+            r = np.random.default_rng(epoch)
+            for _ in range(3):
+                ids = r.integers(1, 64, size=(2, 8)).astype(np.int32)
+                yield ids, ids
+
+    buffer().clear()
+    state = create_train_state(jax.random.PRNGKey(0), cfg, tcfg)
+    Trainer(cfg, tcfg, state, log_fn=lambda s: None).fit(DS())
+    names = [s["name"] for s in buffer().snapshot()]
+    assert names.count("train.data_wait") == 4  # 3 batches + the exhausted next()
+    assert names.count("train.step") == 3 and names.count("train.fit") == 1
+    assert all(s["lane"] == "train" for s in buffer().snapshot())
 
 
 # --------------------------------------------------------------------------

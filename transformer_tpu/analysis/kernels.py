@@ -167,6 +167,11 @@ class _Capture:
     in_avals: list[tuple[tuple[int, ...], Any]] = dataclasses.field(default_factory=list)
     scalar_values: list[Any] = dataclasses.field(default_factory=list)
     calls: int = 1
+    # What the traced pallas_call eqn is called: the call's ``name=`` (the
+    # kernel's name in the compiled program and the profiler's trace) where
+    # it has one, else the kernel function's. Reports and the baseline stay
+    # keyed by the function's name.
+    eqn_name: str = ""
 
     def site_key(self):
         return (
@@ -267,6 +272,7 @@ def _capture_pallas(records: list[_Capture]):
             dimension_semantics=sem,
             input_output_aliases=aliases,
             interpret=kw.get("interpret"),
+            eqn_name=kw.get("name") or getattr(fn, "__name__", str(fn)),
         )
         inner = real(kernel, *pargs, **kw)
 
@@ -953,7 +959,7 @@ def _match_sites(caps: list[_Capture], eqns: list):
         pool.setdefault(_eqn_key(eqn), []).append(eqn)
     pairs = []
     for cap in deduped.values():
-        key = (cap.kernel_name, cap.grid)
+        key = (cap.eqn_name, cap.grid)
         bucket = pool.get(key)
         pairs.append((cap, bucket.pop(0) if bucket else None))
     return pairs

@@ -78,8 +78,10 @@ def define_metrics_flags() -> None:
         "metrics.snapshot events)")
     flags.DEFINE_boolean(
         "trace", False,
-        "record hierarchical trace.span events (request-scoped distributed "
-        "tracing, docs/OBSERVABILITY.md) into --metrics_jsonl; export with "
+        "also write every closed span (request-scoped distributed tracing, "
+        "docs/OBSERVABILITY.md) as a trace.span event into --metrics_jsonl; "
+        "spans are always kept in memory (obs.trace.buffer()) and mirrored "
+        "into a profiler trace while one is taken. Export with "
         "`python -m transformer_tpu.obs trace <file> --out trace.json` and "
         "load in chrome://tracing / Perfetto. Answers and compiled programs "
         "are unaffected (contract-checked)")
@@ -393,6 +395,7 @@ def flags_to_telemetry():
 
     from transformer_tpu.obs import EventLog, Telemetry
     from transformer_tpu.obs.breaker import CircuitBreaker
+    from transformer_tpu.obs.trace import BUFFER_CAPACITY
 
     events = None
     if FLAGS.metrics_jsonl:
@@ -406,11 +409,12 @@ def flags_to_telemetry():
             breaker=CircuitBreaker("event_sink", threshold=3, cooldown_s=30.0),
         )
     if FLAGS.trace and events is None:
-        # A tracer without an event sink would pay full span bookkeeping
-        # and silently drop every trace.span — tell the operator instead.
+        # Spans are recorded either way (obs.trace.buffer()); without an
+        # event sink there is nowhere to write them — tell the operator.
         logging.warning(
-            "--trace needs --metrics_jsonl to record trace.span events; "
-            "tracing disabled for this run"
+            "--trace without --metrics_jsonl: spans are kept in memory only "
+            "(the last %d, obs.trace.buffer()); no trace.span event is "
+            "written", BUFFER_CAPACITY,
         )
     telemetry = Telemetry(
         events=events,

@@ -272,6 +272,7 @@ def _fwd(cfg: _FlashConfig, q, k, v, kv_mask):
         ],
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
+        name="flash_attention_fwd",
     )(*inputs)
     return out, lse
 
@@ -411,6 +412,7 @@ def flash_ring_step(
         input_output_aliases={n_fixed: 0, n_fixed + 1: 1, n_fixed + 2: 2},
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
+        name="flash_attention_fwd_ring",
     )(*inputs)
 
 
@@ -582,6 +584,7 @@ def flash_chunk_bwd(cfg: _FlashConfig, q, k, v, kv_mask, lse, delta, do):
         scratch_shapes=[pltpu.VMEM((cfg.block_q, d), jnp.float32)],
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
+        name="flash_attention_bwd_dq",
     )(*inputs)
 
     # dk/dv: k-blocks parallel; (group member, q-block) pairs sequential.
@@ -634,6 +637,7 @@ def flash_chunk_bwd(cfg: _FlashConfig, q, k, v, kv_mask, lse, delta, do):
         ],
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
+        name="flash_attention_bwd_dkv",
     )(*inputs_kv)
     return dq, dk, dv
 

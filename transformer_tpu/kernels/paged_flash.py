@@ -264,6 +264,7 @@ def paged_flash_attention(
         out_shape=jax.ShapeDtypeStruct((n, h_kv, gs, d), q.dtype),
         compiler_params=_compiler_params(("parallel", "arbitrary")),
         interpret=bool(interpret),
+        name="paged_flash_attention",
     )(table, lengths, *inputs)
     # Unfold (N, H_kv, G*S_q, D) -> (N, S_q, H, D).
     return (

@@ -7,7 +7,8 @@ a Prometheus text file rewritten atomically every ``interval`` seconds and
 a ``metrics.snapshot`` event appended to the log on the same cadence.
 ``cli/flags.py flags_to_telemetry`` builds it from ``--metrics_jsonl`` /
 ``--metrics_port`` / ``--metrics_interval``; passing ``telemetry=None``
-everywhere keeps the zero-overhead default.
+everywhere keeps metrics and the event log off (spans still reach the
+in-memory buffer through ``obs.trace.default_tracer()``).
 
 Design rule (contract-checked by ``analysis/contracts.py telemetry_inert``):
 nothing in this module imports jax or touches device values. Recording
@@ -24,6 +25,7 @@ from typing import Any, Callable
 
 from transformer_tpu.obs.events import EventLog
 from transformer_tpu.obs.registry import Histogram, MetricsRegistry
+from transformer_tpu.obs.trace import Tracer, buffer as span_buffer
 
 
 def timed_call(
@@ -56,13 +58,14 @@ def timed_call(
 class Telemetry:
     """Registry + event log + periodic sinks, as one pass-around handle.
 
-    ``trace=True`` additionally carries a :class:`~transformer_tpu.obs.
-    trace.Tracer` bound to this bundle's event emit — the scheduler and
-    trainer consult ``telemetry.tracer`` and record hierarchical
-    ``trace.span`` events when it is set (docs/OBSERVABILITY.md tracing
-    section). Off by default: spans multiply event volume per request, so
-    tracing is an explicit opt-in (``--trace``), while staying answer- and
-    jaxpr-inert whenever it IS on (contract-checked).
+    The bundle always carries a :class:`~transformer_tpu.obs.trace.Tracer`
+    (``telemetry.tracer``): the scheduler and trainer record hierarchical
+    spans through it, and every closed span lands in the process-wide
+    in-memory buffer (``obs.trace.buffer()``). ``trace=True`` (``--trace``)
+    decides only whether closed spans are ALSO written to the event log as
+    ``trace.span`` events (docs/OBSERVABILITY.md tracing section) — off by
+    default, since spans multiply event volume per request. Either way
+    tracing stays answer- and jaxpr-inert (contract-checked).
     """
 
     def __init__(
@@ -84,16 +87,12 @@ class Telemetry:
         self._last_flush = float("-inf")
         self._server = None
         self._t0 = time.time()
-        self.tracer = None
+        self.tracer = Tracer(self.emit if trace else None)
         # Armed on demand (arm_profiler / arm_flight): the per-program
         # dispatch profiler (obs/profile.py) and the always-on flight
         # recorder (obs/flight.py). None keeps both surfaces free.
         self.profiler = None
         self.flight = None
-        if trace:
-            from transformer_tpu.obs.trace import Tracer
-
-            self.tracer = Tracer(self.emit)
 
     # ---- optional subsystems ---------------------------------------------
 
@@ -215,6 +214,11 @@ class Telemetry:
             }
         if self.profiler is not None:
             doc["profiler"] = dict(self.profiler.stats)
+        spans = span_buffer()
+        doc["spans"] = {
+            "buffered": len(spans), "capacity": spans.capacity,
+            "dropped": spans.dropped, "open": self.tracer.open_count,
+        }
         return doc
 
     # ---- scrape endpoint --------------------------------------------------

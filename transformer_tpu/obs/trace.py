@@ -42,12 +42,26 @@ stack.
 Thread-safety: spans may start on one thread (client ``submit``) and end
 on another (the scheduler loop); the tracer's open-span accounting is
 locked, and emission goes through the multi-writer-safe EventLog.
+
+Three sinks, no switch. Every closed span goes to (1) the process-wide
+:func:`buffer` — bounded, in memory, always — with the fields of its
+``trace.span`` event plus ``t0_mono``, the ``time.perf_counter()`` reading
+at its start (the clock benchmarks and step timers use); (2) the event log,
+when the tracer was given an ``emit`` (``Telemetry(trace=True)``,
+``--trace``); (3) the profiler's own trace, for spans opened with the
+context-manager form, when the tracer was given an ``annotate`` factory
+(``name -> context manager``; the scheduler and the trainer pass
+``jax.profiler.TraceAnnotation`` through ``utils/profiling.annotate``, so
+this module still imports no jax): the span is then also a host event of the
+same name on the device's clock, at the cost of a flag test while no
+profiler session runs. Long-lived ``start_span`` spans are not mirrored.
 """
 
 from __future__ import annotations
 
-import contextlib
+import collections
 import os
+import random
 import threading
 import time
 
@@ -55,14 +69,81 @@ import time
 #: shadow them (``Span.end`` silently drops offenders rather than corrupt
 #: the schema; the exporter and merge tooling key on these).
 RESERVED_SPAN_FIELDS = frozenset(
-    {"ts", "kind", "trace", "span", "parent", "name", "lane", "t0", "dur_s"}
+    {"ts", "kind", "trace", "span", "parent", "name", "lane", "t0", "dur_s",
+     "t0_mono"}
 )
+
+#: Closed spans the process keeps (oldest dropped first). A serving replica
+#: closes some 200 spans a second, so this is the last five minutes.
+BUFFER_CAPACITY = 65536
+
+
+class SpanBuffer:
+    """A bounded, thread-safe ring of closed spans (dicts). Process-wide
+    (:func:`buffer`) because its readers — a benchmark's metric reader,
+    ``/healthz``, a flight dump — do not hold the scheduler or its
+    ``Telemetry``; spans keep their ``lane``, so several replicas in one
+    process stay apart."""
+
+    def __init__(self, capacity: int = BUFFER_CAPACITY) -> None:
+        self._spans: collections.deque = collections.deque(maxlen=capacity)
+        self._lock = threading.Lock()
+        self.dropped = 0
+
+    @property
+    def capacity(self) -> int:
+        return self._spans.maxlen
+
+    def __len__(self) -> int:
+        return len(self._spans)
+
+    def append(self, span: dict) -> None:
+        with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                self.dropped += 1
+            self._spans.append(span)
+
+    def snapshot(self) -> list:
+        """The buffered spans, oldest first (a copy of the list; the span
+        dicts are shared and must not be mutated)."""
+        with self._lock:
+            return list(self._spans)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._spans.clear()
+            self.dropped = 0
+
+
+_BUFFER = SpanBuffer()
+_DEFAULT_TRACER: "Tracer | None" = None
+
+
+def buffer() -> SpanBuffer:
+    """The process-wide buffer every :class:`Tracer` appends to."""
+    return _BUFFER
+
+
+def default_tracer() -> "Tracer":
+    """The buffer-only tracer code uses where no ``Telemetry`` was passed."""
+    global _DEFAULT_TRACER
+    if _DEFAULT_TRACER is None:
+        _DEFAULT_TRACER = Tracer()
+    return _DEFAULT_TRACER
+
 
 _TRACEPARENT_VERSION = "00"
 
 
+# Span ids come from a generator seeded once from the OS (and again in a
+# forked child), not from a system call per span: a decode step opens eight
+# spans, and they identify, they do not protect.
+_IDS = random.Random()
+os.register_at_fork(after_in_child=_IDS.seed)
+
+
 def _hex_id(nbytes: int) -> str:
-    return os.urandom(nbytes).hex()
+    return "%0*x" % (2 * nbytes, _IDS.getrandbits(8 * nbytes))
 
 
 class SpanContext:
@@ -120,7 +201,7 @@ class Span:
 
     __slots__ = (
         "name", "ctx", "parent_id", "lane", "attrs",
-        "_t0_wall", "_t0_mono", "_tracer", "_ended",
+        "_t0_wall", "_t0_mono", "_tracer", "_ended", "_mirror",
     )
 
     def __init__(self, tracer: "Tracer", name: str, ctx: SpanContext,
@@ -134,6 +215,29 @@ class Span:
         self._t0_mono = time.perf_counter()
         self._tracer = tracer
         self._ended = False
+        self._mirror = None
+
+    def __enter__(self) -> "Span":
+        """Become this thread's current span (``tracer.span(...)`` is used
+        this way) and, where the tracer has an ``annotate`` factory, a host
+        event of the same name in the profiler's trace."""
+        self._tracer._stack().append(self)
+        annotate = self._tracer.annotate
+        if annotate is not None:
+            self._mirror = annotate(self.name)
+            self._mirror.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._mirror is not None:
+            self._mirror.__exit__(None, None, None)
+        self._tracer._stack().pop()
+        if not self._ended:
+            if exc_type is not None:
+                self.end(error=exc_type.__name__)
+            else:
+                self.end()
+        return False
 
     def set(self, **attrs) -> None:
         """Attach/overwrite attributes before the span closes (recorded in
@@ -151,11 +255,16 @@ class Span:
 
 
 class Tracer:
-    """Span factory bound to an emit callable (``EventLog.emit`` or
-    ``Telemetry.emit`` — anything with the ``(kind, **fields)`` shape)."""
+    """Span factory. Closed spans always land in :func:`buffer`; ``emit``
+    (``EventLog.emit`` or ``Telemetry.emit`` — anything with the
+    ``(kind, **fields)`` shape) additionally writes them to an event log,
+    None means buffer only. ``annotate`` (``name -> context manager``,
+    settable after construction by the first user that imports jax) mirrors
+    every ``span()`` context into the profiler's trace."""
 
-    def __init__(self, emit) -> None:
+    def __init__(self, emit=None, annotate=None) -> None:
         self._emit = emit
+        self.annotate = annotate
         self._lock = threading.Lock()
         self._open: dict[str, str] = {}  # span_id -> name (introspection)
         self._local = threading.local()
@@ -222,8 +331,8 @@ class Tracer:
             "trace": span.ctx.trace_id,
             "span": span.ctx.span_id,
             "name": span.name,
-            "t0": round(span._t0_wall, 6),
-            "dur_s": round(dur, 9),
+            "t0": span._t0_wall,
+            "dur_s": dur,
         }
         if span.parent_id is not None:
             fields["parent"] = span.parent_id
@@ -237,26 +346,23 @@ class Tracer:
             fields[key] = value
         # ts = close time, consistent with every other event kind; t0/dur_s
         # carry the interval (the exporter never trusts ts for geometry).
-        self._emit("trace.span", ts=round(span._t0_wall + dur, 6), **fields)
+        ts = span._t0_wall + dur
+        _BUFFER.append({"ts": ts, "kind": "trace.span", **fields,
+                        "t0_mono": span._t0_mono})
+        if self._emit is not None:
+            # The log's times are rounded (microseconds; nanoseconds for
+            # a duration), the buffer's are as the clocks gave them.
+            fields.update(t0=round(fields["t0"], 6), dur_s=round(dur, 9))
+            self._emit("trace.span", ts=round(ts, 6), **fields)
 
-    @contextlib.contextmanager
     def span(self, name: str, parent=None, lane: "str | None" = None, **attrs):
-        """Context-manager span: parents to the enclosing ``span()`` on this
-        thread (unless ``parent=`` overrides), pushes itself as current for
-        the duration, and always closes — even on exception (recorded as
-        ``error=<type name>``; the exception propagates untouched)."""
-        sp = self.start_span(name, parent=parent, lane=lane, **attrs)
-        stack = self._stack()
-        stack.append(sp)
-        try:
-            yield sp
-        except BaseException as e:
-            sp.end(error=type(e).__name__)
-            raise
-        finally:
-            stack.pop()
-            if not sp._ended:
-                sp.end()
+        """Context-manager span (``with tracer.span(...) as sp``): parents to
+        the enclosing ``span()`` on this thread (unless ``parent=``
+        overrides), is current for the duration, and always closes — even on
+        exception (recorded as ``error=<type name>``; the exception
+        propagates untouched). With an ``annotate`` factory the same
+        interval is a host event of the same name in the profiler's trace."""
+        return self.start_span(name, parent=parent, lane=lane, **attrs)
 
 
 def traced_call(fn, tracer: Tracer, name: str, lane: "str | None" = None,

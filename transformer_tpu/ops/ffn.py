@@ -258,5 +258,6 @@ def fused_ln_ffn(
         ],
         compiler_params=_compiler_params(("arbitrary",)),
         interpret=bool(interpret),
+        name="fused_ln_ffn",
     )(*inputs)
     return out.reshape(*lead, d)

@@ -731,11 +731,10 @@ class DistributedTrainer(Trainer):
                 donate=donate,
             )
             self.multi_step = self._sharded_multi_step
-        if self.telemetry is not None:
-            # The plain-step wrappers installed by Trainer.__init__ were just
-            # replaced by the sharded steps — re-route them through the
-            # dispatch-timing wrapper.
-            self._wrap_steps_for_dispatch_timing()
+        # The plain-step wrappers installed by Trainer.__init__ were just
+        # replaced by the sharded steps — re-route them through the
+        # dispatch-timing and span wrappers.
+        self._wrap_steps_for_dispatch_timing()
 
     def _sharded_train_step(self, state, src, tgt, rng):
         src = put_batch(np.asarray(src), self.mesh, self.shard_seq)

@@ -109,7 +109,6 @@ mid-flight, never a corrupted neighbor.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import threading
 import time
@@ -133,6 +132,7 @@ from transformer_tpu.models.transformer import (
     transformer_prefill,
     transformer_verify,
 )
+from transformer_tpu.obs.trace import SpanContext
 from transformer_tpu.ops.attention import (
     insert_kv_blocks,
     kv_buffer_keys,
@@ -160,6 +160,7 @@ from transformer_tpu.train.decode import (
     prefill_len_for,
     sample_token,
 )
+from transformer_tpu.utils.profiling import mirrored_tracer
 
 
 def abstract_pool_caches(cfg: ModelConfig, num_slots: int, max_total: int):
@@ -639,8 +640,8 @@ class _Pending:
     # re-try this entry.
     attempts: int = 0
     not_before: float = 0.0
-    # Tracing (None when the scheduler has no tracer): the request's root
-    # span (submit -> answer) and the currently-open lifecycle child.
+    # Tracing: the request's root span (submit -> answer) and the
+    # currently-open lifecycle child.
     # span_admit/span_prefill ride here only during an admission attempt,
     # so a transient-fault retry (or an admission error) can close them.
     span_root: object = None
@@ -693,13 +694,17 @@ class _Active:
     t_admit: float = 0.0
     t_prefill: float | None = None
     t_first: float | None = None
+    # The last output token's stamp and the longest gap between two of them
+    # (None until there are two): one stamp per slot, no list.
+    t_last: float = 0.0
+    itl_max: float | None = None
     # Absolute perf_counter deadline (None = no deadline): checked at the
     # queue, prefill, and decode-step boundaries; expiry frees the slot and
     # answers a structured "deadline" error with the partial continuation.
     deadline: float | None = None
-    # Tracing spans (None without a tracer): the root rides over from the
-    # _Pending; prefill closes when the LAST prompt token is in cache
-    # (exactly the t_prefill edge) and decode opens there.
+    # Tracing spans: the root rides over from the _Pending; prefill closes
+    # when the LAST prompt token is in cache (exactly the t_prefill edge)
+    # and decode opens there.
     span_root: object = None
     span_prefill: object = None
     span_decode: object = None
@@ -1069,9 +1074,11 @@ class ContinuousScheduler:
         # byte-identical (tests/test_obs.py pins this) and the decode hot
         # path compiles the same programs (retrace budget stays 0).
         self._tel = telemetry
-        # Tracing rides the telemetry bundle (Telemetry(trace=True) /
-        # --trace); None disables every span site at one attribute check.
-        self._tracer = getattr(telemetry, "tracer", None)
+        # Spans go through the telemetry bundle's tracer (or the process
+        # default without one): always into the in-memory buffer, into the
+        # event log under --trace, and — the context-manager ones — into
+        # the profiler's trace while a profiler session runs.
+        self._tracer = mirrored_tracer(telemetry)
         # Per-program dispatch profiler (obs/profile.py, armed via
         # Telemetry.arm_profiler): clocks each canned program under the
         # SAME base names the cost model prices, so the roofline report
@@ -1224,11 +1231,9 @@ class ContinuousScheduler:
     # ---- tracing / SLO plumbing -------------------------------------------
 
     def _traced(self, name: str, parent, **attrs):
-        """A ``tracer.span`` context (explicit parent — request-lifecycle
-        spans must tie to THEIR request's tree, never to whatever step span
-        happens to be current), or a no-op when tracing is off."""
-        if self._tracer is None:
-            return contextlib.nullcontext()
+        """A ``tracer.span`` context with an explicit parent — request-
+        lifecycle spans must tie to THEIR request's tree, never to whatever
+        step span happens to be current."""
         return self._tracer.span(name, parent=parent, **attrs)
 
     def _record_request(self, span: dict, root=None) -> None:
@@ -1511,17 +1516,13 @@ class ContinuousScheduler:
         # under an incoming W3C "traceparent" when the request carries one
         # — the cross-process hook the router tier rides. Invalid headers
         # degrade to a fresh trace (W3C semantics), never an error.
-        root = queue_span = None
-        if self._tracer is not None:
-            from transformer_tpu.obs.trace import SpanContext
-
-            root = self._tracer.start_span(
-                "serve.request", lane="intake",
-                parent=SpanContext.from_traceparent(req.get("traceparent")),
-            )
-            queue_span = self._tracer.start_span(
-                "serve.queue", parent=root, lane="intake"
-            )
+        root = self._tracer.start_span(
+            "serve.request", lane="intake",
+            parent=SpanContext.from_traceparent(req.get("traceparent")),
+        )
+        queue_span = self._tracer.start_span(
+            "serve.queue", parent=root, lane="intake"
+        )
         refused = None  # the refusal message, captured INSIDE the lock —
         # reading self._done[order] back after release would race the
         # scheduler thread's drain_ready() popping it.
@@ -1566,7 +1567,7 @@ class ContinuousScheduler:
                 )
                 if deadline is not None:
                     self._queued_deadlines += 1
-        if refused is not None and root is not None:
+        if refused is not None:
             queue_span.end(error=refused)
             root.end(order=order, error=refused, code=refused_code)
         if self._tel is not None:
@@ -1583,22 +1584,19 @@ class ContinuousScheduler:
         return order
 
     def submit_done(self, resp: dict) -> int:
-        root = None
-        if self._tracer is not None:
-            # Pre-answered (parse/routing) responses still get a (leaf)
-            # span: every output order is accounted for in the trace.
-            root = self._tracer.start_span("serve.request", lane="intake")
+        # Pre-answered (parse/routing) responses still get a (leaf) span:
+        # every output order is accounted for in the trace.
+        root = self._tracer.start_span("serve.request", lane="intake")
         with self._intake_lock:
             order = self._next_order
             self._next_order += 1
             self._done[order] = resp
-        if root is not None:
-            extra = {}
-            if "error" in resp:
-                extra["error"] = resp["error"]
-                if "code" in resp:  # error code, like every error root
-                    extra["code"] = resp["code"]
-            root.end(order=order, **extra)
+        extra = {}
+        if "error" in resp:
+            extra["error"] = resp["error"]
+            if "code" in resp:  # error code, like every error root
+                extra["code"] = resp["code"]
+        root.end(order=order, **extra)
         if self._tel is not None:
             self._m_requests.inc()
             if "error" in resp:
@@ -1743,7 +1741,7 @@ class ContinuousScheduler:
                         p, ("span_admit", "span_prefill"),
                         error=f"{type(e).__name__}: {e}", retried=True,
                     )
-                    if self._tracer is not None and p.span_queue is None:
+                    if p.span_queue is None:
                         p.span_queue = self._tracer.start_span(
                             "serve.queue", parent=p.span_root, lane="intake",
                             attempt=p.attempts,
@@ -1755,8 +1753,7 @@ class ContinuousScheduler:
                             "backoff_ms": round(wait_ms, 3),
                             "error": f"{type(e).__name__}: {e}",
                         }
-                        if p.span_root is not None:
-                            retry_ev["trace"] = p.span_root.ctx.trace_id
+                        retry_ev["trace"] = p.span_root.ctx.trace_id
                         self._tel.emit("serve.retry", **retry_ev)
                     continue
                 self._answer_admission_error(p, e, now)
@@ -1948,18 +1945,10 @@ class ContinuousScheduler:
         finally:
             self._breaker_trace = None
 
-    def _start_inner(self, p: _Pending) -> None:
-        order, req, t_enq = p.order, p.req, p.t_enqueue
-        maybe_fail("serve.prefill")  # chaos point: admission-time fault
-        if self._tracer is not None:
-            # The queue phase ends here (a retry re-opens it); everything
-            # from validation through the first pick is the admit span.
-            # Faults from here on feed breakers under this request's name.
-            self._end_spans(p, ("span_queue",))
-            p.span_admit = self._tracer.start_span(
-                "serve.admit", parent=p.span_root, lane="intake"
-            )
-            self._breaker_trace = p.span_root.ctx.trace_id
+    def _validate(self, req: dict, t_enq: float):
+        """Tokenize and validate one request before a slot is popped, so a
+        bad request answers alone: ``(ids, max_new, deadline, (sample,
+        temperature, top_k, top_p, seed))``."""
         prompt = str(req["prompt"])
         ids = [self.tok.bos_id, *self.tok.encode(prompt)]
         L = len(ids)
@@ -2017,6 +2006,23 @@ class ContinuousScheduler:
                 "cache refuses — resend with cache_prefix=false or serve "
                 "without attention_window"
             )
+        return ids, max_new, deadline, (sample, temperature, top_k, top_p, seed)
+
+    def _start_inner(self, p: _Pending) -> None:
+        order, req, t_enq = p.order, p.req, p.t_enqueue
+        maybe_fail("serve.prefill")  # chaos point: admission-time fault
+        # The queue phase ends here (a retry re-opens it); everything from
+        # validation through the first pick is the admit span. Faults from
+        # here on feed breakers under this request's name.
+        self._end_spans(p, ("span_queue",))
+        p.span_admit = self._tracer.start_span(
+            "serve.admit", parent=p.span_root, lane="intake"
+        )
+        self._breaker_trace = p.span_root.ctx.trace_id
+        with self._traced("admit.encode", p.span_admit, lane="intake"):
+            ids, max_new, deadline, sampling = self._validate(req, t_enq)
+        L = len(ids)
+        sample, temperature, top_k, top_p, seed = sampling
         use_prefix = (
             self.prefix_cache is not None
             and bool(req.get("cache_prefix", True))
@@ -2038,8 +2044,7 @@ class ContinuousScheduler:
                 ) as msp:
                     hit = self.prefix_cache.match(ids[: L - 1])
                     m = hit.tokens
-                    if msp is not None:
-                        msp.set(hit_tokens=m)
+                    msp.set(hit_tokens=m)
             except Exception:  # noqa: BLE001  # tpa: disable=TPA006 — prefix reuse is an optional accelerator: ANY cache failure (corrupt block, injected fault, trie bug) feeds the breaker and degrades THIS admission to full prefill; it must never answer the request with an error
                 self._brk_prefix.record_failure()
                 prefix_ok = False
@@ -2048,14 +2053,13 @@ class ContinuousScheduler:
         n = m + n_suffix
         slot = self._free.pop()
         t_admit = time.perf_counter()
-        if self._tracer is not None:
-            # The slot is known now: the request's remaining lifecycle
-            # renders on this slot's lane (admit/queue stay on intake —
-            # they are scheduler work, not slot residency).
-            p.span_root.lane = f"slot{slot}"
-            p.span_prefill = self._tracer.start_span(
-                "serve.prefill", parent=p.span_root, lane=f"slot{slot}",
-            )
+        # The slot is known now: the request's remaining lifecycle renders
+        # on this slot's lane (admit/queue stay on intake — they are
+        # scheduler work, not slot residency).
+        p.span_root.lane = f"slot{slot}"
+        p.span_prefill = self._tracer.start_span(
+            "serve.prefill", parent=p.span_root, lane=f"slot{slot}",
+        )
         aliased = 0
         try:
             if m:
@@ -2097,23 +2101,31 @@ class ContinuousScheduler:
                 from transformer_tpu.kernels.kv_pool import KVPoolExhausted
 
                 try:
-                    self._paged_ensure(slot, n)
-                    self._paged_cow(slot, m, n)
+                    with self._traced(
+                        "admit.blocks", p.span_admit, lane="intake"
+                    ):
+                        self._paged_ensure(slot, n)
+                        self._paged_cow(slot, m, n)
                 except KVPoolExhausted as e:
                     raise TransientError(str(e)) from e
-                logits, self.pool.caches = self._fn_slot_prefill_paged(
-                    self.params, self.pool.caches,
-                    self.pool.alloc.table_device(), jnp.int32(slot),
-                    jnp.asarray([ids[m:n]], jnp.int32), jnp.int32(m),
-                    self.cfg, self.prefill_chunk,
-                    self.pool.block_tokens, self.pool.buf_len,
-                )
-            else:
-                logits, self.pool.caches = self._fn_slot_prefill(
-                    self.params, self.pool.caches, jnp.int32(slot),
-                    jnp.asarray([ids[m:n]], jnp.int32), jnp.int32(m), self.cfg,
-                    self.prefill_chunk,
-                )
+            # Returns at enqueue: the prefill runs on while the host goes on.
+            with self._traced(
+                "admit.prefill_dispatch", p.span_admit, lane="intake"
+            ):
+                if self.paged:
+                    logits, self.pool.caches = self._fn_slot_prefill_paged(
+                        self.params, self.pool.caches,
+                        self.pool.alloc.table_device(), jnp.int32(slot),
+                        jnp.asarray([ids[m:n]], jnp.int32), jnp.int32(m),
+                        self.cfg, self.prefill_chunk,
+                        self.pool.block_tokens, self.pool.buf_len,
+                    )
+                else:
+                    logits, self.pool.caches = self._fn_slot_prefill(
+                        self.params, self.pool.caches, jnp.int32(slot),
+                        jnp.asarray([ids[m:n]], jnp.int32), jnp.int32(m),
+                        self.cfg, self.prefill_chunk,
+                    )
         except Exception:
             if self.paged:
                 self.pool.alloc.free_slot(slot)
@@ -2162,10 +2174,9 @@ class ContinuousScheduler:
                 else None
             ),
             t_enqueue=t_enq or t_admit, t_admit=t_admit,
-            # Dispatch-time edge: under async dispatch the prefill has been
-            # ENQUEUED here, not finished; the full-prefill path syncs just
-            # below at the first pick, making the span exact there.
-            t_prefill=time.perf_counter(),
+            # Stamped where the whole prompt is in cache: below, after the
+            # first pick's sync, for a full prefill; at the boundary step
+            # for a chunked (tail-fed) one.
             deadline=deadline,
             # Span ownership transfers from the _Pending to the slot state:
             # from here on, answer paths close through st, not p.
@@ -2174,13 +2185,15 @@ class ContinuousScheduler:
         p.span_root = p.span_prefill = None
         self._active[slot] = st
         self.stats["max_active"] = max(self.stats["max_active"], len(self._active))
-        self._end_spans(
-            p, ("span_admit",), slot=slot, prefix_hit_tokens=st.prefix_hit
+        p.span_admit.set(
+            slot=slot, prefix_hit_tokens=st.prefix_hit, prompt_tokens=L,
+            prefill_tokens=n_suffix,
         )
         if deadline is not None and time.perf_counter() >= deadline:
             # Prefill-boundary deadline check: the prompt ingest alone
             # consumed the budget — answer now instead of decoding tokens
             # the client has already given up on.
+            self._end_spans(p, ("span_admit",))
             self.stats["admitted"] += 1
             if self._tel is not None:
                 self._m_admissions.inc()
@@ -2190,15 +2203,21 @@ class ContinuousScheduler:
             return
         if n < L:
             st.cur = ids[n]  # un-prefilled prompt tail feeds token-by-token
+            self._end_spans(p, ("span_admit",))
         else:
             try:
-                tokv = int(
-                    _pick_one(
-                        logits, jnp.asarray(st.key), jnp.int32(n - 1),
-                        jnp.float32(st.temperature),
-                        sample=st.sample, top_k=st.top_k, top_p=st.top_p,
+                # int() waits for the prefill and the pick: the one sync of
+                # an admission.
+                with self._traced(
+                    "admit.first_pick", p.span_admit, lane="intake"
+                ):
+                    tokv = int(
+                        _pick_one(
+                            logits, jnp.asarray(st.key), jnp.int32(n - 1),
+                            jnp.float32(st.temperature),
+                            sample=st.sample, top_k=st.top_k, top_p=st.top_p,
+                        )
                     )
-                )
             except Exception:
                 # The pick failing must not leak the slot: restore the pool
                 # so the error answers this request alone (admit() catches;
@@ -2210,10 +2229,11 @@ class ContinuousScheduler:
                 self._free.append(slot)
                 p.span_root, p.span_prefill = st.span_root, st.span_prefill
                 raise
-            if self._tracer is not None:
-                # The pick above synced the prefill: the whole prompt is in
-                # cache, decoding starts now.
-                self._trace_prefill_done(st)
+            # The pick above synced the prefill: the whole prompt is in
+            # cache, decoding starts now.
+            st.t_prefill = time.perf_counter()
+            self._end_spans(p, ("span_admit",))
+            self._trace_prefill_done(st)
             self._consume_pick(slot, st, tokv)
         self.stats["admitted"] += 1
         if self._tel is not None:
@@ -2226,6 +2246,33 @@ class ContinuousScheduler:
         slot on the plain path, up to ``speculate_k + 1`` on the
         speculative verify path. Retires finished slots; no-op when the
         pool is idle."""
+        if not self._active:
+            # An idle pool leaves no span: a serve loop polls it thousands
+            # of times a second.
+            self._step_prepare()
+            self._step_publish()
+            return
+        with self._tracer.span(
+            "scheduler.step", lane="scheduler",
+            active=len(self._active), backlog=len(self._queue),
+        ) as step_span:
+            with self._tracer.span("step.prepare", lane="scheduler") as sp:
+                preempted = self.stats["kv_preempted"]
+                self._step_prepare()
+                sp.set(preempted=self.stats["kv_preempted"] - preempted)
+            # The slots this step feeds (prepare may have expired some).
+            step_span.set(active=len(self._active))
+            if not self._active:
+                with self._tracer.span(
+                    "step.bookkeep", lane="scheduler", retired=0
+                ):
+                    self._step_publish()
+            elif self.speculate_k:
+                self._step_verify(step_span)
+            else:
+                self._step_plain(step_span)
+
+    def _step_prepare(self) -> None:
         self._expire(time.perf_counter())
         # The step-boundary weight flip: no-op unless a verified stage is
         # pending AND the expiry sweep just drained the last slot.
@@ -2235,111 +2282,124 @@ class ContinuousScheduler:
             # pool-exhausted slot is preempted here (answered "resource")
             # and must not be stepped.
             self._paged_prepare(self.speculate_k + 1 if self.speculate_k else 1)
-        if not self._active:
-            if self._tel is not None:
-                self._m_active.set(0)
-                self._m_backlog.set(len(self._queue))
-                self._m_ready.set(len(self._done))
-                self._paged_gauges()
-                self._tel.maybe_flush()
-                if self._slo is not None:
-                    self._slo.maybe_evaluate()
-            return
-        if self.speculate_k:
-            self._step_verify()
-        else:
-            self._step_plain()
 
-    def _step_plain(self) -> None:
-        t_step = time.perf_counter()
-        step_span = None
-        if self._tracer is not None:
-            step_span = self._tracer.start_span(
-                "scheduler.step", lane="scheduler",
-                active=len(self._active), backlog=len(self._queue),
-            )
-        N = self.num_slots
-        toks = np.full((N,), PAD_ID, np.int32)
-        keys = np.zeros((N, *np.shape(jax.random.PRNGKey(0))), np.uint32)
-        positions = np.zeros((N,), np.int32)
-        temps = np.ones((N,), np.float32)
-        for slot, st in self._active.items():
-            toks[slot] = st.cur
-            keys[slot] = st.key
-            positions[slot] = st.pos
-            temps[slot] = st.temperature
+    def _step_publish(self) -> None:
+        """The end of every step: gauges, the periodic sinks, the SLO tick."""
+        if self._tel is None:
+            return
+        self._m_active.set(len(self._active))
+        self._m_backlog.set(len(self._queue))
+        self._m_ready.set(len(self._done))
+        self._paged_gauges()
+        self._tel.maybe_flush()
+        if self._slo is not None:
+            self._slo.maybe_evaluate()
+
+    def _dispatch_pool_step(self, table, positions, toks):
+        """Enqueue the one pool-step program this scheduler's layout and
+        kernel choice fixed: ``(logits, new pool caches)``."""
         if self.paged and self.decode_kernel == "paged_flash":
-            logits, self.pool.caches = _pool_step_paged_flash(
-                self.params, self.pool.caches,  # tpa: disable=TPA005 — exclusive if/elif/else triplet: exactly one branch runs per step and all rebind self.pool.caches from their own result
-                self.pool.alloc.table_device(), jnp.asarray(positions),
-                jnp.asarray(toks), self.cfg,
+            return _pool_step_paged_flash(
+                self.params, self.pool.caches,  # tpa: disable=TPA005 — exclusive branches: exactly one runs per step and the caller rebinds self.pool.caches from its result
+                table, positions, toks, self.cfg,
                 self.pool.block_tokens, self._kernel_interpret,
             )
-        elif self.paged:
-            logits, self.pool.caches = self._fn_pool_step_paged(
-                self.params, self.pool.caches,  # tpa: disable=TPA005 — exclusive if/elif/else triplet: exactly one branch runs per step and all rebind self.pool.caches from their own result
-                self.pool.alloc.table_device(), jnp.asarray(positions),
-                jnp.asarray(toks), self.cfg,
+        if self.paged:
+            return self._fn_pool_step_paged(
+                self.params, self.pool.caches,  # tpa: disable=TPA005 — exclusive branches: exactly one runs per step and the caller rebinds self.pool.caches from its result
+                table, positions, toks, self.cfg,
                 self.pool.block_tokens, self.pool.buf_len,
             )
-        else:
-            logits, self.pool.caches = self._fn_pool_step(
-                self.params, self.pool.caches, jnp.asarray(toks), self.cfg
-            )
-        groups: dict[tuple, list[int]] = {}
-        for slot, st in self._active.items():
-            groups.setdefault((st.sample, st.top_k, st.top_p), []).append(slot)
+        return self._fn_pool_step(
+            self.params, self.pool.caches,  # tpa: disable=TPA005 — exclusive branches: exactly one runs per step and the caller rebinds self.pool.caches from its result
+            toks, self.cfg,
+        )
+
+    def _step_plain(self, step_span) -> None:
+        t_step = time.perf_counter()
+        span = partial(self._tracer.span, lane="scheduler")
+        with span("step.build"):
+            N = self.num_slots
+            toks = np.full((N,), PAD_ID, np.int32)
+            keys = np.zeros((N, *np.shape(jax.random.PRNGKey(0))), np.uint32)
+            positions = np.zeros((N,), np.int32)
+            temps = np.ones((N,), np.float32)
+            groups: dict[tuple, list[int]] = {}
+            for slot, st in self._active.items():
+                toks[slot] = st.cur
+                keys[slot] = st.key
+                positions[slot] = st.pos
+                temps[slot] = st.temperature
+                groups.setdefault(
+                    (st.sample, st.top_k, st.top_p), []
+                ).append(slot)
+            # Only what the pool step reads is copied before it is enqueued.
+            d_toks, d_positions = jnp.asarray(toks), jnp.asarray(positions)
+            table = self.pool.alloc.table_device() if self.paged else None
         picks: dict[int, int] = {}
-        for (sample, top_k, top_p), slots in groups.items():
-            out = np.asarray(
-                _pick_pool(
-                    logits, jnp.asarray(keys), jnp.asarray(positions),
-                    jnp.asarray(temps),
+        # One dispatch/fetch pair per sampling group; the first dispatch also
+        # enqueues the pool step, and copies the picks' inputs in AFTER it,
+        # while the device is already at work (a small host-to-device copy
+        # costs the host a quarter of a millisecond on the chip). A dispatch
+        # returns at enqueue, the fetch is the wait for the device.
+        for i, ((sample, top_k, top_p), slots) in enumerate(groups.items()):
+            with span("step.dispatch", programs=1 if i else 2):
+                if i == 0:
+                    logits, self.pool.caches = self._dispatch_pool_step(
+                        table, d_positions, d_toks
+                    )
+                    d_keys, d_temps = jnp.asarray(keys), jnp.asarray(temps)
+                pick = _pick_pool(
+                    logits, d_keys, d_positions, d_temps,
                     sample=sample, top_k=top_k, top_p=top_p,
                 )
-            )
+            with span("step.fetch"):
+                out = np.asarray(pick)
             for slot in slots:
                 picks[slot] = int(out[slot])
-        for slot, st in list(self._active.items()):
-            st.pos += 1
-            st.forwards += 1
-            if st.pos < st.prompt_len:
-                st.cur = st.ids[st.pos]  # still consuming the prompt tail
-                continue
-            if st.pos == st.prompt_len and not st.emitted:
-                # Only reachable for a chunked (tail-fed) prompt: the step
-                # that just ran ingested the FINAL prompt token (and its
-                # logits feed the first pick below) — close the prefill span
-                # here so it covers the whole prompt. Full-prefill slots pick
-                # their first token at admission and skip this transition.
-                st.t_prefill = time.perf_counter()
-                if self._tracer is not None:
+        with span("step.bookkeep") as sp:
+            emitted = continued = walked = 0
+            stepped = len(self._active)
+            for slot, st in list(self._active.items()):
+                st.pos += 1
+                st.forwards += 1
+                if st.pos < st.prompt_len:
+                    st.cur = st.ids[st.pos]  # still consuming the prompt tail
+                    walked += 1
+                    continue
+                if st.pos == st.prompt_len and not st.emitted:
+                    # Only reachable for a chunked (tail-fed) prompt: the
+                    # step that just ran ingested the FINAL prompt token (and
+                    # its logits feed the first pick below) — close the
+                    # prefill span here so it covers the whole prompt.
+                    # Full-prefill slots pick their first token at admission
+                    # and skip this transition.
+                    st.t_prefill = time.perf_counter()
                     self._trace_prefill_done(st)
-            self._consume_pick(slot, st, picks[slot])
-        self.stats["steps"] += 1
-        if step_span is not None:
-            step_span.end()
-        if self._tel is not None:
-            # The np.asarray(_pick_pool) above was a real device sync, so
-            # this window is genuine step time, not dispatch time.
-            dt_step = time.perf_counter() - t_step
-            self._m_step_s.observe(dt_step)
-            self._m_steps.inc()
-            if self._profiler is not None:
-                # One token per slot that picked this step: the honest
-                # token credit for a pool-step dispatch.
-                self._profiler.record(
-                    self._prog_step, dt_step, tokens=len(picks)
-                )
-            self._m_active.set(len(self._active))
-            self._m_backlog.set(len(self._queue))
-            self._m_ready.set(len(self._done))
-            self._paged_gauges()
-            self._tel.maybe_flush()
-            if self._slo is not None:
-                self._slo.maybe_evaluate()
+                first = not st.emitted
+                if self._consume_pick(slot, st, picks[slot]):
+                    emitted += 1
+                    continued += not first
+            self.stats["steps"] += 1
+            if self._tel is not None:
+                # The np.asarray(pick) above was a real device sync, so this
+                # window is genuine step time, not dispatch time.
+                dt_step = time.perf_counter() - t_step
+                self._m_step_s.observe(dt_step)
+                self._m_steps.inc()
+                if self._profiler is not None:
+                    # One token per slot that picked this step: the honest
+                    # token credit for a pool-step dispatch.
+                    self._profiler.record(
+                        self._prog_step, dt_step, tokens=len(picks)
+                    )
+            self._step_publish()
+            sp.set(retired=stepped - len(self._active))
+        # Slots that produced an output token, those of them for which it
+        # was not the first, and slots that only consumed a prompt-tail token.
+        step_span.set(emitted=emitted, continued=continued, walked=walked)
 
-    def _step_verify(self) -> None:
+    def _step_verify(self, step_span) -> None:
         """One speculative verify step: every occupied slot feeds its
         pending token plus up to ``speculate_k`` lookahead tokens — the
         un-ingested prompt tail first (teacher-forced, like chunked
@@ -2353,15 +2413,9 @@ class ContinuousScheduler:
         (tests/test_speculative.py pins this)."""
         t_step = time.perf_counter()
         n_rows = len(self._active)  # rows fed at dispatch (pre-retirement)
-        step_span = draft_span = None
-        if self._tracer is not None:
-            step_span = self._tracer.start_span(
-                "scheduler.step", lane="scheduler",
-                active=len(self._active), backlog=len(self._queue),
-            )
-            draft_span = self._tracer.start_span(
-                "spec.draft", parent=step_span, lane="scheduler",
-            )
+        draft_span = self._tracer.start_span(
+            "spec.draft", parent=step_span, lane="scheduler",
+        )
         N, W = self.num_slots, self.speculate_k + 1
         toks = np.full((N, W), PAD_ID, np.int32)
         keys = np.zeros((N, *np.shape(jax.random.PRNGKey(0))), np.uint32)
@@ -2405,12 +2459,10 @@ class ContinuousScheduler:
             positions[slot] = st.pos
             temps[slot] = st.temperature
         self._breaker_trace = None
-        verify_span = None
-        if draft_span is not None:
-            draft_span.end(drafted=sum(n for _, n in rows.values()))
-            verify_span = self._tracer.start_span(
-                "spec.verify", parent=step_span, lane="scheduler", width=W,
-            )
+        draft_span.end(drafted=sum(n for _, n in rows.values()))
+        verify_span = self._tracer.start_span(
+            "spec.verify", parent=step_span, lane="scheduler", width=W,
+        )
         if self.paged and self.decode_kernel == "paged_flash":
             logits, self.pool.caches = _pool_verify_paged_flash(
                 self.params, self.pool.caches,  # tpa: disable=TPA005 — exclusive if/elif/else triplet: exactly one branch runs per step and all rebind self.pool.caches from their own result
@@ -2493,23 +2545,20 @@ class ContinuousScheduler:
                 # token is the known prompt token at the new position.
                 st.cur = st.ids[st.pos]
                 continue
-            if not st.emitted and st.t_prefill is not None:
+            if not st.emitted:
                 # First generated pick for a tail-fed prompt: this verify
                 # ingested the final prompt token — close the prefill span
                 # here, exactly like the plain path's boundary transition.
                 st.t_prefill = time.perf_counter()
-                if self._tracer is not None:
-                    self._trace_prefill_done(st)
+                self._trace_prefill_done(st)
             for tok in emitted:
                 self._consume_pick(slot, st, tok)
                 if slot not in self._active:
                     break  # retired (EOS / budget): drop the row's tail
-        rollback_span = None
-        if verify_span is not None:
-            verify_span.end(drafted=drafted, accepted=accepted)
-            rollback_span = self._tracer.start_span(
-                "spec.rollback", parent=step_span, lane="scheduler"
-            )
+        verify_span.end(drafted=drafted, accepted=accepted)
+        rollback_span = self._tracer.start_span(
+            "spec.rollback", parent=step_span, lane="scheduler"
+        )
         if self.paged:
             # Paged rollback IS table truncation: blocks past each slot's
             # kept width return to the pool's free list (re-ensured next
@@ -2523,13 +2572,11 @@ class ContinuousScheduler:
             self.pool.caches = self._fn_pool_rollback(
                 self.pool.caches, jnp.asarray(delta)  # tpa: disable=TPA005 — the linter's linear scan pairs this dense-branch donation with the paged verify call above; the branches are mutually exclusive and every donating call rebinds immediately
             )
-        if rollback_span is not None:
-            rollback_span.end()
+        rollback_span.end()
         self.stats["steps"] += 1
         self.stats["drafted"] = self.stats.get("drafted", 0) + drafted
         self.stats["accepted"] = self.stats.get("accepted", 0) + accepted
-        if step_span is not None:
-            step_span.end(drafted=drafted, accepted=accepted)
+        step_span.set(drafted=drafted, accepted=accepted)
         if self._tel is not None:
             dt_step = time.perf_counter() - t_step
             self._m_step_s.observe(dt_step)
@@ -2546,13 +2593,7 @@ class ContinuousScheduler:
                     self._m_spec_accepted.inc(accepted)
                 if drafted - accepted:
                     self._m_spec_rejected.inc(drafted - accepted)
-            self._m_active.set(len(self._active))
-            self._m_backlog.set(len(self._queue))
-            self._m_ready.set(len(self._done))
-            self._paged_gauges()
-            self._tel.maybe_flush()
-            if self._slo is not None:
-                self._slo.maybe_evaluate()
+        self._step_publish()
 
     def _consumable(self, st: _Active, emitted: list[int]) -> int:
         """How many of a verify row's emissions ``_consume_pick`` will
@@ -2569,23 +2610,29 @@ class ContinuousScheduler:
                 break
         return n
 
-    def _consume_pick(self, slot: int, st: _Active, tokv: int) -> None:
+    def _consume_pick(self, slot: int, st: _Active, tokv: int) -> bool:
         """Apply one generated token: retire on EOS or budget exhaustion,
         else schedule it as the slot's next input. The budget check runs
         BEFORE the append so max_new=0 answers with an empty continuation
-        (matching generate(max_new=0))."""
+        (matching generate(max_new=0)). True when the token was emitted
+        to the client (not an EOS, not over budget)."""
         if tokv == self.tok.eos_id or len(st.emitted) >= st.max_new:
             self._finish(slot, st)
-            return
+            return False
         st.emitted.append(tokv)
+        now = time.perf_counter()
         if st.t_first is None:
-            st.t_first = time.perf_counter()
+            st.t_first = now
+        else:
+            st.itl_max = max(st.itl_max or 0.0, now - st.t_last)
+        st.t_last = now
         if self._tel is not None:
             self._m_tokens.inc()
         if len(st.emitted) >= st.max_new:
             self._finish(slot, st)
         else:
             st.cur = tokv
+        return True
 
     def _finish(self, slot: int, st: _Active) -> None:
         # Attribution BEFORE the allow() below: a cooldown-driven
@@ -2611,7 +2658,7 @@ class ContinuousScheduler:
                 try:
                     with self._traced(
                         "prefix.insert", st.span_root,
-                        lane=st.span_root.lane if st.span_root else None,
+                        lane=st.span_root.lane,
                         tokens=aligned,
                     ):
                         if self.paged:
@@ -2669,9 +2716,11 @@ class ContinuousScheduler:
         root = st.span_root
         self._end_spans(st, ("span_prefill",))
         self._end_spans(st, ("span_decode",), new_tokens=len(st.emitted))
+        # The longest gap between two of its output tokens, where it had two.
+        itl = {} if st.itl_max is None else {"itl_max_s": round(st.itl_max, 6)}
         self._end_spans(
             st, ("span_root",), order=st.order,
-            prompt_tokens=st.prompt_len, new_tokens=len(st.emitted),
+            prompt_tokens=st.prompt_len, new_tokens=len(st.emitted), **itl,
         )
         if self._tel is not None or self._span_tap is not None:
             now = time.perf_counter()
@@ -2701,6 +2750,7 @@ class ContinuousScheduler:
                 span["prefill_s"] = round(st.t_prefill - st.t_admit, 6)
             if st.t_first is not None:
                 span["ttft_s"] = round(st.t_first - st.t_enqueue, 6)
+            span.update(itl)
             if self._tel is not None:
                 self._m_queue_s.observe(queue_s)
                 self._m_total_s.observe(total_s)

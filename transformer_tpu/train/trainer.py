@@ -24,6 +24,8 @@ import optax
 
 from transformer_tpu.config import ModelConfig, TrainConfig
 from transformer_tpu.models import transformer_apply
+from transformer_tpu.obs.telemetry import timed_call
+from transformer_tpu.obs.trace import traced_call
 from transformer_tpu.train.checkpoint import CheckpointManager
 from transformer_tpu.train.loss import (
     chunked_cross_entropy_from_hidden,
@@ -31,7 +33,7 @@ from transformer_tpu.train.loss import (
 )
 from transformer_tpu.train.state import TrainState, make_optimizer
 from transformer_tpu.utils.preemption import PreemptionGuard
-from transformer_tpu.utils.profiling import Profiler, StepTimer
+from transformer_tpu.utils.profiling import Profiler, StepTimer, mirrored_tracer
 from transformer_tpu.utils.tensorboard import SummaryWriter
 
 
@@ -575,9 +577,10 @@ class Trainer:
         # points the loop already has (log/eval/epoch boundaries) — zero new
         # device ops, zero recompiles (analysis telemetry_inert contract).
         self.telemetry = telemetry
-        # Tracing (--trace): train.fit/train.step/train.eval/ckpt.* spans on
-        # the "train" lane of the same event log the scheduler traces into.
-        self._tracer = getattr(telemetry, "tracer", None)
+        # Spans (train.fit/train.step/train.data_wait/train.eval/ckpt.*) on
+        # the "train" lane: always into the in-memory buffer, into the event
+        # log under --trace, into the profiler's trace while one is taken.
+        self._tracer = mirrored_tracer(telemetry)
         self._last_metrics: dict | None = None
         self._window_mark = (0, 0, 0.0)  # (steps, tokens, time) at last record
         if telemetry is not None:
@@ -620,8 +623,7 @@ class Trainer:
             eval_step = jax.jit(eval_step)
         self.train_step = train_step
         self.eval_step = eval_step
-        if telemetry is not None:
-            self._wrap_steps_for_dispatch_timing()
+        self._wrap_steps_for_dispatch_timing()
 
     def _wrap_steps_for_dispatch_timing(self) -> None:
         """Route the step callables through ``obs.telemetry.timed_call`` —
@@ -629,20 +631,20 @@ class Trainer:
         async dispatch this histogram measures host dispatch latency (a
         host-stall detector); StepTimer's synced windows stay the
         device-throughput source of truth. DistributedTrainer re-invokes
-        this after swapping in its sharded steps. With tracing on, the same
-        callables additionally run through ``obs.trace.traced_call`` — one
-        ``train.step`` span per dispatch, parented under the open
-        ``train.fit`` span (the contract pins that wrapper's jaxpr inertness
-        too). Both wrappers chain ``__wrapped__``, and every probe that
-        needs the jitted fn unwraps the CHAIN, not one level."""
-        from transformer_tpu.obs.telemetry import timed_call
-
-        self._m_dispatch = self.telemetry.registry.histogram(
-            "train_dispatch_seconds", "host dispatch latency per step call"
-        )
-        self.train_step = timed_call(self.train_step, self._m_dispatch)
-        if self.multi_step is not None:
-            self.multi_step = timed_call(self.multi_step, self._m_dispatch)
+        this after swapping in its sharded steps. With or without a
+        telemetry bundle the same callables run through
+        ``obs.trace.traced_call`` — one ``train.step`` span per dispatch,
+        parented under the open ``train.fit`` span (the contract pins that
+        wrapper's jaxpr inertness too). The wrappers chain ``__wrapped__``,
+        and every probe that needs the jitted fn unwraps the CHAIN, not one
+        level."""
+        if self.telemetry is not None:
+            self._m_dispatch = self.telemetry.registry.histogram(
+                "train_dispatch_seconds", "host dispatch latency per step call"
+            )
+            self.train_step = timed_call(self.train_step, self._m_dispatch)
+            if self.multi_step is not None:
+                self.multi_step = timed_call(self.multi_step, self._m_dispatch)
         profiler = getattr(self.telemetry, "profiler", None)
         if profiler is not None:
             # Third sibling in the chain (same jaxpr-inertness contract):
@@ -658,26 +660,19 @@ class Trainer:
                 self.multi_step = profile_call(
                     self.multi_step, profiler, "train.step"
                 )
-        if self._tracer is not None:
-            from transformer_tpu.obs.trace import traced_call
-
-            self.train_step = traced_call(
-                self.train_step, self._tracer, "train.step", lane="train"
+        self.train_step = traced_call(
+            self.train_step, self._tracer, "train.step", lane="train"
+        )
+        if self.multi_step is not None:
+            self.multi_step = traced_call(
+                self.multi_step, self._tracer, "train.step", lane="train"
             )
-            if self.multi_step is not None:
-                self.multi_step = traced_call(
-                    self.multi_step, self._tracer, "train.step", lane="train"
-                )
 
     # ------------------------------------------------------------------ loop
     def _span(self, name: str, **attrs):
-        """A ``train``-lane tracing span, or a no-op context without a
-        tracer — the trainer's sites all parent via the thread-local stack
-        (everything nests under the ``train.fit`` root)."""
-        if self._tracer is None:
-            import contextlib
-
-            return contextlib.nullcontext()
+        """A ``train``-lane tracing span — the trainer's sites all parent
+        via the thread-local stack (everything nests under the ``train.fit``
+        root)."""
         return self._tracer.span(name, lane="train", **attrs)
 
     def evaluate(
@@ -811,7 +806,16 @@ class Trainer:
                     groups = _dispatch_groups(batch_iter, cfg.steps_per_dispatch)
                 else:
                     groups = ((s, t, 1) for s, t in batch_iter)
-                for src, tgt, k in groups:
+                while True:
+                    # What the loop waits for the input pipeline: one span a
+                    # batch (or dispatch group), and one (``end``) for the
+                    # call that finds the epoch exhausted.
+                    with self._span("train.data_wait") as wait:
+                        group = next(groups, None)
+                        if group is None:
+                            wait.set(end=True)
+                            break
+                    src, tgt, k = group
                     if self.profiler is not None:
                         self.profiler.maybe_trace(step, block_on=self.state)
                     if k == 1:

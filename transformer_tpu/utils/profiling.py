@@ -10,7 +10,8 @@ path. This module is the TPU-native upgrade:
   ``[start_step, start_step + num_steps)`` via ``jax.profiler`` and writes a
   TensorBoard-profile-compatible dump.
 - :func:`annotate` labels host-side regions so they show up on the trace
-  timeline.
+  timeline; :func:`mirrored_tracer` hands it to the span tracer, so every
+  ``tracer.span(...)`` context is also a host event in the profiler's trace.
 - :class:`StepTimer` keeps an online step-duration distribution and
   throughput estimate — the structured replacement for the reference's
   printed per-step deltas.
@@ -18,7 +19,6 @@ path. This module is the TPU-native upgrade:
 
 from __future__ import annotations
 
-import contextlib
 import os
 import time
 
@@ -26,6 +26,7 @@ import jax
 from jax.experimental.compilation_cache import compilation_cache
 
 from transformer_tpu.obs.quantiles import StreamingHistogram
+from transformer_tpu.obs.trace import Tracer, default_tracer
 
 
 # Fixed so that every process of this checkout — CLIs, benchmarks, replica
@@ -105,11 +106,21 @@ class Profiler:
     close = stop
 
 
-@contextlib.contextmanager
 def annotate(name: str):
-    """Label a host-side region on the profiler timeline."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
+    """A context manager that labels a host-side region on the profiler's
+    timeline (a flag test while no profiler session runs)."""
+    return jax.profiler.TraceAnnotation(name)
+
+
+def mirrored_tracer(telemetry) -> Tracer:
+    """The tracer serving and training code records spans into: the
+    telemetry bundle's, or the process's buffer-only default where none was
+    passed. Its ``span()`` contexts are mirrored into the profiler's trace
+    through :func:`annotate` (``obs/`` itself imports no jax)."""
+    tracer = getattr(telemetry, "tracer", None) or default_tracer()
+    if tracer.annotate is None:
+        tracer.annotate = annotate
+    return tracer
 
 
 class StepTimer:
