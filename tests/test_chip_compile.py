@@ -135,6 +135,44 @@ def test_paged_flash_attention(
     assert f"[{num_blocks},{block_tokens},{h_kv},{d}]" in call.group(0)
 
 
+@pytest.mark.parametrize("heads,window", [(72, 512), (48, 0)], ids=["laguna-window", "laguna-full"])
+def test_paged_flash_attention_band(one_chip, heads, window):
+    """The Laguna cell's two layer kinds over one pool: 8 KV heads of 128,
+    pages of 16, a table 256 wide; the window layer's band is static."""
+    from transformer_tpu.kernels.paged_flash import paged_flash_attention
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((5632, 16, 8, 128), BF16)
+
+    def fn(q, k_pool, v_pool, table, lengths):
+        return paged_flash_attention(q, k_pool, v_pool, table, lengths, window=window, interpret=False)
+
+    text = _compile(fn, sds((32, 1, heads, 128), BF16), pool, pool, sds((32, 256), jnp.int32),
+                    sds((32,), jnp.int32)).as_text()
+    assert re.search(r"%paged_flash_attention[\w.]* = [^\n]*custom-call\(", text) and text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("tokens", [32, 2048], ids=["decode", "prefill"])
+def test_moe_expert_ffn_at_laguna_widths(one_chip, tokens):
+    """The dropless expert layer at the cell's shapes: 128 held experts of
+    3 x 3072 x 1024, 10 picks a token over 256; 16-row tiles at decode and
+    128-row tiles at a 2,048-token prefill. The grouped kernel is named."""
+    from transformer_tpu.ops.moe import dropless_tile_rows, moe_apply_dropless, moe_init
+
+    assert dropless_tile_rows(tokens, 10, 256) == (16 if tokens == 32 else 128)
+    p = jax.eval_shape(lambda: moe_init(jax.random.PRNGKey(0), 3072, 1024, 256, BF16, experts_held=128,
+                                        activation="swiglu", shared_dff=1024))
+    x = jax.ShapeDtypeStruct((tokens, 3072), BF16, sharding=one_chip)
+
+    def fn(p, x):
+        return moe_apply_dropless(p, x, num_experts=256, top_k=10, routed_scale=2.5, interpret=False)
+
+    text = _compile(fn, _placed(p, one_chip), x).as_text()
+    assert re.search(r"%moe_expert_ffn[\w.]* = [^\n]*custom-call\(", text) and text.count("tpu_custom_call") == 1
+
+
 def test_fused_ln_ffn(one_chip):
     from transformer_tpu.ops.ffn import ffn_init, fused_ln_ffn
     from transformer_tpu.ops.nn import layernorm_init

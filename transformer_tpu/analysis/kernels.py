@@ -1222,6 +1222,18 @@ def _canned_entries() -> dict[str, Callable[[], tuple[Callable, tuple]]]:
         )
         return fn, (ln, ffn, bf16(2, 8))
 
+    def moe_ffn():
+        # The dropless expert layer's grouped product: 6 row tiles of 16 over
+        # 4 experts, one expert without a token, two dead tiles at the end.
+        from transformer_tpu.kernels.moe_ffn import moe_expert_ffn
+
+        group = jnp.asarray([0, 0, 1, 3, 3, 3], jnp.int32)
+        live = jnp.asarray(4, jnp.int32)
+        fn = lambda x, wg, wi, wo: moe_expert_ffn(  # noqa: E731
+            x, wg, wi, wo, group, live, tile_rows=16, block_dff=128, interpret=True
+        )
+        return fn, (bf16(96, 128), bf16(4, 128, 256), bf16(4, 128, 256), bf16(4, 256, 128))
+
     def _serve_entry(variant):
         # Mirror costs.canned_cost_reports()'s fused paged serve program
         # exactly — the kernels verified here are the ones costs prices.
@@ -1258,6 +1270,7 @@ def _canned_entries() -> dict[str, Callable[[], tuple[Callable, tuple]]]:
         "paged_flash[bf16,streamed]": paged_streamed,
         "ffn.fused[relu,bf16]": ffn_relu,
         "ffn.fused[swiglu,bf16]": ffn_swiglu,
+        "moe.expert_ffn[swiglu,bf16]": moe_ffn,
     }
     for variant in ("lm_bf16", "lm_int8_cache", "lm_gqa"):
         entries[f"serve.pool_step_paged_flash[{variant}]"] = functools.partial(

@@ -24,6 +24,31 @@ PAD_ID = 0
 
 
 @dataclasses.dataclass(frozen=True)
+class AttentionKind:
+    """One kind of self-attention layer in a model whose layers differ
+    (``ModelConfig.attention_kinds`` / ``layer_pattern``): its query heads,
+    its causal window and its rotary frequencies. Every kind shares the
+    model's KV heads and head size, so all layers fit one KV pool."""
+
+    name: str
+    num_heads: int = 0  # query heads; 0 = ModelConfig.num_heads
+    # Causal band over POSITIONAL storage (a full-length cache or the paged
+    # pool, masked at absolute positions) -- unlike the model-wide
+    # ``ModelConfig.attention_window``, which also makes the dense cache roll.
+    window: int = 0
+    rope_base: float = 10000.0
+    # Share of the head that is rotated (its first channels); the rest passes.
+    rotary_share: float = 1.0
+    # YaRN (arXiv:2309.00071) frequency blend; factor 0 = plain rotary.
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    # Multiplies cos and sin (YaRN's attention factor; 1 = none).
+    rope_attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Architecture of one Transformer (encoder-decoder or decoder-only).
 
@@ -39,6 +64,8 @@ class ModelConfig:
     # decode KV cache (and kv parameter count) shrinks by that factor.
     # 0 = num_heads (standard MHA, the reference's attention).
     num_kv_heads: int = 0
+    # Size of one head where it is not d_model / num_heads (0 = that).
+    head_size: int = 0
     dff: int = 1024
     input_vocab_size: int = 32000
     target_vocab_size: int = 32000
@@ -116,9 +143,70 @@ class ModelConfig:
     moe_capacity_factor: float = 1.25
     moe_every: int = 1  # 1 = every layer; 2 = every other layer (GShard style)
     moe_aux_weight: float = 0.01  # load-balance loss weight in the objective
+    # "capacity": the fixed-slot dispatch above, which drops overflow tokens
+    # (training). "dropless": tokens grouped by expert and a grouped product
+    # over the experts that received any (``ops/moe.py moe_apply_dropless``);
+    # gated experts without biases, a shared expert, and this chip's share of
+    # an expert-parallel layer exist only there.
+    moe_dispatch: str = "capacity"  # "capacity" | "dropless"
+    moe_dff: int = 0  # width of one routed expert; 0 = dff
+    moe_shared_dff: int = 0  # width of the shared expert every token takes; 0 = none
+    # Experts held HERE out of the router's ``moe_experts`` (0 = all), and the
+    # id of the first: picks that fall on other chips' experts add nothing.
+    moe_experts_held: int = 0
+    moe_expert_offset: int = 0
+    moe_routed_scale: float = 1.0  # multiplies the renormalised top-k weights
+    moe_leading_dense: int = 0  # leading layers that keep the dense FFN
+    # Seeded (not loaded) weights only: factors on the Glorot draw of the
+    # router's kernel and of the routed experts' out kernels. A trained
+    # router is peaked (a token's first pick carries most of its weight) and
+    # a trained branch is small beside the residual stream; a Glorot draw is
+    # flat and large, and two precisions of one model then part ways at every
+    # near-tie of the last pick and the next. Stand-in weights that should
+    # behave like a checkpoint's set these (PERF.md section 6, PR 28).
+    moe_router_init_scale: float = 1.0
+    moe_out_init_scale: float = 1.0
+    # Block options of the RMSNorm / no-bias families.
+    norm: str = "layernorm"  # "layernorm" | "rmsnorm" (parameter: scale only)
+    use_bias: bool = True  # biases on projections, FFN and the untied head
+    attention_gate: str = ""  # "per_head": sigmoid gate on each head's output
+    # Layers of several kinds: layer i is of kind layer_pattern[i % period],
+    # a name in ``attention_kinds``. Empty = every layer alike (num_heads,
+    # attention_window, rotary base 10,000 over the whole head). JSON lists
+    # and dicts are accepted and stored as tuples, so the config stays a
+    # hashable static argument.
+    layer_pattern: tuple[str, ...] = ()
+    attention_kinds: tuple[AttentionKind, ...] = ()
 
     def __post_init__(self) -> None:
-        if self.d_model % self.num_heads != 0:
+        object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+        object.__setattr__(self, "attention_kinds", tuple(
+            k if isinstance(k, AttentionKind) else AttentionKind(**k)
+            for k in self.attention_kinds
+        ))
+        names = [k.name for k in self.attention_kinds]
+        if len(set(names)) != len(names) or set(self.layer_pattern) - set(names):
+            raise ValueError(
+                f"layer_pattern {self.layer_pattern} must name attention_kinds "
+                f"(distinct names; got {names})"
+            )
+        if bool(self.layer_pattern) != bool(self.attention_kinds):
+            raise ValueError("layer_pattern and attention_kinds come together")
+        if self.layer_pattern and not self.decoder_only:
+            raise ValueError("attention kinds (causal windows, heads by layer) are a decoder-only model's")
+        for k in self.attention_kinds:
+            heads = k.num_heads or self.num_heads
+            if heads % self.kv_heads or k.window < 0 or not 0.0 < k.rotary_share <= 1.0:
+                raise ValueError(f"attention kind {k} does not fit this model")
+            if int(self.head_dim * k.rotary_share) % 2:
+                raise ValueError(f"attention kind {k.name!r} rotates an odd number of channels")
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got {self.norm!r}")
+        if self.attention_gate not in ("", "per_head"):
+            raise ValueError(f"attention_gate must be '' or 'per_head', got {self.attention_gate!r}")
+        if self.moe_dispatch not in ("capacity", "dropless"):
+            raise ValueError(f"moe_dispatch must be 'capacity' or 'dropless', got {self.moe_dispatch!r}")
+        if self.head_size < 0 or (not self.head_size and self.d_model % self.num_heads != 0):
             # Same invariant the reference asserts (``Attention.py:42``).
             raise ValueError(
                 f"d_model ({self.d_model}) must be divisible by num_heads "
@@ -152,20 +240,46 @@ class ModelConfig:
                 f"position_scheme must be 'sinusoidal' or 'rope', got "
                 f"{self.position_scheme!r}"
             )
-        if self.position_scheme == "rope" and (self.d_model // self.num_heads) % 2:
+        if self.position_scheme == "rope" and self.head_dim % 2:
             raise ValueError(
                 "position_scheme='rope' needs an even head_dim "
-                f"(got {self.d_model // self.num_heads})"
+                f"(got {self.head_dim})"
             )
         # Single source of truth for activation names: the op registry.
         from transformer_tpu.ops.ffn import FFN_ACTIVATIONS, is_gated
 
         if self.ffn_activation not in FFN_ACTIVATIONS:
             raise ValueError(f"unknown ffn_activation {self.ffn_activation!r}")
-        if self.moe_experts and is_gated(self.ffn_activation):
+        dropless = self.moe_dispatch == "dropless"
+        # Nothing in the mathematics ties the expert's form to the dispatch:
+        # each dispatch computes the one form its user has (the capacity
+        # einsums the reference's biased ungated FFN, the grouped kernel
+        # ``moe_expert_ffn`` three bias-free matrices), so the other two
+        # pairs are refused here rather than computed as something else.
+        if self.moe_experts and is_gated(self.ffn_activation) != dropless:
             raise ValueError(
-                "MoE experts use the ungated FFN: pick an ungated activation "
-                f"with moe_experts > 0 (got {self.ffn_activation!r})"
+                "capacity-dispatch experts are ungated and dropless experts "
+                f"gated: got ffn_activation={self.ffn_activation!r} with "
+                f"moe_dispatch={self.moe_dispatch!r}"
+            )
+        held = self.experts_held
+        if not dropless and (
+            self.moe_experts_held or self.moe_expert_offset or self.moe_shared_dff
+            or self.moe_dff or self.moe_routed_scale != 1.0
+        ):
+            raise ValueError(
+                "a share of the experts, a shared expert, an expert width and a "
+                "routed scale need moe_dispatch='dropless'"
+            )
+        if self.moe_router_init_scale <= 0 or self.moe_out_init_scale <= 0:
+            raise ValueError(
+                "moe_router_init_scale and moe_out_init_scale must be > 0 (got "
+                f"{self.moe_router_init_scale}/{self.moe_out_init_scale})"
+            )
+        if self.moe_expert_offset < 0 or self.moe_expert_offset + held > self.moe_experts:
+            raise ValueError(
+                f"experts {self.moe_expert_offset}..{self.moe_expert_offset + held} "
+                f"held here lie outside the router's {self.moe_experts}"
             )
         if self.attention_impl not in ("xla", "flash", "ring", "ulysses"):
             raise ValueError(f"unknown attention_impl {self.attention_impl!r}")
@@ -189,7 +303,20 @@ class ModelConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.num_heads
+        return self.head_size or self.d_model // self.num_heads
+
+    def layer_kind(self, layer_index: int) -> AttentionKind:
+        """The attention kind of layer ``layer_index``; for a model whose
+        layers are all alike, the one kind its scalar fields describe."""
+        if not self.layer_pattern:
+            return AttentionKind("", self.num_heads, self.attention_window)
+        name = self.layer_pattern[layer_index % len(self.layer_pattern)]
+        kind = next(k for k in self.attention_kinds if k.name == name)
+        return kind if kind.num_heads else dataclasses.replace(kind, num_heads=self.num_heads)
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_experts_held or self.moe_experts
 
     @property
     def kv_heads(self) -> int:

@@ -790,6 +790,7 @@ def paged_attention(
     width: int | None = None,
     block_q: int = 128,
     block_k: int = 128,
+    window: int = 0,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Attention over a paged KV pool through per-sequence block tables.
@@ -820,6 +821,8 @@ def paged_attention(
         beyond every slot's length carry softmax weight exactly 0.0 in
         fp32, so the clamp is bitwise-invisible. Ignored by
         "paged_flash" (the kernel skips out-of-length blocks instead).
+      window: static causal band over absolute positions (0 = none), on
+        the "xla" oracle and in the "paged_flash" kernel.
 
     Returns (N, S_q, H, D) attention outputs in q's dtype.
     """
@@ -831,8 +834,10 @@ def paged_attention(
 
         return paged_flash_attention(
             q, k_pool, v_pool, table, lengths,
-            k_scale=k_scale, v_scale=v_scale, interpret=interpret,
+            k_scale=k_scale, v_scale=v_scale, window=window, interpret=interpret,
         )
+    if window and impl != "xla":
+        raise ValueError(f"paged_attention impl={impl!r} has no window band")
     from transformer_tpu.kernels.kv_pool import gather_block_views
 
     k = gather_block_views(k_pool, table, width=width)  # (N, L, H_kv, D)
@@ -867,5 +872,8 @@ def paged_attention(
     q_pos = (lengths[:, None, None, None] - s_q) + jnp.arange(s_q)[
         None, None, :, None
     ]
-    out, _ = dot_product_attention(q, k, v, positions <= q_pos)
+    visible = positions <= q_pos
+    if window:
+        visible &= positions > q_pos - window
+    out, _ = dot_product_attention(q, k, v, visible)
     return out

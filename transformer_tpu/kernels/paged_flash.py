@@ -236,6 +236,7 @@ def _paged_kernel(
     scale: float,
     quantized: bool,
     streamed: bool,
+    window: int,
 ):
     """Two ways to the same block. ``streamed``: grid (N,), the pools stay in
     HBM and the kernel copies ``pages`` pages a loop step into one of two
@@ -254,6 +255,11 @@ def _paged_kernel(
     col = jax.lax.broadcasted_iota(jnp.int32, (gs, tokens), 1)
     q_pos = (length - s_q) + row % s_q
 
+    def first_block(seq_length):
+        # The block that holds the first position any of the slot's query
+        # rows may see: row 0's band starts at seq_length - s_q - window + 1.
+        return jnp.maximum(seq_length - s_q - window + 1, 0) // tokens
+
     def init():
         m_scr[...] = jnp.full_like(m_scr, _MASKED)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -262,13 +268,16 @@ def _paged_kernel(
     def fold(j, groups):
         k, v = groups[0], groups[1]
         k_sc, v_sc = (groups[2], groups[3]) if quantized else (None, None)
-        _attend(
-            q_ref,
-            _block_heads(k, k_sc, q_ref.dtype),
-            _block_heads(v, v_sc, q_ref.dtype),
-            (j * tokens + col) <= q_pos,
-            m_scr, l_scr, acc_scr, scale,
-        )
+        k_heads = _block_heads(k, k_sc, q_ref.dtype)
+        v_heads = _block_heads(v, v_sc, q_ref.dtype)
+        # Loads first, the mask after: in this order a model without a window
+        # lowers to the kernel the benchmark's accepted cells were measured
+        # with, operation for operation (PERF.md section 6, PR 28).
+        pos = j * tokens + col
+        visible = pos <= q_pos
+        if window:
+            visible &= pos > q_pos - window
+        _attend(q_ref, k_heads, v_heads, visible, m_scr, l_scr, acc_scr, scale)
 
     def finalize():
         out_ref[0] = (acc_scr[...] / l_scr[...][:, :, :1]).astype(out_ref.dtype)
@@ -280,9 +289,14 @@ def _paged_kernel(
         j, nblk = pl.program_id(1), pl.num_programs(1)
         pl.when(j == 0)(init)
 
-        # Blocks that start at or past the slot's length hold no visible
-        # position: no compute, and their pages all resolve to the sink.
-        @pl.when(j * tokens < length)
+        # Blocks that start at or past the slot's length, or end before its
+        # band, hold no visible position: no compute, and their pages all
+        # resolve to the sink.
+        alive = j * tokens < length
+        if window:
+            alive &= j >= first_block(length)
+
+        @pl.when(alive)
         def _block():
             fold(j, [
                 page_refs[i * pages : (i + 1) * pages] for i in range(pools)
@@ -326,7 +340,7 @@ def _paged_kernel(
     @pl.when(s == 0)
     def _prime():
         slot_ref[0] = 0
-        start(0, 0, 0)
+        start(0, first_block(lengths_ref[0]) if window else 0, 0)
 
     init()
     # A slot always runs its first block (lengths >= S_q >= 1 in every
@@ -342,13 +356,19 @@ def _paged_kernel(
 
         @pl.when(nxt_seq < n)
         def _prefetch():
-            start(nxt_seq, jnp.where(last, 0, j + 1), 1 - slot)
+            # (The clamp keeps the read of the next slot's length in range
+            # where there is no next slot; the copy is then not started.)
+            nxt_first = first_block(lengths_ref[jnp.minimum(s + 1, n - 1)]) if window else 0
+            start(nxt_seq, jnp.where(last, nxt_first, j + 1), 1 - slot)
 
         wait(slot)
         fold(j, [[k_buf.at[slot]], [v_buf.at[slot]]])
         return 1 - slot
 
-    slot_ref[0] = jax.lax.fori_loop(0, nblk, block_step, slot_ref[0])
+    # On a window layer the walk starts at the block that holds the band's
+    # first position: work follows min(length, window), not the length.
+    j0 = first_block(length) if window else 0
+    slot_ref[0] = jax.lax.fori_loop(j0, nblk, block_step, slot_ref[0])
     finalize()
 
 
@@ -361,6 +381,7 @@ def paged_flash_attention(
     *,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
+    window: int = 0,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused attention over a paged KV pool, blocks read in place.
@@ -378,6 +399,10 @@ def paged_flash_attention(
       k_scale, v_scale: (num_blocks, B, H_kv, 1) fp32 dequant scales for
         int8 pools (``init_block_pool(quantize=True)`` storage layout); the
         kernel consumes codes + scales directly.
+      window: static causal band (0 = none): a query at position p sees
+        positions ``p - window + 1 .. p``. Blocks that end before a slot's
+        band are neither copied nor computed, on both routes; the positions
+        before the band inside its first block are masked.
       interpret: Pallas interpret mode; default True off-TPU (same
         convention as ``flash_attention``).
 
@@ -439,6 +464,9 @@ def paged_flash_attention(
         def index(s, j, table_ref, lengths_ref):
             page = j * pages + p
             live = page * block_tokens < lengths_ref[s]
+            if window:
+                # Pages that end before the band resolve to the sink too.
+                live &= (page + 1) * block_tokens > lengths_ref[s] - s_q - window + 1
             return (jnp.where(live, table_ref[s, page], 0), 0, 0, 0)
 
         return index
@@ -480,6 +508,7 @@ def paged_flash_attention(
         scale=d**-0.5,
         quantized=quantized,
         streamed=streamed,
+        window=window,
     )
     out = pl.pallas_call(
         kernel,

@@ -24,8 +24,8 @@ from transformer_tpu.ops.attention import init_cache, mha_apply, mha_init
 from transformer_tpu.ops.nn import (
     Params,
     embedding_init,
-    layernorm_apply,
-    layernorm_init,
+    norm_apply,
+    norm_init,
     remat_layer,
 )
 from transformer_tpu.models.encoder import (
@@ -33,7 +33,9 @@ from transformer_tpu.models.encoder import (
     _ffn_sublayer_init,
     _sublayer,
     _token_mask_from,
+    attention_init,
     embed_prologue,
+    layer_rope,
     layer_uses_moe,
 )
 
@@ -43,20 +45,17 @@ def decoder_layer_init(
 ) -> Params:
     k1, k2, k3 = jax.random.split(key, 3)
     params: Params = {
-        "self_mha": mha_init(
-            k1, cfg.d_model, cfg.num_heads, cfg.params_dtype,
-            num_kv_heads=cfg.kv_heads,
-        ),
+        "self_mha": attention_init(k1, cfg, layer_index),
         **_ffn_sublayer_init(k3, cfg, layer_uses_moe(cfg, layer_index)),
-        "ln1": layernorm_init(cfg.d_model, cfg.params_dtype),
-        "ln_ffn": layernorm_init(cfg.d_model, cfg.params_dtype),
+        "ln1": norm_init(cfg.d_model, cfg.params_dtype, cfg.norm),
+        "ln_ffn": norm_init(cfg.d_model, cfg.params_dtype, cfg.norm),
     }
     if not cfg.decoder_only:
         params["cross_mha"] = mha_init(
             k2, cfg.d_model, cfg.num_heads, cfg.params_dtype,
-            num_kv_heads=cfg.kv_heads,
+            num_kv_heads=cfg.kv_heads, head_dim=cfg.head_dim, use_bias=cfg.use_bias,
         )
-        params["ln2"] = layernorm_init(cfg.d_model, cfg.params_dtype)
+        params["ln2"] = norm_init(cfg.d_model, cfg.params_dtype, cfg.norm)
     return params
 
 
@@ -72,6 +71,7 @@ def decoder_layer_apply(
     return_weights: bool = False,
     cache: dict[str, Any] | None = None,
     cross_kv: tuple[jax.Array, jax.Array] | None = None,
+    layer_index: int = 0,
 ) -> tuple[
     jax.Array, jax.Array | None, jax.Array | None, dict[str, Any] | None, jax.Array | None
 ]:
@@ -81,6 +81,9 @@ def decoder_layer_apply(
 
     ``cross_kv`` optionally carries this layer's pre-projected encoder K/V so
     decode steps don't re-project the static encoder output every token.
+    ``layer_index`` (static) picks the layer's attention kind where the
+    model's layers differ: its window and its rotary frequencies (its heads
+    are the parameters' shape).
     """
     r1, r2, r3 = (None, None, None) if rng is None else jax.random.split(rng, 3)
     boxes: list[Any] = [None, None, None]
@@ -91,12 +94,12 @@ def decoder_layer_apply(
             params["self_mha"], h, h, self_mask,
             impl=cfg.attention_impl,
             causal=cache is None,  # cache path builds its own prefix mask
-            window=cfg.attention_window,
+            window=cfg.layer_kind(layer_index).window,
             return_weights=return_weights,
             cache=cache,
             flash_block_q=cfg.flash_block_q,
             flash_block_k=cfg.flash_block_k,
-            rope=cfg.position_scheme == "rope",
+            rope=layer_rope(cfg, layer_index),
         )
         boxes[0], boxes[2] = w, new_cache
         return out
@@ -140,7 +143,7 @@ def decoder_init(key: jax.Array, cfg: ModelConfig, embedding: Params | None = No
         "layers": [decoder_layer_init(keys[i + 1], cfg, i) for i in range(cfg.num_layers)],
     }
     if cfg.norm_scheme == "pre":
-        params["final_ln"] = layernorm_init(cfg.d_model, cfg.params_dtype)
+        params["final_ln"] = norm_init(cfg.d_model, cfg.params_dtype, cfg.norm)
     return params
 
 
@@ -173,18 +176,25 @@ def decoder_apply(
     new_caches: list[dict[str, Any]] | None = [] if caches is not None else None
     aux_total = None
 
-    def layer_call(layer, x, enc_out, self_mask, cross_mask, r, cache, cross_kv):
-        return decoder_layer_apply(
-            layer, x, enc_out, self_mask, cross_mask, cfg,
-            r, deterministic, return_weights, cache=cache, cross_kv=cross_kv,
-        )
+    def call_for(layer_index):
+        def layer_call(layer, x, enc_out, self_mask, cross_mask, r, cache, cross_kv):
+            return decoder_layer_apply(
+                layer, x, enc_out, self_mask, cross_mask, cfg,
+                r, deterministic, return_weights, cache=cache, cross_kv=cross_kv,
+                layer_index=layer_index,
+            )
 
-    if cfg.remat and caches is None:
-        # Training-time only (decode's KV-cache path gains nothing from
-        # recomputation); see cfg.remat docstring.
-        layer_call = remat_layer(layer_call, cfg)
+        if cfg.remat and caches is None:
+            # Training-time only (decode's KV-cache path gains nothing from
+            # recomputation); see cfg.remat docstring.
+            return remat_layer(layer_call, cfg)
+        return layer_call
+
+    # One call per attention kind: layers of one kind share it.
+    period = len(cfg.layer_pattern) or 1
+    calls = [call_for(i) for i in range(min(period, cfg.num_layers))]
     for i, layer in enumerate(params["layers"]):
-        x, w1, w2, new_cache, aux = layer_call(
+        x, w1, w2, new_cache, aux = calls[i % period](
             layer, x, enc_out, self_mask, cross_mask, rngs[i + 1],
             None if caches is None else caches[i],
             None if cross_kvs is None else cross_kvs[i],
@@ -200,7 +210,7 @@ def decoder_apply(
     if aux_total is not None:
         attn_weights["moe_aux_decoder"] = aux_total
     if cfg.norm_scheme == "pre":
-        x = layernorm_apply(params["final_ln"], x, cfg.layernorm_epsilon)
+        x = norm_apply(params["final_ln"], x, cfg.layernorm_epsilon, cfg.norm)
     return x, attn_weights, new_caches
 
 

@@ -21,37 +21,61 @@ import numpy as np
 from transformer_tpu.config import ModelConfig
 from transformer_tpu.ops.attention import mha_apply, mha_init
 from transformer_tpu.ops.ffn import ffn_apply, ffn_init
-from transformer_tpu.ops.moe import moe_apply, moe_init
+from transformer_tpu.ops.moe import moe_apply, moe_apply_dropless, moe_init
 from transformer_tpu.ops.nn import (
     Params,
     dropout,
     embedding_init,
     embedding_lookup,
-    layernorm_apply,
-    layernorm_init,
+    norm_apply,
+    norm_init,
     remat_layer,
 )
-from transformer_tpu.ops.positional import sinusoidal_positional_encoding
+from transformer_tpu.ops.positional import kind_rope, sinusoidal_positional_encoding
 
 
 def layer_uses_moe(cfg: ModelConfig, layer_index: int) -> bool:
     """Whether layer ``layer_index`` (0-based) carries a MoE FFN: every
     ``moe_every``-th layer counting from the top of the cadence (GShard
-    alternates, Switch uses every layer — ``cfg.moe_every`` choses)."""
-    return cfg.moe_experts > 0 and (layer_index + 1) % cfg.moe_every == 0
+    alternates, Switch uses every layer — ``cfg.moe_every`` choses), after
+    the ``cfg.moe_leading_dense`` layers that keep the dense FFN."""
+    return (
+        cfg.moe_experts > 0
+        and layer_index >= cfg.moe_leading_dense
+        and (layer_index + 1) % cfg.moe_every == 0
+    )
+
+
+def attention_init(key: jax.Array, cfg: ModelConfig, layer_index: int) -> Params:
+    """Self-attention parameters of layer ``layer_index``: its kind's heads."""
+    return mha_init(
+        key, cfg.d_model, cfg.layer_kind(layer_index).num_heads, cfg.params_dtype,
+        num_kv_heads=cfg.kv_heads, head_dim=cfg.head_dim, use_bias=cfg.use_bias,
+        gate=cfg.attention_gate == "per_head",
+    )
+
+
+def layer_rope(cfg: ModelConfig, layer_index: int) -> bool | dict:
+    """``mha_apply``'s ``rope`` for layer ``layer_index``: off, or
+    ``apply_rope``'s arguments for its kind (base, rotated share, YaRN; for a
+    model of one kind, the plain rotation at base 10,000)."""
+    return cfg.position_scheme == "rope" and kind_rope(cfg.layer_kind(layer_index))
 
 
 def _ffn_sublayer_init(key: jax.Array, cfg: ModelConfig, use_moe: bool) -> dict:
     if use_moe:
         return {
             "moe": moe_init(
-                key, cfg.d_model, cfg.dff, cfg.moe_experts, cfg.params_dtype
+                key, cfg.d_model, cfg.moe_dff or cfg.dff, cfg.moe_experts,
+                cfg.params_dtype, experts_held=cfg.moe_experts_held,
+                activation=cfg.ffn_activation, shared_dff=cfg.moe_shared_dff,
+                router_scale=cfg.moe_router_init_scale, out_scale=cfg.moe_out_init_scale,
             )
         }
     return {
         "ffn": ffn_init(
             key, cfg.d_model, cfg.dff, cfg.params_dtype,
-            activation=cfg.ffn_activation,
+            activation=cfg.ffn_activation, use_bias=cfg.use_bias,
         )
     }
 
@@ -74,6 +98,8 @@ def _ffn_sublayer_apply(
 ):
     """Dense or MoE FFN, depending on which key the layer params carry; a MoE
     layer's load-balance loss lands in ``aux_box[0]``."""
+    if "moe" in params and cfg.moe_dispatch == "dropless":
+        return dropless_moe(params["moe"], h, cfg, token_mask)[0]
     if "moe" in params:
         y, aux = moe_apply(
             params["moe"], h,
@@ -88,30 +114,37 @@ def _ffn_sublayer_apply(
     return ffn_apply(params["ffn"], h, cfg.ffn_activation)
 
 
+def dropless_moe(moe_params: Params, h: jax.Array, cfg: ModelConfig, token_mask, interpret=None):
+    """``moe_apply_dropless`` with the model's routing constants."""
+    return moe_apply_dropless(
+        moe_params, h,
+        num_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
+        expert_offset=cfg.moe_expert_offset, routed_scale=cfg.moe_routed_scale,
+        activation=cfg.ffn_activation, token_mask=token_mask, interpret=interpret,
+    )
+
+
 def encoder_layer_init(
     key: jax.Array, cfg: ModelConfig, layer_index: int = 0
 ) -> Params:
     k_mha, k_ffn = jax.random.split(key)
     return {
-        "mha": mha_init(
-            k_mha, cfg.d_model, cfg.num_heads, cfg.params_dtype,
-            num_kv_heads=cfg.kv_heads,
-        ),
+        "mha": attention_init(k_mha, cfg, layer_index),
         **_ffn_sublayer_init(k_ffn, cfg, layer_uses_moe(cfg, layer_index)),
-        "ln1": layernorm_init(cfg.d_model, cfg.params_dtype),
-        "ln2": layernorm_init(cfg.d_model, cfg.params_dtype),
+        "ln1": norm_init(cfg.d_model, cfg.params_dtype, cfg.norm),
+        "ln2": norm_init(cfg.d_model, cfg.params_dtype, cfg.norm),
     }
 
 
 def _sublayer(cfg: ModelConfig, params_ln, x, fn, rng, deterministic):
     """Residual sublayer in post-LN (reference wiring) or pre-LN form."""
     if cfg.norm_scheme == "pre":
-        y = fn(layernorm_apply(params_ln, x, cfg.layernorm_epsilon))
+        y = fn(norm_apply(params_ln, x, cfg.layernorm_epsilon, cfg.norm))
         y = dropout(rng, y, cfg.dropout_rate, deterministic)
         return x + y
     y = fn(x)
     y = dropout(rng, y, cfg.dropout_rate, deterministic)
-    return layernorm_apply(params_ln, x + y, cfg.layernorm_epsilon)
+    return norm_apply(params_ln, x + y, cfg.layernorm_epsilon, cfg.norm)
 
 
 def encoder_layer_apply(
@@ -158,7 +191,7 @@ def encoder_init(key: jax.Array, cfg: ModelConfig) -> Params:
         "layers": [encoder_layer_init(keys[i + 1], cfg, i) for i in range(cfg.num_layers)],
     }
     if cfg.norm_scheme == "pre":
-        params["final_ln"] = layernorm_init(cfg.d_model, cfg.params_dtype)
+        params["final_ln"] = norm_init(cfg.d_model, cfg.params_dtype, cfg.norm)
     return params
 
 
@@ -244,5 +277,5 @@ def encoder_apply(
     if aux_total is not None:
         attn_weights["moe_aux_encoder"] = aux_total
     if cfg.norm_scheme == "pre":
-        x = layernorm_apply(params["final_ln"], x, cfg.layernorm_epsilon)
+        x = norm_apply(params["final_ln"], x, cfg.layernorm_epsilon, cfg.norm)
     return x, attn_weights

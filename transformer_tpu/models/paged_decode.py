@@ -27,11 +27,22 @@ rows just written are visible to the attention (lengths = index + S_q) with
 per-row offset causality inside the kernel, which is what serves both
 one-token decode (S_q = 1) and speculative verify (S_q = k + 1).
 
+Layers of several kinds (``cfg.layer_pattern``) run side by side over ONE
+pool and one block table: a kind's query heads are its parameters' shape,
+its rotary frequencies come from the config, and its window is the
+kernel's static band (the loop over a slot's blocks starts at the block
+that holds ``length - window``). A dropless expert layer runs inside the
+step too (``ops/moe.py moe_apply_dropless``: the grouped kernel reads the
+experts the step's tokens hit), free slots routed nowhere; its counts of
+picks held here and of experts hit accumulate in the pool pytree
+(``MOE_COUNTS``), where the scheduler reads them every few steps.
+
 Scope guards (the gather path remains the general fallback): decoder-only
-LM configs, no attention window (the paged-flash kernel has no band mask —
-windowed configs keep the gather path, whose prefix mask carries the band),
-deterministic (dropout-free) decode. MoE FFN layers fall back to the XLA
-sublayer per layer; their attention still runs fused.
+LM configs, no model-wide ``attention_window`` (that option makes the dense
+cache roll, which no paged layout serves), deterministic (dropout-free)
+decode. The residual+LN+FFN kernel serves the LayerNorm-and-bias block;
+an RMSNorm or bias-free dense FFN and a capacity-dispatch MoE layer keep
+the XLA sublayer; their attention still runs fused.
 """
 
 from __future__ import annotations
@@ -47,14 +58,20 @@ from transformer_tpu.kernels.kv_pool import block_row_ids, scatter_rows
 from transformer_tpu.models.encoder import (
     _ffn_sublayer_apply,
     _sublayer,
+    dropless_moe,
     embed_prologue,
-    layer_uses_moe,
+    layer_rope,
 )
 from transformer_tpu.models.transformer import project_logits
-from transformer_tpu.ops.attention import _project, _quantize_kv, kv_buffer_keys
+from transformer_tpu.ops.attention import _project, _quantize_kv, kv_buffer_keys, merge_heads
 from transformer_tpu.ops.ffn import fused_ln_ffn
-from transformer_tpu.ops.nn import Params, layernorm_apply
+from transformer_tpu.ops.nn import Params, norm_apply
 from transformer_tpu.ops.positional import apply_rope
+
+# Key of the pool pytree (in the first expert layer's dict) under which a
+# dropless model's decode steps accumulate int32 [picks held here, experts
+# hit, steps]: the programs return (logits, pools) and nothing else.
+MOE_COUNTS = "moe_counts"
 
 
 def check_paged_flash_config(cfg: ModelConfig) -> None:
@@ -64,8 +81,9 @@ def check_paged_flash_config(cfg: ModelConfig) -> None:
         raise ValueError("paged_flash decode serves decoder-only LM configs")
     if cfg.attention_window:
         raise ValueError(
-            "paged_flash decode has no sliding-window band mask; serve "
-            "attention_window configs with --decode_kernel xla"
+            "paged_flash decode serves no rolling-window cache "
+            "(attention_window); give the window layers an attention kind "
+            "(cfg.layer_pattern), or use --decode_kernel xla"
         )
 
 
@@ -136,21 +154,24 @@ def paged_decode_forward(
 
     x = jax.vmap(embed_one)(toks, index)  # (N, S_q, d_model)
     dtype = x.dtype
-    rope = cfg.position_scheme == "rope"
+    # A free slot sits at index 0 and feeds PAD: it is no token.
+    is_token = jnp.broadcast_to((index > 0)[:, None], (n, s_q))
+    moe_counts = None
 
     new_pools: list[dict[str, Any]] = []
     for i, layer in enumerate(dec["layers"]):
         pool = pool_caches[i]
         pool_box = [pool]
+        rope, window = layer_rope(cfg, i), cfg.layer_kind(i).window
 
-        def self_attn(h, layer=layer, pool_box=pool_box):
+        def self_attn(h, layer=layer, pool_box=pool_box, rope=rope, window=window):
             mp = layer["self_mha"]
             q = _project(mp["query"], h, dtype)
             k = _project(mp["key"], h, dtype)
             v = _project(mp["value"], h, dtype)
             if rope:
                 rot = jax.vmap(
-                    lambda t, off: apply_rope(t[None], off + jnp.arange(s_q))[0]
+                    lambda t, off: apply_rope(t[None], off + jnp.arange(s_q), **rope)[0]
                 )
                 q = rot(q, index)
                 k = rot(k, index)
@@ -159,19 +180,26 @@ def paged_decode_forward(
             quant = {"k_scale": pool["k_scale"], "v_scale": pool["v_scale"]} if "k_scale" in pool else {}
             out = paged_attention(
                 q, pool["k"], pool["v"], table, lengths,
-                impl="paged_flash", interpret=interpret, **quant,
+                impl="paged_flash", window=window, interpret=interpret, **quant,
             )
-            return jnp.einsum(
-                "bshd,hdm->bsm", out, mp["out"]["kernel"].astype(dtype)
-            ) + mp["out"]["bias"].astype(dtype)
+            return merge_heads(mp, out, h)
 
         x = _sublayer(cfg, layer["ln1"], x, self_attn, None, True)
         new_pools.append(pool_box[0])
 
-        if layer_uses_moe(cfg, i):
-            # MoE dispatch is data-dependent routing — its fusion is a
-            # separate kernel. Keep the XLA sublayer; attention above
-            # already ran fused.
+        if "moe" in layer and cfg.moe_dispatch == "dropless":
+            counts_box: list = [None]
+
+            def experts(h, layer=layer, counts_box=counts_box):
+                y, counts_box[0] = dropless_moe(layer["moe"], h, cfg, is_token, interpret)
+                return y
+
+            x = _sublayer(cfg, layer["ln_ffn"], x, experts, None, True)
+            moe_counts = counts_box[0] if moe_counts is None else moe_counts + counts_box[0]
+        elif "moe" in layer or cfg.norm != "layernorm" or not cfg.use_bias:
+            # Capacity dispatch, and the dense FFN of a block the fused
+            # kernel does not express (RMSNorm, no biases): the XLA
+            # sublayer; attention above already ran fused.
             aux_box: list = [None]
             x = _sublayer(
                 cfg, layer["ln_ffn"], x,
@@ -189,6 +217,10 @@ def paged_decode_forward(
                 interpret=interpret,
             )
 
+    for i, old in enumerate(pool_caches):
+        if MOE_COUNTS in old:
+            step = jnp.concatenate([moe_counts, jnp.ones((1,), jnp.int32)])
+            new_pools[i] = dict(new_pools[i], **{MOE_COUNTS: old[MOE_COUNTS] + step})
     if cfg.norm_scheme == "pre":
-        x = layernorm_apply(dec["final_ln"], x, cfg.layernorm_epsilon)
+        x = norm_apply(dec["final_ln"], x, cfg.layernorm_epsilon, cfg.norm)
     return project_logits(params, x, cfg), new_pools

@@ -47,7 +47,7 @@ def transformer_init(key: jax.Array, cfg: ModelConfig) -> Params:
         params = {"encoder": encoder, "decoder": decoder_init(k_dec, cfg, embedding=shared)}
     if not cfg.tie_output:
         params["final"] = dense_init(
-            k_final, cfg.d_model, cfg.target_vocab_size, cfg.params_dtype
+            k_final, cfg.d_model, cfg.target_vocab_size, cfg.params_dtype, cfg.use_bias
         )
     return params
 

@@ -94,6 +94,9 @@ def mha_init(
     num_heads: int,
     param_dtype=jnp.float32,
     num_kv_heads: int | None = None,
+    head_dim: int | None = None,
+    use_bias: bool = True,
+    gate: bool = False,
 ) -> Params:
     """Parameters for multi-head attention: q/k/v projections shaped
     (d_model, heads, head_dim) and an output projection (heads, head_dim,
@@ -102,8 +105,13 @@ def mha_init(
 
     ``num_kv_heads < num_heads`` gives grouped-query/multi-query attention:
     k/v kernels carry only (d_model, kv_heads, head_dim) — fewer parameters
-    and an ``H/H_kv``-times smaller decode KV cache."""
-    head_dim = d_model // num_heads
+    and an ``H/H_kv``-times smaller decode KV cache.
+
+    ``head_dim`` is ``d_model / num_heads`` unless given (a model whose heads
+    are wider than that: the out projection is then (H, D, d_model) with
+    ``H * D != d_model``). ``use_bias=False`` leaves the four biases out;
+    ``gate`` adds the (d_model, H) kernel of a per-head output gate."""
+    head_dim = head_dim or d_model // num_heads
     kv_heads = num_kv_heads or num_heads
     kq, kk, kv, ko = jax.random.split(key, 4)
 
@@ -112,24 +120,47 @@ def mha_init(
         w = glorot_uniform(k, (d_model, fan_out), param_dtype, d_model, fan_out)
         return w.reshape(d_model, heads, head_dim)
 
-    return {
-        "query": {"kernel": proj(kq, num_heads), "bias": jnp.zeros((num_heads, head_dim), param_dtype)},
-        "key": {"kernel": proj(kk, kv_heads), "bias": jnp.zeros((kv_heads, head_dim), param_dtype)},
-        "value": {"kernel": proj(kv, kv_heads), "bias": jnp.zeros((kv_heads, head_dim), param_dtype)},
+    width = num_heads * head_dim
+    params = {
+        "query": {"kernel": proj(kq, num_heads)},
+        "key": {"kernel": proj(kk, kv_heads)},
+        "value": {"kernel": proj(kv, kv_heads)},
         "out": {
-            "kernel": glorot_uniform(ko, (d_model, d_model), param_dtype, d_model, d_model)
+            "kernel": glorot_uniform(ko, (d_model, width), param_dtype, width, d_model)
             .reshape(d_model, num_heads, head_dim)
             .transpose(1, 2, 0),
-            "bias": jnp.zeros((d_model,), param_dtype),
         },
     }
+    if use_bias:
+        for name, heads in (("query", num_heads), ("key", kv_heads), ("value", kv_heads)):
+            params[name]["bias"] = jnp.zeros((heads, head_dim), param_dtype)
+        params["out"]["bias"] = jnp.zeros((d_model,), param_dtype)
+    if gate:
+        params["gate"] = {
+            "kernel": glorot_uniform(
+                jax.random.fold_in(key, 4), (d_model, num_heads), param_dtype, d_model, num_heads
+            )
+        }
+    return params
 
 
 def _project(p: Params, x: jax.Array, dtype) -> jax.Array:
     # (B, S, M) @ (M, H, D) -> (B, S, H, D)
-    return jnp.einsum("bsm,mhd->bshd", x.astype(dtype), p["kernel"].astype(dtype)) + p[
-        "bias"
-    ].astype(dtype)
+    y = jnp.einsum("bsm,mhd->bshd", x.astype(dtype), p["kernel"].astype(dtype))
+    return y + p["bias"].astype(dtype) if "bias" in p else y
+
+
+def merge_heads(params: Params, out: jax.Array, x_q: jax.Array) -> jax.Array:
+    """(B, S, H, D) head outputs -> (B, S, d_model): each head multiplied by
+    its sigmoid gate where the layer has one (computed from the sublayer's
+    own input ``x_q``; arXiv:2505.06708's head-wise output gate), then the
+    out projection."""
+    dtype = out.dtype
+    if "gate" in params:
+        g = jnp.einsum("bsm,mh->bsh", x_q.astype(dtype), params["gate"]["kernel"].astype(dtype))
+        out = out * jax.nn.sigmoid(g.astype(jnp.float32)).astype(dtype)[..., None]
+    y = jnp.einsum("bshd,hdm->bsm", out, params["out"]["kernel"].astype(dtype))
+    return y + params["out"]["bias"].astype(dtype) if "bias" in params["out"] else y
 
 
 def project_kv(params: Params, x_kv: jax.Array, dtype=None) -> tuple[jax.Array, jax.Array]:
@@ -168,7 +199,7 @@ def mha_apply(
     precomputed_kv: tuple[jax.Array, jax.Array] | None = None,
     flash_block_q: int = 128,
     flash_block_k: int = 128,
-    rope: bool = False,
+    rope: bool | dict = False,
 ) -> tuple[jax.Array, jax.Array | None, dict[str, Any] | None]:
     """Multi-head attention forward.
 
@@ -205,7 +236,9 @@ def mha_apply(
         (``ops.positional.apply_rope``) — self-attention only (cross-attention
         callers must leave this False; cached keys are stored rotated, so the
         decode path composes for free). Positions come from ``cache["index"]``
-        when decoding, else ``arange(S_q)``.
+        when decoding, else ``arange(S_q)``. A dict gives ``apply_rope``'s
+        keyword arguments (``ops.positional.kind_rope``: base, rotated share
+        of the head, YaRN); ``True`` is the plain rotation at base 10,000.
 
     Returns ``(out, weights|None, cache|None)``.
     """
@@ -230,9 +263,10 @@ def mha_apply(
 
         offset = cache["index"] if cache is not None else 0
         positions = offset + jnp.arange(x_q.shape[1])
-        q = apply_rope(q, positions)
+        rope_kw = rope if isinstance(rope, dict) else {}
+        q = apply_rope(q, positions, **rope_kw)
         if precomputed_kv is None:
-            k = apply_rope(k, positions)
+            k = apply_rope(k, positions, **rope_kw)
 
     if cache is not None:
         idx = cache["index"]
@@ -400,10 +434,7 @@ def mha_apply(
             mask = cmask if mask is None else jnp.logical_and(mask, cmask)
         out, weights = dot_product_attention(q, k, v, mask, return_weights=return_weights)
 
-    merged = jnp.einsum(
-        "bshd,hdm->bsm", out, params["out"]["kernel"].astype(dtype)
-    ) + params["out"]["bias"].astype(dtype)
-    return merged, weights, cache
+    return merge_heads(params, out, x_q), weights, cache
 
 
 def _quantize_kv(t: jax.Array) -> tuple[jax.Array, jax.Array]:

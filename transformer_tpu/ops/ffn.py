@@ -51,17 +51,18 @@ def ffn_init(
     dff: int,
     param_dtype=jnp.float32,
     activation: str = "relu",
+    use_bias: bool = True,
 ) -> Params:
     # Ungated configs split exactly as before the gated variants existed, so
     # seeded inits stay byte-identical regardless of JAX's split semantics.
     k1, k2 = jax.random.split(key)
     params = {
-        "in": dense_init(k1, d_model, dff, param_dtype),
-        "out": dense_init(k2, dff, d_model, param_dtype),
+        "in": dense_init(k1, d_model, dff, param_dtype, use_bias),
+        "out": dense_init(k2, dff, d_model, param_dtype, use_bias),
     }
     if is_gated(activation):
         params["gate"] = dense_init(
-            jax.random.fold_in(key, 2), d_model, dff, param_dtype
+            jax.random.fold_in(key, 2), d_model, dff, param_dtype, use_bias
         )
     return params
 

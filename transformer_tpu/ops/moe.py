@@ -37,7 +37,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from transformer_tpu.ops.ffn import _ACTIVATIONS
+from transformer_tpu.ops.ffn import _ACTIVATIONS, ffn_apply, ffn_init
 from transformer_tpu.ops.nn import Params, glorot_uniform
 
 # Active mesh for expert-sharding constraints (see ``expert_mesh`` below).
@@ -80,30 +80,54 @@ def moe_init(
     dff: int,
     num_experts: int,
     param_dtype=jnp.float32,
+    *,
+    experts_held: int = 0,
+    activation: str = "relu",
+    shared_dff: int = 0,
+    router_scale: float = 1.0,
+    out_scale: float = 1.0,
 ) -> Params:
-    """Router plus ``num_experts`` independent FFNs stacked on a leading E
-    axis. Per-expert fan-in/fan-out matches ``ffn_init`` so a 1-expert MoE is
-    parameter-for-parameter the dense FFN."""
+    """Router plus the experts' FFNs stacked on a leading E axis. Per-expert
+    fan-in/fan-out matches ``ffn_init`` so a 1-expert MoE is
+    parameter-for-parameter the dense FFN. Each stacked leaf is one random
+    draw (a Python loop over 128 experts, three leaves and four layers is
+    1,536 draws to compile).
+
+    A gated ``activation`` (the dropless layer's experts) gives a third,
+    ``gate`` stack and no biases; ``experts_held`` (0 = all) stacks only this
+    chip's share while the router keeps its full width; ``shared_dff`` adds
+    the shared expert every token takes, a plain gated FFN without biases.
+    ``router_scale`` and ``out_scale`` multiply the Glorot draw of the router's
+    kernel and of the routed experts' out kernels (``ModelConfig``'s
+    ``moe_*_init_scale``: seeded weights that behave like a checkpoint's)."""
+    from transformer_tpu.ops.ffn import is_gated
+
     k_router, k_in, k_out = jax.random.split(key, 3)
-    E = num_experts
+    E = experts_held or num_experts
 
-    def stacked(k, d_in, d_out):
-        keys = jax.random.split(k, E)
-        return jnp.stack(
-            [glorot_uniform(keys[e], (d_in, d_out), param_dtype, d_in, d_out) for e in range(E)]
-        )
+    def scaled(kernel, scale):
+        return kernel if scale == 1.0 else (kernel.astype(jnp.float32) * scale).astype(param_dtype)
 
-    return {
-        "router": {"kernel": glorot_uniform(k_router, (d_model, E), param_dtype, d_model, E)},
-        "in": {
-            "kernel": stacked(k_in, d_model, dff),
-            "bias": jnp.zeros((E, dff), param_dtype),
-        },
-        "out": {
-            "kernel": stacked(k_out, dff, d_model),
-            "bias": jnp.zeros((E, d_model), param_dtype),
-        },
+    def stacked(k, d_in, d_out, scale=1.0):
+        return scaled(glorot_uniform(k, (E, d_in, d_out), param_dtype, d_in, d_out), scale)
+
+    router = glorot_uniform(k_router, (d_model, num_experts), param_dtype, d_model, num_experts)
+    params = {
+        "router": {"kernel": scaled(router, router_scale)},
+        "in": {"kernel": stacked(k_in, d_model, dff)},
+        "out": {"kernel": stacked(k_out, dff, d_model, out_scale)},
     }
+    if not is_gated(activation):
+        params["in"]["bias"] = jnp.zeros((E, dff), param_dtype)
+        params["out"]["bias"] = jnp.zeros((E, d_model), param_dtype)
+        return params
+    params["gate"] = {"kernel": stacked(jax.random.fold_in(key, 3), d_model, dff)}
+    if shared_dff:
+        params["shared"] = ffn_init(
+            jax.random.fold_in(key, 4), d_model, shared_dff, param_dtype,
+            activation=activation, use_bias=False,
+        )
+    return params
 
 
 def expert_capacity(
@@ -113,6 +137,20 @@ def expert_capacity(
     ``S * k / E`` scaled by the capacity factor, at least 1, at most S."""
     even = seq_len * top_k / num_experts
     return max(1, min(seq_len, math.ceil(even * capacity_factor)))
+
+
+def _route(params: Params, x: jax.Array, k: int):
+    """The router both dispatches share, in fp32 from the start: softmax over
+    all experts, the ``k`` largest, renormalised to sum 1 (GShard's top-2
+    convention; ``norm_topk_prob``). Returns (probs (..., E), gates (..., k),
+    expert ids (..., k))."""
+    logits = jnp.einsum(
+        "...m,me->...e", x.astype(jnp.float32), params["router"]["kernel"].astype(jnp.float32)
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, indices = jax.lax.top_k(probs, k)
+    gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+    return probs, gates, indices
 
 
 def moe_apply(
@@ -144,19 +182,12 @@ def moe_apply(
     dtype = x.dtype
 
     # --- routing (fp32: softmax over experts + cumsum bookkeeping) ---------
-    router_logits = jnp.einsum(
-        "bsm,me->bse", x.astype(jnp.float32), params["router"]["kernel"].astype(jnp.float32)
-    )
-    probs = jax.nn.softmax(router_logits, axis=-1)  # (B, S, E)
+    probs, gates, indices = _route(params, x, k)
     live = (
         None
         if token_mask is None
         else jnp.broadcast_to(token_mask.astype(jnp.float32), (B, S))
     )
-
-    gates, indices = jax.lax.top_k(probs, k)  # (B, S, k)
-    # Renormalize over the selected experts (GShard top-2 convention).
-    gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
 
     combine = jnp.zeros((B, S, E, C), jnp.float32)
     counts = jnp.zeros((B, E), jnp.float32)  # slots used so far, per expert
@@ -206,3 +237,96 @@ def moe_apply(
         p = jnp.sum(probs * live[..., None], axis=(0, 1)) / n
     aux = jnp.float32(E) * jnp.sum(f * p)
     return y, aux
+
+
+def dropless_tile_rows(tokens: int, top_k: int, num_experts: int) -> int:
+    """Rows of one tile of the grouped product: the power of two at or above
+    the rows an expert expects (``tokens * top_k / num_experts``), between 16
+    (a packed bf16 sublane tile) and 128 (the MXU's rows)."""
+    expect = tokens * top_k / num_experts
+    rows = 16
+    while rows < expect and rows < 128:
+        rows *= 2
+    return rows
+
+
+def moe_apply_dropless(
+    params: Params,
+    x: jax.Array,
+    *,
+    num_experts: int,
+    top_k: int,
+    expert_offset: int = 0,
+    routed_scale: float = 1.0,
+    activation: str = "swiglu",
+    token_mask: jax.Array | None = None,
+    interpret: bool | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """(..., M) -> ((..., M), int32 [picks that landed here, experts hit]).
+
+    A routed layer of gated experts that drops no token, told which experts
+    it holds: the stacks in ``params`` are experts ``expert_offset ..
+    expert_offset + E_held - 1`` of the router's ``num_experts``. Every token
+    is routed over ALL experts (``_route``); its picks that fall on an expert
+    held here are grouped by expert (a stable sort by expert id, group sizes,
+    each group padded to whole row tiles) and go through one grouped gated
+    FFN (``kernels/moe_ffn.py``) that reads only the experts hit; picks that
+    fall elsewhere add nothing (their chip adds them, in a deployment). The
+    renormalised gate times ``routed_scale`` weighs each expert's OUTPUT; the
+    shared expert, where the layer has one, is added unweighted.
+
+    ``token_mask`` (...,) bool, False = not a token (a free slot): routed
+    nowhere, counted nowhere.
+    """
+    from transformer_tpu.kernels.moe_ffn import moe_expert_ffn
+
+    lead, m = x.shape[:-1], x.shape[-1]
+    xt = x.reshape(-1, m)
+    T = xt.shape[0]
+    k = min(top_k, num_experts)
+    held = params["in"]["kernel"].shape[0]
+    _, gates, indices = _route(params, xt, k)  # (T, k)
+    local = indices - expert_offset
+    here = (local >= 0) & (local < held)
+    if token_mask is not None:
+        here &= token_mask.reshape(-1, 1)
+    weights = jnp.where(here, gates * routed_scale, 0.0)
+
+    # --- group the picks held here by expert; ``held`` marks the others -----
+    eid = jnp.where(here, local, held).reshape(-1).astype(jnp.int32)  # (T*k,)
+    picks = eid.shape[0]
+    tm = dropless_tile_rows(T, k, num_experts)
+    tiles = -(-picks // tm) + min(held, picks)  # sum_e ceil(n_e / tm) at most
+    order = jnp.argsort(eid, stable=True)
+    sorted_eid = eid[order]
+    sizes = jnp.bincount(eid, length=held + 1)[:held]
+    group_tiles = (sizes + tm - 1) // tm
+    tile_end = jnp.cumsum(group_tiles)
+    live_tiles = tile_end[-1]
+    g = jnp.minimum(sorted_eid, held - 1)
+    rank = jnp.arange(picks) - (jnp.cumsum(sizes) - sizes)[g]
+    dest_sorted = jnp.where(
+        sorted_eid < held, (tile_end - group_tiles)[g] * tm + rank, tiles * tm
+    )  # the padded row of each sorted pick; past the end = not held
+    t = jnp.minimum(jnp.arange(tiles), jnp.maximum(live_tiles - 1, 0))
+    tile_group = jnp.minimum(jnp.searchsorted(tile_end, t, side="right"), held - 1)
+
+    source = jnp.full((tiles * tm,), T, jnp.int32).at[dest_sorted].set(
+        (order // k).astype(jnp.int32), mode="drop"
+    )
+    x_pad = jnp.concatenate([xt, jnp.zeros((1, m), xt.dtype)])[source]
+    out = moe_expert_ffn(
+        x_pad, params["gate"]["kernel"].astype(xt.dtype), params["in"]["kernel"].astype(xt.dtype),
+        params["out"]["kernel"].astype(xt.dtype), tile_group, live_tiles,
+        tile_rows=tm, activation=activation, interpret=interpret,
+    )
+    # Back to (token, pick) order: each pick reads its expert's row; a pick
+    # not held here reads row 0, which nobody may have written, and drops it.
+    dest = jnp.zeros((picks,), jnp.int32).at[order].set(dest_sorted.astype(jnp.int32))
+    rows = out[jnp.where(here.reshape(-1), dest, 0)].reshape(T, k, m)
+    rows = jnp.where(here[..., None], rows.astype(jnp.float32), 0.0)
+    y = jnp.einsum("tk,tkm->tm", weights, rows).astype(xt.dtype)
+    if "shared" in params:
+        y = y + ffn_apply(params["shared"], xt, activation)
+    counts = jnp.stack([jnp.sum(here), jnp.sum(sizes > 0)]).astype(jnp.int32)
+    return y.reshape(*lead, m), counts

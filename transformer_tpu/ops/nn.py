@@ -22,18 +22,20 @@ def glorot_uniform(key: jax.Array, shape: tuple[int, ...], dtype, fan_in: int, f
     return jax.random.uniform(key, shape, dtype=dtype, minval=-limit, maxval=limit)
 
 
-def dense_init(key: jax.Array, d_in: int, d_out: int, dtype=jnp.float32) -> Params:
-    return {
-        "kernel": glorot_uniform(key, (d_in, d_out), dtype, d_in, d_out),
-        "bias": jnp.zeros((d_out,), dtype=dtype),
-    }
+def dense_init(
+    key: jax.Array, d_in: int, d_out: int, dtype=jnp.float32, use_bias: bool = True
+) -> Params:
+    params = {"kernel": glorot_uniform(key, (d_in, d_out), dtype, d_in, d_out)}
+    if use_bias:
+        params["bias"] = jnp.zeros((d_out,), dtype=dtype)
+    return params
 
 
 def dense_apply(params: Params, x: jax.Array, dtype=None) -> jax.Array:
+    """``x @ kernel`` plus the bias where the layer has one."""
     dtype = dtype or x.dtype
-    kernel = params["kernel"].astype(dtype)
-    bias = params["bias"].astype(dtype)
-    return jnp.matmul(x.astype(dtype), kernel) + bias
+    y = jnp.matmul(x.astype(dtype), params["kernel"].astype(dtype))
+    return y + params["bias"].astype(dtype) if "bias" in params else y
 
 
 def embedding_init(key: jax.Array, vocab_size: int, d_model: int, dtype=jnp.float32) -> Params:
@@ -70,6 +72,26 @@ def layernorm_apply(params: Params, x: jax.Array, epsilon: float = 1e-6) -> jax.
     normed = (x32 - mean) * jax.lax.rsqrt(var + epsilon)
     out = normed * params["scale"].astype(jnp.float32) + params["bias"].astype(jnp.float32)
     return out.astype(orig_dtype)
+
+
+def norm_init(d: int, dtype=jnp.float32, kind: str = "layernorm") -> Params:
+    """Parameters of ``norm_apply``: RMSNorm has a ``scale`` and no ``bias``."""
+    if kind == "rmsnorm":
+        return {"scale": jnp.ones((d,), dtype=dtype)}
+    return layernorm_init(d, dtype)
+
+
+def norm_apply(
+    params: Params, x: jax.Array, epsilon: float = 1e-6, kind: str = "layernorm"
+) -> jax.Array:
+    """The block's normalisation: LayerNorm, or RMSNorm (Zhang & Sennrich
+    2019: ``x / sqrt(mean(x^2) + eps) * scale``, no mean, no bias), with the
+    statistics in fp32 either way."""
+    if kind == "layernorm":
+        return layernorm_apply(params, x, epsilon)
+    x32 = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + epsilon)
+    return (x32 * inv * params["scale"].astype(jnp.float32)).astype(x.dtype)
 
 
 def dropout(key: jax.Array | None, x: jax.Array, rate: float, deterministic: bool) -> jax.Array:

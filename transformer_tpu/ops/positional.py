@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def sinusoidal_positional_encoding(
@@ -35,10 +36,47 @@ def sinusoidal_positional_encoding(
     return table.astype(dtype)
 
 
+def rope_inv_freq(
+    rotary_dim: int,
+    base: float = 10000.0,
+    yarn_factor: float = 0.0,
+    yarn_original_max_position: int = 0,
+    yarn_beta_fast: float = 32.0,
+    yarn_beta_slow: float = 1.0,
+) -> np.ndarray:
+    """(rotary_dim / 2,) inverse frequencies ``base**(-i / (rotary_dim/2))``.
+
+    ``yarn_factor > 0`` blends them as YaRN does (arXiv:2309.00071, the
+    "NTK-by-parts" rule): channel pairs that turn more than ``beta_fast``
+    times within the original context keep their frequency, those that turn
+    fewer than ``beta_slow`` times have it divided by the factor, and a
+    linear ramp over the pair index joins the two. A pair turns ``n`` times
+    in ``L`` positions at index ``rotary_dim * ln(L / (2 pi n)) / (2 ln base)``;
+    the ramp's ends are that index for ``beta_fast`` rounded down and for
+    ``beta_slow`` rounded up, clipped to the pairs there are. Host numpy in
+    float64: the table is a compile-time constant."""
+    half = rotary_dim // 2
+    inv = base ** (-np.arange(half, dtype=np.float64) / half)
+    if not yarn_factor:
+        return inv.astype(np.float32)
+
+    def pair_index(turns: float) -> float:
+        return rotary_dim * np.log(yarn_original_max_position / (turns * 2 * np.pi)) / (2 * np.log(base))
+
+    low = max(np.floor(pair_index(yarn_beta_fast)), 0)
+    high = min(np.ceil(pair_index(yarn_beta_slow)), rotary_dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (inv / yarn_factor * ramp + inv * (1.0 - ramp)).astype(np.float32)
+
+
 def apply_rope(
     x: jax.Array,
     positions: jax.Array,
     base: float = 10000.0,
+    *,
+    rotary_share: float = 1.0,
+    attention_factor: float = 1.0,
+    **yarn,
 ) -> jax.Array:
     """Rotary position embedding (no reference counterpart — the reference is
     additive-sinusoidal only; RoPE is the long-context extension for the
@@ -51,16 +89,35 @@ def apply_rope(
     interleaved gather, TPU-lane friendly. ``positions`` is (S,) absolute
     token positions (pass ``offset + arange(S)`` during KV-cache decode).
     Angles in fp32; output in x.dtype.
+
+    ``rotary_share < 1`` rotates only the first ``D * rotary_share`` channels
+    (half-split within them) and passes the rest; ``attention_factor``
+    multiplies cos and sin; ``yarn`` are ``rope_inv_freq``'s YaRN arguments.
     """
-    head_dim = x.shape[-1]
-    half = head_dim // 2
-    inv_freq = jnp.power(
-        jnp.float32(base), -jnp.arange(0, half, dtype=jnp.float32) / half
-    )  # (D/2,)
-    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # (S, D/2)
-    cos = jnp.cos(angles)[None, :, None, :]  # (1, S, 1, D/2)
+    rot = int(x.shape[-1] * rotary_share)
+    half = rot // 2
+    inv_freq = jnp.asarray(rope_inv_freq(rot, base, **yarn))  # (rot/2,)
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # (S, rot/2)
+    cos = jnp.cos(angles)[None, :, None, :]  # (1, S, 1, rot/2)
     sin = jnp.sin(angles)[None, :, None, :]
+    if attention_factor != 1.0:
+        cos, sin = cos * attention_factor, sin * attention_factor
     x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., :half], x32[..., half:]
-    rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    x1, x2 = x32[..., :half], x32[..., half:rot]
+    rotated = jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x32[..., rot:]], axis=-1
+    )
     return rotated.astype(x.dtype)
+
+
+def kind_rope(kind) -> dict:
+    """``apply_rope``'s keyword arguments for one ``config.AttentionKind``."""
+    kw = {"base": kind.rope_base, "rotary_share": kind.rotary_share,
+          "attention_factor": kind.rope_attention_factor}
+    if kind.yarn_factor:
+        kw.update(
+            yarn_factor=kind.yarn_factor,
+            yarn_original_max_position=kind.yarn_original_max_position,
+            yarn_beta_fast=kind.yarn_beta_fast, yarn_beta_slow=kind.yarn_beta_slow,
+        )
+    return kw

@@ -123,7 +123,9 @@ import numpy as np
 from transformer_tpu.config import PAD_ID, ModelConfig
 from transformer_tpu.data.seeding import keyed_rng
 from transformer_tpu.models.decoder import init_decoder_caches
+from transformer_tpu.models.encoder import layer_uses_moe
 from transformer_tpu.models.paged_decode import (
+    MOE_COUNTS,
     check_paged_flash_config,
     paged_decode_forward,
 )
@@ -161,6 +163,11 @@ from transformer_tpu.train.decode import (
     sample_token,
 )
 from transformer_tpu.utils.profiling import mirrored_tracer
+
+
+# Decode steps between two fetches of a dropless model's expert counts: a
+# fetch costs the host 0.25 ms or more (PERF.md, PR 26).
+_MOE_READ_EVERY = 32
 
 
 def abstract_pool_caches(cfg: ModelConfig, num_slots: int, max_total: int):
@@ -950,6 +957,25 @@ class ContinuousScheduler:
                 )
             check_paged_flash_config(cfg)
         self.decode_kernel = decode_kernel
+        # ---- counts a model of several layer kinds adds to a step ---------
+        # A windowed kind: the positions its layers attend beside the full
+        # layers' (host arithmetic over the step's positions). A dropless
+        # expert model on the fused step: picks held here and experts hit,
+        # accumulated on the device in the pool pytree (the pool programs
+        # return logits and pools, nothing else) and fetched every
+        # _MOE_READ_EVERY steps, after the step's own sync.
+        self._band = next(
+            (k.window for k in cfg.attention_kinds if k.window), 0
+        )
+        self._moe_layer = None
+        if decode_kernel == "paged_flash" and cfg.moe_dispatch == "dropless":
+            self._moe_layer = next(
+                (i for i in range(cfg.num_layers) if layer_uses_moe(cfg, i)), None
+            )
+        if self._moe_layer is not None:
+            self.pool.caches[self._moe_layer][MOE_COUNTS] = jnp.zeros((3,), jnp.int32)
+        self._moe_read = np.zeros((3,), np.int64)  # the device's totals at the last fetch
+        self._moe_unread = [0, 0]  # steps and stepped slots since then
         self._kernel_interpret = jax.default_backend() != "tpu"
         # ---- program dispatch: module-level jits or sharded twins ---------
         # Unsharded schedulers dispatch the module-level programs (shared
@@ -1188,6 +1214,15 @@ class ContinuousScheduler:
                     "paged KV pool blocks on the free list")
                 self._m_pool_used.set(self.pool.alloc.used_blocks)
                 self._m_pool_free.set(self.pool.alloc.free_blocks)
+                if self._moe_layer is not None:
+                    self._m_moe_assign = reg.counter(
+                        "serve_moe_assignments_total",
+                        "router picks of decode steps that landed on an "
+                        "expert this replica holds")
+                    self._m_moe_hit = reg.counter(
+                        "serve_moe_experts_hit_total",
+                        "experts that received a token, summed over expert "
+                        "layers and decode steps")
                 if prefix_cache is not None:
                     self._m_alias_tokens = reg.counter(
                         "serve_prefix_alias_tokens_total",
@@ -2333,6 +2368,14 @@ class ContinuousScheduler:
                 groups.setdefault(
                     (st.sample, st.top_k, st.top_p), []
                 ).append(slot)
+            if self._band:
+                # Positions this step's attention reads: all of a slot's on a
+                # full layer, the band's on a window layer.
+                lengths = positions[list(self._active)].astype(np.int64) + 1
+                step_span.set(
+                    attn_pos_full=int(lengths.sum()),
+                    attn_pos_band=int(np.minimum(lengths, self._band).sum()),
+                )
             # Only what the pool step reads is copied before it is enqueued.
             d_toks, d_positions = jnp.asarray(toks), jnp.asarray(positions)
             table = self.pool.alloc.table_device() if self.paged else None
@@ -2360,6 +2403,8 @@ class ContinuousScheduler:
         with span("step.bookkeep") as sp:
             emitted = continued = walked = 0
             stepped = len(self._active)
+            if self._moe_layer is not None:
+                self._read_moe_counts(step_span, stepped)
             for slot, st in list(self._active.items()):
                 st.pos += 1
                 st.forwards += 1
@@ -2398,6 +2443,30 @@ class ContinuousScheduler:
         # Slots that produced an output token, those of them for which it
         # was not the first, and slots that only consumed a prompt-tail token.
         step_span.set(emitted=emitted, continued=continued, walked=walked)
+
+    def _read_moe_counts(self, step_span, stepped: int) -> None:
+        """Every ``_MOE_READ_EVERY`` plain steps: one small fetch of the
+        expert layers' counts (the step's own fetch has just synced, so it
+        waits for nothing), onto the step's span and into the registry as
+        what was added since the last fetch."""
+        self._moe_unread[0] += 1
+        self._moe_unread[1] += stepped
+        if self._moe_unread[0] < _MOE_READ_EVERY:
+            return
+        total = np.asarray(self.pool.caches[self._moe_layer][MOE_COUNTS], np.int64)
+        # The device's int32 totals are never reset and wrap (after hours of
+        # serving): what was added since the last fetch is the difference
+        # modulo 2**32, far above what 32 steps can add.
+        assign, hit, steps = ((total - self._moe_read) & 0xFFFFFFFF).tolist()
+        self._moe_read = total
+        step_span.set(
+            moe_assign=assign, moe_hit=hit, moe_steps=steps,
+            moe_tokens=self._moe_unread[1],
+        )
+        self._moe_unread = [0, 0]
+        if self._tel is not None:
+            self._m_moe_assign.inc(assign)
+            self._m_moe_hit.inc(hit)
 
     def _step_verify(self, step_span) -> None:
         """One speculative verify step: every occupied slot feeds its
