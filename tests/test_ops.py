@@ -1,5 +1,8 @@
 """Unit tests for core ops (L1) against NumPy oracles (SURVEY.md §4 plan)."""
 
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,7 +22,7 @@ from transformer_tpu.ops import (
 )
 from transformer_tpu.ops.attention import init_cache
 from transformer_tpu.ops.masks import NEG_INF, attention_bias
-from transformer_tpu.ops.nn import layernorm_apply, layernorm_init
+from transformer_tpu.ops.nn import dropout, layernorm_apply, layernorm_init
 
 
 class TestPositionalEncoding:
@@ -252,3 +255,203 @@ class TestLayerNorm:
         np.testing.assert_allclose(out, expected, atol=1e-4)
         np.testing.assert_allclose(out.mean(-1), 0.0, atol=1e-5)
         np.testing.assert_allclose(out.std(-1), 1.0, atol=1e-2)
+
+
+def _kept(y) -> np.ndarray:
+    return np.asarray(y) != 0
+
+
+def _four_sigma(p: float, n: int) -> float:
+    return 4.0 * (p * (1.0 - p) / n) ** 0.5
+
+
+def _chance(rate: float) -> float:
+    """The share of elements on which two independent masks agree."""
+    return (1 - rate) ** 2 + rate**2
+
+
+class TestDropout:
+    """The mask's statistics and its determinism. The benchmark's training
+    check runs with dropout off, so nothing else holds these."""
+
+    SHAPE = (64, 128, 256)
+    N = int(np.prod(SHAPE))
+
+    # The inputs and the jitted function are made once: 2M elements a call.
+    @staticmethod
+    @functools.lru_cache(maxsize=None)
+    def _x(shape=SHAPE):
+        # No element is 0, so the kept positions can be read off the output.
+        return jax.random.normal(jax.random.PRNGKey(7), shape) + 3.0
+
+    @staticmethod
+    @functools.lru_cache(maxsize=None)
+    def _jitted(rate):
+        return jax.jit(lambda k, v: dropout(k, v, rate, False))
+
+    @classmethod
+    def _drop(cls, key, x, rate):
+        return cls._jitted(rate)(key, x)
+
+    @pytest.mark.parametrize("rate", [0.1, 0.3])
+    def test_kept_share_is_keep(self, rate):
+        kept = _kept(self._drop(jax.random.PRNGKey(0), self._x(), rate))
+        assert abs(kept.mean() - (1 - rate)) < _four_sigma(1 - rate, self.N)
+
+    @pytest.mark.parametrize("rate", [0.1, 0.3])
+    def test_kept_are_scaled_and_dropped_are_zero(self, rate):
+        x = self._x()
+        y = np.asarray(self._drop(jax.random.PRNGKey(1), x, rate))
+        kept = y != 0
+        np.testing.assert_allclose(y[kept], np.asarray(x)[kept] / (1 - rate), rtol=1e-6)
+        assert 0 < (~kept).sum() < self.N
+
+    @pytest.mark.parametrize("rate", [0.1, 0.3])
+    def test_same_key_same_mask_twice_and_under_checkpoint(self, rate):
+        x, key = self._x(), jax.random.PRNGKey(2)
+        first = self._drop(key, x, rate)
+        np.testing.assert_array_equal(first, self._drop(key, x, rate))
+        recomputed = jax.jit(jax.checkpoint(lambda k, v: dropout(k, v, rate, False)))(key, x)
+        np.testing.assert_array_equal(first, recomputed)
+
+    @pytest.mark.parametrize("rate", [0.1, 0.3])
+    def test_gradient_is_the_forward_mask_over_keep(self, rate):
+        """Under ``jax.checkpoint`` too, where the backward pass makes the
+        mask again instead of reading the one the forward pass wrote."""
+        x, key = self._x(), jax.random.PRNGKey(3)
+        kept = _kept(self._drop(key, x, rate))
+        expected = kept.astype(np.float32) / np.float32(1 - rate)
+        for wrap in (lambda f: f, jax.checkpoint):
+            fn = wrap(lambda v: dropout(key, v, rate, False))
+            grad = jax.jit(jax.grad(lambda v: fn(v).sum()))(x)
+            np.testing.assert_allclose(np.asarray(grad), expected, rtol=1e-6)
+
+    @pytest.mark.parametrize("rate", [0.1, 0.3])
+    def test_split_keys_agree_only_by_chance(self, rate):
+        x = self._x()
+        k1, k2 = jax.random.split(jax.random.PRNGKey(4))
+        agree = (_kept(self._drop(k1, x, rate)) == _kept(self._drop(k2, x, rate))).mean()
+        chance = _chance(rate)
+        assert abs(agree - chance) < _four_sigma(chance, self.N)
+
+    @pytest.mark.parametrize("rate", [0.1, 0.3])
+    @pytest.mark.parametrize("prefix", [(32, 128, 256), (64, 128, 128), (64, 64, 256)])
+    def test_one_key_at_two_shapes_agrees_only_by_chance(self, rate, prefix):
+        """The generator counts from its state, so the shape has to be part of
+        the state: else a key used at two widths gives the narrower tensor
+        the wider one's first bits."""
+        key = jax.random.PRNGKey(5)
+        wide = _kept(self._drop(key, self._x(), rate))
+        narrow = _kept(self._drop(key, self._x(prefix), rate))
+        common = tuple(slice(0, n) for n in prefix)
+        agree = (wide[common] == narrow).mean()
+        chance = _chance(rate)
+        assert abs(agree - chance) < _four_sigma(chance, narrow.size)
+
+    @pytest.mark.parametrize(
+        "rate,deterministic", [(0.3, True), (0.0, False)], ids=["deterministic", "rate0"]
+    )
+    def test_identity_returns_the_input_and_never_reads_the_key(self, rate, deterministic):
+        x = self._x((2, 3))
+        assert dropout(object(), x, rate, deterministic) is x
+
+    def test_training_needs_a_key(self):
+        with pytest.raises(ValueError, match="rng key"):
+            dropout(None, self._x((2, 3)), 0.1, False)
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    def test_typed_key_and_dtype(self, dtype):
+        x = self._x((8, 16, 128)).astype(dtype)
+        y = dropout(jax.random.key(6), x, 0.3, False)
+        assert y.dtype == dtype and 0 < _kept(y).mean() < 1
+
+    @pytest.mark.parametrize("rate", [0.1, 0.3])
+    def test_batch_shards_get_different_masks(self, rate):
+        """Under ``jit`` with the batch split over the 8-device mesh, XLA's
+        partitioner must not hand every shard the same bits."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        mesh = Mesh(np.array(jax.devices()[:8]), ("data",))
+        rows = NamedSharding(mesh, P("data"))
+        x = jax.device_put(self._x(), rows)
+        y = jax.jit(
+            lambda k, v: dropout(k, v, rate, False), out_shardings=rows
+        )(jax.random.PRNGKey(8), x)
+        assert y.sharding.is_equivalent_to(rows, y.ndim)
+        kept = _kept(y)
+        assert abs(kept.mean() - (1 - rate)) < _four_sigma(1 - rate, self.N)
+        shards = kept.reshape(8, -1)
+        chance = _chance(rate)
+        for i in range(1, 8):
+            agree = (shards[0] == shards[i]).mean()
+            assert abs(agree - chance) < _four_sigma(chance, shards[0].size), i
+
+
+class TestDropoutInPrograms:
+    """Where the mask's bits are made, read from lowered programs: the train
+    step draws each site's bits with ``rng_bit_generator`` and hashes nothing
+    of an activation's size; the serving programs draw nothing."""
+
+    @staticmethod
+    def _hashed_words(text: str) -> int:
+        """The largest ``ui32`` tensor that a threefry round (``xor``) touches."""
+        largest = 0
+        for line in text.splitlines():
+            if "stablehlo.xor" in line:
+                for dims in re.findall(r"tensor<((?:\d+x)*)ui32>", line):
+                    largest = max(largest, int(np.prod([int(d) for d in dims.split("x") if d])))
+        return largest
+
+    def test_train_step_draws_with_the_generator_and_hashes_only_keys(self):
+        from transformer_tpu.config import TrainConfig
+        from transformer_tpu.train import create_train_state, make_train_step
+
+        cfg = ModelConfig(
+            num_layers=2, d_model=16, num_heads=2, dff=32, input_vocab_size=40,
+            target_vocab_size=48, max_position=16, dropout_rate=0.1, dtype="float32",
+        )
+        tc = TrainConfig(batch_size=4, sequence_length=8, epochs=1, warmup_steps=100)
+        key = jax.random.PRNGKey(0)
+        state = jax.eval_shape(lambda k: create_train_state(k, cfg, tc), key)
+        src = jax.ShapeDtypeStruct((4, 8), jnp.int32)
+        tgt = jax.ShapeDtypeStruct((4, 9), jnp.int32)
+        text = jax.jit(make_train_step(cfg, tc)).lower(state, src, tgt, key).as_text()
+        # Two prologues, two sublayers an encoder layer, three a decoder layer;
+        # the forward pass draws each site's mask and the backward pass draws
+        # it again (it keeps the key, not the mask).
+        sites = 2 + cfg.num_layers * 2 + cfg.num_layers * 3
+        assert text.count("stablehlo.rng_bit_generator") == 2 * sites
+        # The hash runs over the key's words and the generator's state only.
+        assert 0 < self._hashed_words(text) <= 4
+
+    @pytest.mark.parametrize("program", ["_pool_step_paged_flash", "_slot_prefill_paged"])
+    def test_serving_programs_draw_nothing(self, program):
+        """A serving forward is ``deterministic``: ``dropout`` returns its input
+        before it looks at the key, whatever the model's rate."""
+        from transformer_tpu.models.transformer import transformer_init
+        from transformer_tpu.serve import scheduler as sched
+
+        cfg = ModelConfig(
+            num_layers=2, d_model=16, num_heads=2, dff=32, input_vocab_size=64,
+            target_vocab_size=64, max_position=64, decoder_only=True, tie_output=True,
+            dtype="float32", dropout_rate=0.1,
+        )
+        slots, max_total, block = 2, 32, 8
+        params = jax.eval_shape(lambda k: transformer_init(k, cfg), jax.random.PRNGKey(0))
+        pool, table, index = sched.abstract_paged_pool(
+            cfg, slots, max_total, 1 + slots * (max_total // block), block
+        )
+        if program == "_pool_step_paged_flash":
+            toks = jax.ShapeDtypeStruct((slots,), jnp.int32)
+            lowered = sched._pool_step_paged_flash.lower(
+                params, pool, table, index, toks, cfg, block, True
+            )
+        else:
+            scalar = jax.ShapeDtypeStruct((), jnp.int32)
+            prompt = jax.ShapeDtypeStruct((1, 16), jnp.int32)
+            lowered = sched._slot_prefill_paged.lower(
+                params, pool, table, scalar, prompt, scalar, cfg, 8, block, max_total
+            )
+        text = lowered.as_text()
+        assert "rng_bit_generator" not in text
+        assert self._hashed_words(text) == 0

@@ -152,6 +152,19 @@ def test_failover_zero_loss_byte_identical(lm, spec_file, tmp_path):
             router.pump()
             answered.extend(router.drain_ready())
         assert victim.inflight >= 1, "burst drained before the kill window"
+        # A request writes its first span (``serve.queue``) when the victim
+        # takes it up. The victim has just retired a wave; until it takes up
+        # the next, nothing in flight there has a span, and the merged trace
+        # below would find nothing of the victim's. Wait (milliseconds) for
+        # one more request taken up than answered; the rest of the burst
+        # keeps the victim mid-stream.
+        victim_log = tmp_path / f"{victim.name}.jsonl"
+        taken_up = time.time() + 5
+        while (
+            victim_log.read_text().count('"name": "serve.queue"') <= victim.answered
+            and time.time() < taken_up
+        ):
+            time.sleep(0.001)
         os.kill(victim.pid(), signal.SIGKILL)
         killed_name = victim.name
         while router.busy and time.time() < deadline:

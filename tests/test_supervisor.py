@@ -179,10 +179,16 @@ def test_router_ha_takeover_exactly_once(lm, spec_file, tmp_path):
     want = _reference(lm, reqs)
     new_router = None
     try:
+        # Both control ports must be in the journal's beacon before the
+        # cutover: with a warm compile cache the first replica can answer
+        # two requests before the second has said "ready", and the standby
+        # then adopts a fleet of one.
+        deadline = time.time() + 110
+        while any(l.control_port is None for l in links) and time.time() < deadline:
+            router.pump()
         for r in reqs:
             router.submit(dict(r))
         delivered = []
-        deadline = time.time() + 110
         while len(delivered) < 2 and time.time() < deadline:
             router.pump()
             delivered.extend(router.drain_ready())

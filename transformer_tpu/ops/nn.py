@@ -8,6 +8,8 @@ MXU runs at full rate).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -94,6 +96,34 @@ def norm_apply(
     return (x32 * inv * params["scale"].astype(jnp.float32)).astype(x.dtype)
 
 
+@functools.partial(jax.checkpoint, static_argnums=(2,))
+def _drop(key: jax.Array, x: jax.Array, keep: float) -> jax.Array:
+    """One dropout site: each element kept with probability ``keep`` and
+    scaled by ``1 / keep``.
+
+    The mask's bits come from the device's generator (XLA ``RngBitGenerator``),
+    whose four-word state is hashed out of ``key``: the hash runs over four
+    words, not over the activation. The compare is on integers, at 32 bits of
+    resolution. The shape is folded into the state (one word, one hash)
+    because the generator counts from its state: one key at two shapes would
+    otherwise share the stream's start. The stream is XLA's: the same within
+    one compiled program, not across XLA versions, backends or shardings.
+
+    Under ``jax.checkpoint`` the backward pass keeps the key and draws the
+    mask again: the generator is an instruction of its own that writes its
+    words once for every reader, so a second draw costs two passes over the
+    words, where a kept mask costs a byte an element for the whole of the
+    backward pass (1 GB over the 32 sites of a 256 x 128 x 1024 step, which
+    the Transformer-big cell's widest step has not got: PERF.md section 6,
+    PR 29).
+    """
+    shape_word = functools.reduce(lambda h, n: (h * 1000003 + n) % 2**32, x.shape, x.ndim)
+    state = jax.random.bits(jax.random.fold_in(key, shape_word), (4,), jnp.uint32)
+    _, bits = jax.lax.rng_bit_generator(state, x.shape, dtype=jnp.uint32)
+    mask = bits < jnp.uint32(min(round(keep * 2**32), 2**32 - 1))
+    return jnp.where(mask, x / keep, jnp.zeros_like(x))
+
+
 def dropout(key: jax.Array | None, x: jax.Array, rate: float, deterministic: bool) -> jax.Array:
     """Inverted dropout. ``deterministic=True`` (eval) or rate==0 is identity —
     and both must be decided at trace time (static), never via data-dependent
@@ -102,9 +132,7 @@ def dropout(key: jax.Array | None, x: jax.Array, rate: float, deterministic: boo
         return x
     if key is None:
         raise ValueError("dropout in training mode requires an rng key")
-    keep = 1.0 - rate
-    mask = jax.random.bernoulli(key, p=keep, shape=x.shape)
-    return jnp.where(mask, x / keep, jnp.zeros_like(x))
+    return _drop(key, x, 1.0 - rate)
 
 
 def remat_layer(fn, cfg):
