@@ -402,7 +402,7 @@ def one_chip(args, out: str) -> dict:
         "--dtype=bfloat16", "--warmup_steps=100", "--seed=0",
     ]
 
-    # ---- seq2seq: the reference's own model, and the one bench.py times ----
+    # ---- seq2seq: the reference's own model ----
     mark, t0 = clock.mark(), time.perf_counter()
     s2s = os.path.join(out, "seq2seq")
     events = run_train_cli(s2s, [

@@ -3,6 +3,7 @@ flags; explicitly-passed flags always win. Runs in subprocesses because absl
 flags are process-global (a second define_flags() would collide)."""
 
 import dataclasses
+import json
 import os
 import subprocess
 import sys
@@ -25,14 +26,18 @@ print(m.num_layers, m.d_model, m.dff, m.num_heads, m.tie_embeddings,
 """
 
 
-def _materialize(*argv: str) -> list[str]:
+def _run(snippet: str, *argv: str) -> str:
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     out = subprocess.run(
-        [sys.executable, "-c", _SNIPPET, *argv],
+        [sys.executable, "-c", snippet, *argv],
         capture_output=True, text=True, timeout=120, env=env,
     )
     assert out.returncode == 0, out.stderr[-2000:]
-    return out.stdout.strip().split()
+    return out.stdout.strip()
+
+
+def _materialize(*argv: str) -> list[str]:
+    return _run(_SNIPPET, *argv).split()
 
 
 def test_no_preset_keeps_reference_defaults():
@@ -69,34 +74,50 @@ def test_ffn_activation_flag_list_matches_registry():
     assert tuple(_FFN_ACTIVATION_NAMES) == FFN_ACTIVATIONS
 
 
-def test_presets_match_benchmark_configs():
-    """--preset promises the BASELINE benchmark shapes; pin _PRESETS against
-    benchmarks/run.py's _configs so the two tables cannot drift."""
-    import importlib.util
+_CONFIGS_SNIPPET = """
+import dataclasses, json, sys
+from absl import flags
+from transformer_tpu.cli.flags import (
+    define_flags, flags_to_model_config, flags_to_train_config,
+)
+define_flags()
+flags.FLAGS(sys.argv)
+print(json.dumps({
+    "model": dataclasses.asdict(flags_to_model_config(100, 100)),
+    "train": dataclasses.asdict(flags_to_train_config()),
+}, default=str))
+"""
 
+# What each preset promises beyond its table row (cli/flags.py::_PRESETS is
+# the one table): the fields a reader of BASELINE.json's configs expects.
+_PRESET_PROMISES = {
+    "tiny": {"num_layers": 2, "tie_embeddings": False, "label_smoothing": 0.0},
+    "base": {"num_layers": 6, "d_model": 512, "decoder_only": False},
+    "big": {"d_model": 1024, "num_heads": 16, "label_smoothing": 0.1},
+    "tied": {"tie_embeddings": True, "tie_output": True},
+    "long4k": {
+        "decoder_only": True, "attention_impl": "flash",
+        "sequence_length": 4096,
+    },
+}
+
+
+@pytest.mark.parametrize("name", sorted(_PRESET_PROMISES))
+def test_preset_builds_configs(name):
+    """Each --preset folds into flags from which ModelConfig and TrainConfig
+    build, and every value of its table row lands on the config field of
+    the same name."""
     from transformer_tpu.cli.flags import _PRESETS
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "bench_run", os.path.join(repo, "benchmarks", "run.py")
+    assert set(_PRESETS) == set(_PRESET_PROMISES)
+    built = json.loads(
+        _run(_CONFIGS_SNIPPET, f"--preset={name}").splitlines()[-1]
     )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    configs = bench._configs()
-    assert set(_PRESETS) == set(configs)
-    for name, preset in _PRESETS.items():
-        model_cfg, train_cfg, batch, seq = configs[name]
-        assert preset["num_layers"] == model_cfg.num_layers, name
-        assert preset["d_model"] == model_cfg.d_model, name
-        assert preset["num_heads"] == model_cfg.num_heads, name
-        assert preset["dff"] == model_cfg.dff, name
-        assert preset["batch_size"] == batch, name
-        assert preset.get("label_smoothing", 0.0) == train_cfg.label_smoothing, name
-        assert preset.get("tie_embeddings", False) == model_cfg.tie_embeddings, name
-        assert preset.get("decoder_only", False) == model_cfg.decoder_only, name
-        if model_cfg.decoder_only:
-            assert preset.get("attention_impl") == model_cfg.attention_impl, name
-            assert preset.get("sequence_length") == seq, name
+    for field, want in {**_PRESETS[name], **_PRESET_PROMISES[name]}.items():
+        homes = [c for c in ("model", "train") if field in built[c]]
+        assert homes, f"{name}: no config has a field {field!r}"
+        for home in homes:
+            assert built[home][field] == want, (name, home, field)
 
 
 @pytest.mark.slow  # heavyweight: slow tier (fast tier keeps a specimen)
@@ -104,7 +125,6 @@ def test_serve_loop_end_to_end(tmp_path):
     """cli.serve: build a tiny export, pipe mixed raw/JSON/bad requests
     through the loop, get one JSONL response per request with the loop
     surviving the malformed one."""
-    import json
 
     build = f"""
 import jax

@@ -21,7 +21,6 @@ from transformer_tpu.analysis.kernels import (
     analyze_entries,
     compare_kernels_to_baseline,
     default_kernels_baseline_path,
-    program_kernel_vmem,
     run_kernels,
     write_kernels_baseline,
 )
@@ -116,11 +115,6 @@ class TestVmemModel:
     def test_budget_table_generations(self):
         assert VMEM_BUDGETS[DEFAULT_GENERATION] == 16 * 1024 * 1024
         assert VMEM_BUDGETS["v6e"] == 32 * 1024 * 1024
-
-    def test_program_kernel_vmem_hook(self):
-        fn, args = _copy_entry()()
-        vmem = program_kernel_vmem(fn, *args)
-        assert vmem == {"kern": 16384}
 
 
 class TestRuleTwins:

@@ -1,14 +1,11 @@
-"""Performance observatory (``obs/profile.py`` + ``obs/flight.py``): the
-per-program dispatch profiler with its measured-vs-predicted roofline join
-and banked drift bands, the always-on flight recorder with supervisor-
-captured postmortems, the ``/healthz`` endpoint, and the event-catalogue
-AST gate that keeps docs/OBSERVABILITY.md honest."""
+"""The flight recorder (``obs/flight.py``) with supervisor-captured
+postmortems, the ``/healthz`` endpoint, and the event-catalogue AST gate
+that keeps docs/OBSERVABILITY.md honest."""
 
 import ast
 import io
 import json
 import os
-import shutil
 import signal
 import subprocess
 import sys
@@ -25,19 +22,6 @@ from transformer_tpu.obs.flight import (
     flight_path_for,
     load_flight_record,
 )
-from transformer_tpu.obs.profile import (
-    BASELINE_PATH,
-    CANNED_PROGRAMS,
-    ProgramProfiler,
-    band_breaches,
-    load_baseline,
-    measured_from_events,
-    profile_call,
-    roofline_ratio,
-    roofline_report,
-    write_baseline,
-)
-from transformer_tpu.obs.registry import MetricsRegistry
 
 REPO = Path(__file__).resolve().parents[1]
 
@@ -77,288 +61,6 @@ def _scheduler(lm, telemetry, **kw):
     return ContinuousScheduler(
         params, cfg, tok, num_slots=2, max_total=32, default_max_new=4,
         telemetry=telemetry, **kw,
-    )
-
-
-# --------------------------------------------------------------------------
-# the profiler: gauges, drift transitions, the wrapper (no jax)
-
-
-def test_profiler_gauges_export():
-    """Every perf_* family — histogram, token counter, derived measured
-    gauges, roofline ratio, and the drift gauge — lands in the registry's
-    Prometheus exposition (the acceptance criterion)."""
-    reg = MetricsRegistry()
-    baseline = {
-        "programs": {"serve.pool_step": {
-            "p50_s": 0.001, "band": [0.2, 5.0], "bytes_moved": 1000,
-        }},
-    }
-    prof = ProgramProfiler(
-        registry=reg, baseline=baseline, device_kind="TPU v5 lite"
-    )
-    for _ in range(16):
-        prof.record("serve.pool_step", 0.001, tokens=2)
-    text = reg.to_prometheus_text()
-    for metric in (
-        "perf_seconds_serve_pool_step_count 16",
-        "perf_tokens_total_serve_pool_step 32",
-        "perf_measured_tokens_per_s_serve_pool_step",
-        "perf_measured_p50_ms_serve_pool_step",
-        "perf_measured_bytes_per_s_serve_pool_step",
-        "perf_roofline_ratio_serve_pool_step",
-        "perf_drift_serve_pool_step",
-    ):
-        assert metric in text, f"{metric} missing from exposition"
-    # The drift gauge carries measured-p50 / banked-p50 — all samples AT
-    # the banked p50, so the ratio sits inside the band (histogram-bucket
-    # approximation allowed).
-    drift = reg.gauge("perf_drift_serve_pool_step").value
-    assert 0.2 <= drift <= 5.0
-    row = prof.summary()["serve.pool_step"]
-    assert row["dispatches"] == 16 and row["tokens"] == 32.0
-    assert row["drift"] == pytest.approx(drift, rel=1e-6)
-    assert row["roofline_ratio"] == roofline_ratio(
-        1000, row["p50_s"], "TPU v5 lite"
-    ) > 0
-    assert row["tokens_per_s"] > 0
-    # No peak is assumed for a device outside the table: the same samples
-    # on an unknown device export every family EXCEPT the ratio.
-    reg2 = MetricsRegistry()
-    prof2 = ProgramProfiler(registry=reg2, baseline=baseline, device_kind="cpu")
-    for _ in range(16):
-        prof2.record("serve.pool_step", 0.001, tokens=2)
-    assert "perf_roofline_ratio" not in reg2.to_prometheus_text()
-    assert "perf_measured_p50_ms_serve_pool_step" in reg2.to_prometheus_text()
-    assert "roofline_ratio" not in prof2.summary()["serve.pool_step"]
-    assert roofline_ratio(1000, 0.001, "cpu") is None
-    assert roofline_ratio(1000, 0.001, None) is None
-
-
-def test_drift_event_fires_on_transition_only():
-    """A drifting program emits ONE perf.drift per breach-state
-    transition, never per sample (slo.burn's discipline)."""
-    events = []
-    baseline = {"programs": {"train.step": {
-        "p50_s": 0.001, "band": [0.5, 2.0],
-    }}}
-    prof = ProgramProfiler(
-        emit=lambda kind, **f: events.append({"kind": kind, **f}),
-        baseline=baseline,
-    )
-    for _ in range(8):
-        prof.record("train.step", 0.001)
-    assert events == []  # first judgment lands in band: silence
-    for _ in range(64):  # p50 walks 100x out of band — many judged samples
-        prof.record("train.step", 0.1)
-    drifts = [e for e in events if e["kind"] == "perf.drift"]
-    assert len(drifts) == 1, "breach must emit exactly one transition event"
-    assert drifts[0]["program"] == "train.step"
-    assert drifts[0]["breached"] is True
-    assert drifts[0]["ratio"] > 2.0
-    assert drifts[0]["band"] == [0.5, 2.0]
-    assert prof.stats["drift_events"] == 1
-    # A program whose FIRST judgment is already out of band also alerts.
-    events2 = []
-    prof2 = ProgramProfiler(
-        emit=lambda kind, **f: events2.append({"kind": kind, **f}),
-        baseline=baseline,
-    )
-    for _ in range(8):
-        prof2.record("train.step", 0.1)
-    assert [e["kind"] for e in events2] == ["perf.drift"]
-    assert events2[0]["breached"] is True
-
-
-def test_profile_call_wraps_and_records():
-    prof = ProgramProfiler(baseline={})
-
-    def fn(x, y=1):
-        return x + y
-
-    wrapped = profile_call(fn, prof, "serve.pool_step", tokens=3)
-    assert wrapped.__wrapped__ is fn  # the inertness-contract handle
-    assert wrapped(2, y=3) == 5
-    assert prof.stats["records"] == 1
-    row = prof.summary()["serve.pool_step"]
-    assert row["dispatches"] == 1 and row["tokens"] == 3.0
-
-
-def test_baseline_bank_roundtrip(tmp_path):
-    path = str(tmp_path / "bank.json")
-    measured = {
-        "serve.pool_step": {"p50_s": 0.002},
-        "serve.pool_verify": {"p50_s": 0},  # never banked: no honest p50
-    }
-    preds = {"serve.pool_step": {
-        "bytes_moved": 12345, "extras": {"tokens_per_step": 2},
-    }}
-    doc = write_baseline(path, measured, predictions=preds)
-    assert load_baseline(path) == doc
-    entry = doc["programs"]["serve.pool_step"]
-    assert entry["p50_s"] == 0.002
-    assert entry["bytes_moved"] == 12345
-    assert entry["tokens_per_step"] == 2
-    assert entry["band"] == [0.2, 5.0]
-    assert "serve.pool_verify" not in doc["programs"]
-    assert "peak_bytes_per_s" not in doc
-    assert load_baseline(str(tmp_path / "missing.json")) == {}
-
-
-def test_checked_in_baseline_hygiene():
-    """The shipped bank freezes predictions (bytes_moved) and bands but
-    NEVER absolute p50 seconds — those are per-host, banked only by a
-    local ``obs roofline --update`` run."""
-    doc = load_baseline()
-    assert "peak_bytes_per_s" not in doc  # the peak is keyed by device_kind
-    assert doc["programs"], "shipped bank has no programs"
-    for name, entry in doc["programs"].items():
-        assert name in CANNED_PROGRAMS, name
-        assert entry.get("bytes_moved", 0) > 0, name
-        lo, hi = entry["band"]
-        assert 0 < lo < 1 < hi, name
-        assert "p50_s" not in entry, (
-            f"{name}: absolute p50 seconds must not ship in the repo bank"
-        )
-
-
-# --------------------------------------------------------------------------
-# the offline join + the banked-band CLI workflow
-
-
-def _episode_events(
-    p50=0.002, count=16, program="serve.pool_step", roofline=None,
-):
-    from transformer_tpu.obs.quantiles import StreamingHistogram
-
-    suffix = program.replace(".", "_")
-    h = StreamingHistogram()
-    for _ in range(count):
-        h.observe(p50)
-    metrics = {
-        f"perf_seconds_{suffix}": h.snapshot(),
-        f"perf_tokens_total_{suffix}": float(count * 2),
-    }
-    if roofline is not None:
-        # What a profiler running on a device with a known peak exports.
-        metrics[f"perf_roofline_ratio_{suffix}"] = roofline
-    return [{"kind": "metrics.snapshot", "ts": 1.0, "metrics": metrics}]
-
-
-def test_roofline_report_tolerant_join():
-    events = _episode_events()
-    # Measured-only: rows appear with timing columns, nothing else.
-    rows = roofline_report(events, baseline={})["programs"]
-    assert [r["program"] for r in rows] == ["serve.pool_step"]
-    assert rows[0]["dispatches"] == 16 and rows[0]["p50_ms"] > 0
-    assert "roofline_ratio" not in rows[0] and "drift" not in rows[0]
-    # + a costs document: bytes and predicted-tokens columns join in (the
-    # lm_bf16 variant wins when several share a base name).
-    costs = {"programs": [
-        {"name": "serve.pool_step[lm_f32]", "bytes_moved": 7},
-        {"name": "serve.pool_step[lm_bf16]", "bytes_moved": 1000,
-         "extras": {"tokens_per_step": 2}},
-    ]}
-    row = roofline_report(events, costs=costs, baseline={})["programs"][0]
-    assert row["predicted_bytes_moved"] == 1000
-    assert row["effective_bytes_per_s"] == 1000 / row["p50_s"]
-    # The offline join assumes no peak: the ratio appears only when the
-    # live profiler exported one (it ran on a device in the peak table).
-    assert "roofline_ratio" not in row
-    live = roofline_report(
-        _episode_events(roofline=0.25), costs=costs, baseline={},
-    )["programs"][0]
-    assert live["roofline_ratio"] == 0.25
-    assert row["predicted_tokens_per_s"] == pytest.approx(
-        2 / row["p50_s"], rel=1e-3
-    )
-    assert row["measured_over_predicted_tokens"] > 0
-    # + a bank: drift columns judge the band; breaches surface.
-    bank = {"programs": {
-        "serve.pool_step": {"p50_s": row["p50_s"], "band": [0.5, 2.0]},
-    }}
-    report = roofline_report(events, baseline=bank)
-    judged = report["programs"][0]
-    assert judged["drift"] == 1.0 and judged["in_band"] is True
-    assert band_breaches(report) == []
-    bank["programs"]["serve.pool_step"]["p50_s"] = row["p50_s"] / 100
-    report = roofline_report(events, baseline=bank)
-    assert report["programs"][0]["in_band"] is False
-    assert [b["program"] for b in band_breaches(report)] == [
-        "serve.pool_step"
-    ]
-
-
-def test_measured_from_events_last_snapshot_wins():
-    events = _episode_events(count=16) + _episode_events(count=32)
-    measured = measured_from_events(events)
-    assert measured["serve.pool_step"]["dispatches"] == 32
-    assert measured["serve.pool_step"]["tokens"] == 64.0
-    assert measured_from_events([{"kind": "serve.request", "ts": 1.0}]) == {}
-
-
-def test_roofline_cli_banked_band_workflow(tmp_path, capsys):
-    """The acceptance workflow, pinned end to end on a COPY of the
-    checked-in bank: pass -> perturb -> --check fails -> --update ->
-    pass. (The shipped obs/roofline_baseline.json is never rewritten.)"""
-    from transformer_tpu.obs.__main__ import main
-
-    ep = tmp_path / "episode.jsonl"
-    ep.write_text("".join(
-        json.dumps(e) + "\n" for e in _episode_events()
-    ))
-    bank = str(tmp_path / "bank.json")
-    shutil.copy(BASELINE_PATH, bank)
-    # --update banks the measured p50 and freezes the prior bank's
-    # predictions next to it (no --costs given).
-    assert main(["roofline", str(ep), "--baseline", bank, "--update"]) == 0
-    assert "banked 1 program(s)" in capsys.readouterr().out
-    banked = load_baseline(bank)["programs"]["serve.pool_step"]
-    assert banked["p50_s"] > 0
-    assert banked["bytes_moved"] == load_baseline()["programs"][
-        "serve.pool_step"]["bytes_moved"]
-    # Same episode against its own bank: in band, --check passes.
-    assert main(["roofline", str(ep), "--baseline", bank, "--check"]) == 0
-    capsys.readouterr()
-    # Perturb: the bank remembers a 100x faster program -> breach.
-    doc = json.load(open(bank))
-    doc["programs"]["serve.pool_step"]["p50_s"] /= 100.0
-    with open(bank, "w") as f:
-        json.dump(doc, f)
-    assert main(["roofline", str(ep), "--baseline", bank, "--check"]) == 1
-    err = capsys.readouterr().err
-    assert "BAND BREACH serve.pool_step" in err
-    # Re-bank on this host: the band heals.
-    assert main(["roofline", str(ep), "--baseline", bank, "--update"]) == 0
-    assert main(["roofline", str(ep), "--baseline", bank, "--check"]) == 0
-    capsys.readouterr()
-    # The JSON report carries the judged row.
-    assert main(
-        ["roofline", str(ep), "--baseline", bank, "--format=json"]
-    ) == 0
-    report = json.loads(capsys.readouterr().out)
-    rows = {r["program"]: r for r in report["programs"]}
-    assert rows["serve.pool_step"]["in_band"] is True
-    assert rows["serve.pool_step"]["effective_bytes_per_s"] > 0
-    # An episode with no profiler stream banks nothing (exit 2).
-    empty = tmp_path / "empty.jsonl"
-    empty.write_text(json.dumps({"kind": "serve.request", "ts": 1.0}) + "\n")
-    assert main(
-        ["roofline", str(empty), "--baseline", bank, "--update"]
-    ) == 2
-    capsys.readouterr()
-
-
-def test_summarize_reports_perf_section(capsys):
-    from transformer_tpu.obs.__main__ import render_text, summarize_events
-
-    report = summarize_events(_episode_events())
-    assert report["perf"]["programs"], "summarize dropped the perf section"
-    text = render_text(report)
-    assert "perf:" in text and "serve.pool_step" in text
-    # No profiler stream -> no perf section (the section never lies).
-    assert "perf" not in summarize_events(
-        [{"kind": "serve.request", "ts": 1.0}]
     )
 
 
@@ -468,16 +170,15 @@ def test_flight_signal_dump_in_subprocess(tmp_path):
 def test_healthz_endpoint(tmp_path):
     buf = io.StringIO()
     tel = Telemetry(events=EventLog(buf))
-    tel.arm_profiler(baseline={})
     tel.arm_flight(None)
-    tel.profiler.record("serve.pool_step", 0.001, tokens=1)
+    tel.registry.counter("serve_steps_total").inc()
     tel.emit("serve.request", order=0)
     port = tel.start_prometheus_server(0)
     base = f"http://127.0.0.1:{port}"
     try:
         with urllib.request.urlopen(f"{base}/metrics", timeout=10) as r:
             assert r.status == 200
-            assert "perf_seconds_serve_pool_step" in r.read().decode()
+            assert "serve_steps_total 1" in r.read().decode()
         with urllib.request.urlopen(f"{base}/healthz", timeout=10) as r:
             assert r.status == 200
             doc = json.loads(r.read())
@@ -485,7 +186,6 @@ def test_healthz_endpoint(tmp_path):
         assert doc["uptime_s"] >= 0
         assert doc["sinks"]["event_log"]["broken"] is False
         assert doc["flight"]["depth"] >= 1
-        assert doc["profiler"]["records"] == 1
         with pytest.raises(urllib.error.HTTPError) as ei:
             urllib.request.urlopen(f"{base}/bogus", timeout=10)
         assert ei.value.code == 404
@@ -536,8 +236,7 @@ def test_event_catalogue_covers_every_emit_site():
         "docs/OBSERVABILITY.md"
     )
     # This PR's kinds are both emitted somewhere and catalogued.
-    for kind in ("perf.drift", "flight.dump", "route.postmortem",
-                 "metrics.snapshot"):
+    for kind in ("flight.dump", "route.postmortem", "metrics.snapshot"):
         assert kind in emitted, kind
         assert kind in EVENT_CATALOGUE, kind
 
@@ -553,22 +252,20 @@ def test_event_catalogue_documented():
 
 
 # --------------------------------------------------------------------------
-# armed observatory vs the scheduler: inertness, retraces, the join
+# armed flight recorder vs the scheduler: inertness, retraces
 
 
 def _armed_telemetry(buf=None):
     tel = Telemetry(
         events=EventLog(buf) if buf is not None else None, interval=0.0,
     )
-    tel.arm_profiler()
     tel.arm_flight(None)
     return tel
 
 
 def test_scheduler_byte_identity_with_observatory_armed(lm):
-    """Profiler + flight recorder on the serving path change no answer
-    byte — and the dense + paged episodes together give ``obs roofline``
-    its >= 4 canned programs (the acceptance floor) from one CPU run."""
+    """The flight recorder on the serving path changes no answer byte,
+    dense or paged."""
     reqs = [
         {"prompt": PROMPT_A, "max_new": 6},
         {"prompt": "kl", "max_new": 2},
@@ -586,31 +283,11 @@ def test_scheduler_byte_identity_with_observatory_armed(lm):
         [dict(r) for r in reqs]
     )
     assert paged_plain == paged
-    assert tel.profiler.stats["records"] > 0
     assert tel.flight.depth() > 0
-    summary = tel.profiler.summary()
-    for program in ("serve.pool_step", "serve.slot_prefill",
-                    "serve.pool_step_paged", "serve.slot_prefill_paged"):
-        assert program in summary, sorted(summary)
-        assert summary[program]["dispatches"] > 0
-    assert summary["serve.pool_step"]["tokens"] > 0
-    # The episode's snapshots reconstruct the same programs offline, and
-    # the checked-in bank's frozen predictions price their measured p50s
-    # (no roofline ratio: this host's device has no entry in the peak table).
-    tel.maybe_flush(force=True)
-    events = [json.loads(l) for l in buf.getvalue().splitlines()]
-    report = roofline_report(events)
-    rows = {r["program"]: r for r in report["programs"]}
-    assert len(rows) >= 4
-    for program in ("serve.pool_step", "serve.pool_step_paged",
-                    "serve.slot_prefill", "serve.slot_prefill_paged"):
-        assert rows[program]["p50_ms"] > 0, program
-        assert rows[program]["effective_bytes_per_s"] > 0, program
-        assert "roofline_ratio" not in rows[program], program
 
 
 def test_scheduler_zero_recompiles_with_observatory_armed(lm):
-    """Arming profiler + flight recorder must not cost a single recompile
+    """Arming the flight recorder must not cost a single recompile
     on the steady-state decode path (retrace-sentinel criterion)."""
     from transformer_tpu.analysis.retrace import RetraceSentinel
     from transformer_tpu.serve import scheduler as sched_mod
@@ -628,7 +305,7 @@ def test_scheduler_zero_recompiles_with_observatory_armed(lm):
         out = s.run([{"prompt": "ab cd", "max_new": 3}])
         assert "continuation" in out[0]
     sentinel.assert_within_budget()
-    assert tel.profiler.stats["records"] > 0
+    assert tel.flight.depth() > 0
 
 
 # --------------------------------------------------------------------------
@@ -731,43 +408,3 @@ def test_sigkill_postmortem_capture(lm, spec_file, tmp_path):
         assert obs_main(["postmortem", *inputs]) == 0
     text = buf.getvalue()
     assert "postmortem(s)" in text and victim_name in text
-
-
-# --------------------------------------------------------------------------
-# the bench acceptance: a real CPU sweep measures what the model predicts
-
-
-@pytest.mark.slow  # subprocess + two jit sweeps: slow tier
-def test_decode_bench_emits_measured_roofline_columns(tmp_path):
-    """benchmarks/decode_bench.py on CPU: every sweep row carries
-    measured_step_p50_ms (and no roofline ratio — the CPU has no entry in
-    the peak table), and ``obs roofline`` over the episode reports >= 4
-    canned programs (the acceptance bar)."""
-    from transformer_tpu.obs.__main__ import main as obs_main
-
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    jsonl = str(tmp_path / "bench.jsonl")
-    out = subprocess.run(
-        [sys.executable, str(REPO / "benchmarks" / "decode_bench.py"),
-         "--layers", "1", "--d_model", "32", "--heads", "2", "--dff", "64",
-         "--vocab", "128", "--prompt_len", "16", "--decode_steps", "8",
-         "--reps", "1", "--prefix_requests", "4",
-         "--kv_layout", "dense,paged", "--metrics_jsonl", jsonl],
-        capture_output=True, text=True, timeout=420, env=env,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    row = json.loads(out.stdout.strip().splitlines()[-1])
-    for layout_row in row["kv_layouts"]:
-        assert layout_row["measured_step_p50_ms"] > 0, layout_row
-        assert layout_row["roofline_ratio"] is None, layout_row
-    import contextlib
-
-    buf = io.StringIO()
-    with contextlib.redirect_stdout(buf):
-        assert obs_main(["roofline", jsonl, "--format=json"]) == 0
-    report = json.loads(buf.getvalue())
-    canned = [
-        r["program"] for r in report["programs"]
-        if r["program"] in CANNED_PROGRAMS
-    ]
-    assert len(canned) >= 4, canned

@@ -368,6 +368,57 @@ def test_step_spans_phases_and_counts(lm, kw):
     assert retired <= len(reqs)
 
 
+@pytest.mark.parametrize("speculate_k", [0, 2], ids=["plain", "speculate_k=2"])
+@pytest.mark.parametrize(
+    "kw",
+    [
+        dict(kv_layout="dense", decode_kernel="xla"),
+        dict(kv_layout="paged", kv_block=4, decode_kernel="xla"),
+        dict(kv_layout="paged", kv_block=4, decode_kernel="paged_flash"),
+    ],
+    ids=["dense-xla", "paged-xla", "paged-paged_flash"],
+)
+def test_step_metrics_count_every_step(lm, kw, speculate_k):
+    """The histograms and counters an operator reads count what the
+    scheduler did: a ``serve_step_seconds`` sample and a ``serve_steps_total``
+    tick a step (plain or verify), a ``serve_generated_tokens_total`` tick a
+    token answered, a ``serve_prefill_seconds`` sample an admission."""
+    from transformer_tpu.obs import Telemetry
+
+    params, cfg, tok = lm
+    reqs = [
+        {"prompt": "ab cd ef gh ij", "max_new": 6},
+        {"prompt": "kl", "max_new": 3},
+        {"prompt": "ab cd ab cd ab", "max_new": 8},
+        {"prompt": "mn ef cd", "max_new": 4, "temperature": 0.9, "seed": 3},
+        {"prompt": "gh ij kl mn", "max_new": 5},
+    ]
+    tel = Telemetry()
+    tapped = []
+    sched = ContinuousScheduler(
+        params, cfg, tok, num_slots=2, max_total=32, prefill_chunk=3,
+        speculate_k=speculate_k, telemetry=tel, span_tap=tapped.append, **kw,
+    )
+    out = sched.run([dict(r) for r in reqs])
+    assert all("continuation" in a for a in out)
+    reg = tel.registry
+    steps = sched.stats["steps"]
+    assert steps > 0
+    assert reg.histogram("serve_step_seconds").hist.count == steps
+    assert reg.counter("serve_steps_total").value == steps
+    answered = sum(t["new_tokens"] for t in tapped)
+    assert answered >= len(reqs)
+    assert reg.counter("serve_generated_tokens_total").value == answered
+    assert sched.stats["admitted"] == len(reqs)
+    assert reg.histogram("serve_prefill_seconds").hist.count == len(reqs)
+    if speculate_k:
+        drafted = reg.counter("serve_spec_drafted_total").value
+        assert drafted == sched.stats["drafted"] > 0
+        assert reg.counter("serve_spec_accepted_total").value == (
+            sched.stats["accepted"]
+        )
+
+
 def test_sampling_groups_alternate_dispatch_and_fetch(lm):
     """Greedy and sampled requests side by side make two pick groups: the
     step dispatches and fetches one after the other, in that order."""

@@ -243,3 +243,57 @@ def test_stage_params_host_arrays_swap_cleanly(tok):
     leaf = jax.tree_util.tree_leaves(s.params)[0]
     assert leaf.sharding.is_fully_replicated  # placed onto the serving mesh
     assert s._sharded.pool_step._cache_size() == before  # zero recompiles
+
+
+# --------------------------------------------------------------------------
+# the same parity through the wire: a real replica process behind a Router
+
+
+@pytest.mark.parametrize("mesh", [2])
+def test_mesh_parity_through_router(tmp_path, mesh):
+    """A replica worker started with ``--mesh N`` (it grows its own virtual
+    CPU platform before importing jax) announces ``data=N`` on its ready
+    line and answers greedy AND seeded-sampled requests through a Router
+    byte-identically to the unsharded scheduler in this process."""
+    import json
+
+    from transformer_tpu.serve.replica import build_model_from_spec
+    from transformer_tpu.serve.router import ReplicaProcess, Router
+
+    spec = {
+        "config": {
+            "num_layers": 1, "d_model": 16, "num_heads": 2, "dff": 32,
+            "max_position": 32, "decoder_only": True, "tie_output": True,
+            "dtype": "float32", "dropout_rate": 0.0,
+        },
+        "seed": 0,
+        "corpus": ["ab cd ef gh ij kl mn"] * 3,
+        "target_vocab_size": 300,
+    }
+    spec_file = tmp_path / "spec.json"
+    spec_file.write_text(json.dumps(spec))
+    params, cfg, spec_tok = build_model_from_spec(spec)
+    reqs = [q for wave in WAVES for q in wave]
+    want = [
+        r.get("continuation")
+        for r in ContinuousScheduler(params, cfg, spec_tok, num_slots=2).run(
+            [dict(q) for q in reqs]
+        )
+    ]
+    assert all(c is not None for c in want)
+    link = ReplicaProcess.spawn(0, [
+        "--model_spec", str(spec_file), "--serve_slots", "4",
+        "--heartbeat_ms", "100", "--mesh", str(mesh),
+    ])
+    router = Router(
+        [link], encode=spec_tok.encode, bos_id=spec_tok.bos_id,
+        heartbeat_timeout_s=30.0,
+    )
+    link.start_reader(router.inbox)
+    try:
+        got = [o.get("continuation") for o in router.run([dict(q) for q in reqs])]
+        reported = link.mesh
+    finally:
+        router.shutdown()
+    assert got == want, f"mesh={mesh} replica diverged from the unsharded path"
+    assert reported == f"data={mesh}"

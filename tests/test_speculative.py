@@ -437,34 +437,32 @@ def test_verify_contract_covers_cache_variants():
     ), [str(r) for r in results if r.contract == "verify_cache_parity"]
 
 
-@pytest.mark.slow  # subprocess + timing loop: slow tier
-def test_decode_bench_speculative_acceptance():
-    """benchmarks/decode_bench.py --speculate_k 4: tokens-per-forward must
-    exceed 1.5 (the PR's acceptance bar) and the JSONL row is well-formed."""
-    import os
-    import subprocess
-    import sys
+def test_tokens_per_forward_counts(lm):
+    """On repetitive prompts with ``speculate_k=4`` the scheduler's own
+    counters show what speculation is for: more than 1.5 tokens a target
+    forward (incremental decode's ceiling is 1.0), and an acceptance rate
+    that is a rate. Counts only, no clock."""
+    from transformer_tpu.obs import Telemetry
 
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    out = subprocess.run(
-        [sys.executable,
-         os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                      "decode_bench.py"),
-         "--reps", "2", "--speculate_k", "4", "--decode_steps", "48"],
-        capture_output=True, text=True, timeout=420, env=env,
+    params, cfg, tok = lm
+    tel = Telemetry()
+    tapped = []
+    sched = ContinuousScheduler(
+        params, cfg, tok, num_slots=2, speculate_k=4, telemetry=tel,
+        span_tap=tapped.append,
     )
-    assert out.returncode == 0, out.stderr[-2000:]
-    row = json.loads(out.stdout.strip().splitlines()[-1])
-    spec = row["speculative"][0]
-    assert spec["k"] == 4
-    assert spec["tokens_per_forward"] > 1.5, spec
-    assert 0.0 <= spec["acceptance_rate"] <= 1.0
-    bench_rows = [
-        json.loads(line) for line in out.stderr.splitlines()
-        if line.startswith("{")
-    ]
-    assert any(
-        r.get("metric") == "speculative decode tokens-per-forward"
-        and r.get("config", {}).get("speculate_k") == 4
-        for r in bench_rows
-    ), out.stderr[-2000:]
+    out = sched.run([
+        {"prompt": "kl kl kl kl", "max_new": 24},
+        {"prompt": "ab cd ab cd ab cd", "max_new": 20},
+    ])
+    assert all("continuation" in a for a in out)
+    reg = tel.registry
+    tokens = reg.counter("serve_generated_tokens_total").value
+    forwards = sum(t["forwards"] for t in tapped)
+    assert tokens == sum(t["new_tokens"] for t in tapped) == 44
+    assert forwards > 0 and tokens / forwards > 1.5, (tokens, forwards)
+    drafted = reg.counter("serve_spec_drafted_total").value
+    accepted = reg.counter("serve_spec_accepted_total").value
+    assert drafted > 0 and 0.0 <= accepted / drafted <= 1.0
+    assert drafted == sum(t.get("drafted", 0) for t in tapped)
+    assert accepted == sum(t.get("draft_accepted", 0) for t in tapped)

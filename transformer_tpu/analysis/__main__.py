@@ -33,7 +33,7 @@ Subcommands (all CPU-safe; exit code 0 = clean, 1 = findings/violations):
   aggregate exit code: the pre-merge gate (docs/ANALYSIS.md).
 
 ``--format=json`` emits machine-readable output on every subcommand so
-rounds can diff finding counts like a bench (``bench.py`` row style).
+rounds can diff finding counts.
 """
 
 from __future__ import annotations

@@ -543,9 +543,8 @@ def check_telemetry_inert(cfg: ModelConfig) -> str:
     byte-identical jaxprs. The instrumented twin is built with the real
     wrappers the telemetry-enabled Trainer installs around its step
     dispatches — ``obs.telemetry.timed_call`` feeding a live registry
-    histogram + counter, COMPOSED with ``obs.profile.profile_call``
-    recording into a live ProgramProfiler (the roofline sentinel) and
-    ``obs.trace.traced_call`` opening a real span on a live tracer (the
+    histogram + counter, COMPOSED with ``obs.trace.traced_call``
+    opening a real span on a live tracer (the
     ``--trace`` stack, spans emitted through a live FlightRecorder tap
     into a real in-memory EventLog); the serving pool step, slot prefill,
     and speculative verify programs are traced through the same wrappers.
@@ -562,7 +561,6 @@ def check_telemetry_inert(cfg: ModelConfig) -> str:
     from transformer_tpu.obs import MetricsRegistry
     from transformer_tpu.obs.events import EventLog
     from transformer_tpu.obs.flight import FlightRecorder
-    from transformer_tpu.obs.profile import ProgramProfiler, profile_call
     from transformer_tpu.obs.telemetry import timed_call
     from transformer_tpu.obs.trace import Tracer, traced_call
     from transformer_tpu.train.state import TrainState, make_optimizer
@@ -572,12 +570,10 @@ def check_telemetry_inert(cfg: ModelConfig) -> str:
 
     reg = MetricsRegistry()
     span_sink = io.StringIO()
-    # Both PR-18 subsystems armed exactly as production arms them: the
-    # flight recorder taps the tracer's emit path (every span rides the
-    # ring), the profiler records through the registry.
+    # The flight recorder armed exactly as production arms it: it taps
+    # the tracer's emit path (every span rides the ring).
     flight = FlightRecorder(None, capacity=64)
     tracer = Tracer(flight.tap(EventLog(span_sink).emit))
-    profiler = ProgramProfiler(registry=reg)
 
     def canon(jaxpr) -> str:
         # custom_jvp equations print closure thunks with their memory
@@ -587,13 +583,11 @@ def check_telemetry_inert(cfg: ModelConfig) -> str:
         return re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
 
     def twins(fn):
-        # The exact production composition: traced_call outermost around
-        # profile_call around timed_call
+        # The exact production composition: traced_call around timed_call
         # (trainer._wrap_steps_for_dispatch_timing order).
         wrapped = timed_call(
             fn, reg.histogram("contract_seconds"), reg.counter("contract_total")
         )
-        wrapped = profile_call(wrapped, profiler, "contract.step")
         wrapped = traced_call(wrapped, tracer, "contract.step")
         return fn, wrapped
 
@@ -682,15 +676,11 @@ def check_telemetry_inert(cfg: ModelConfig) -> str:
     assert "trace.span" in span_sink.getvalue(), (
         "the tracer's spans never reached the event log"
     )
-    assert profiler.stats["records"] >= len(checked), (
-        "the profiled twin never recorded — the profiler side of the "
-        "contract is vacuous"
-    )
     assert flight.depth() > 0 and flight.dump("request")["spans"], (
         "the tracer's spans never rode the flight-recorder ring"
     )
     return (
-        "jaxpr-identical twins (timed+profiled+traced, flight armed): "
+        "jaxpr-identical twins (timed+traced, flight armed): "
         f"{', '.join(checked)}"
     )
 

@@ -1649,30 +1649,6 @@ def run_kernels(
     return result
 
 
-def program_kernel_vmem(fn: Callable, *args, generation: str | None = None) -> dict:
-    """Per-kernel predicted VMEM for one traceable program (decode_bench
-    hook): {kernel name -> predicted_vmem_bytes}, no lints, no baseline."""
-    import jax
-
-    records: list[_Capture] = []
-    with _capture_pallas(records):
-        jax.make_jaxpr(fn)(*args)
-    out: dict[str, int] = {}
-    deduped: dict = {}
-    for cap in records:
-        deduped.setdefault(cap.site_key(), cap)
-    for cap in deduped.values():
-        views = _spec_views(cap)
-        _check_conformance(cap, views)  # fills grid_varying
-        vmem, _ = _vmem_footprint(cap, views)
-        key = cap.kernel_name
-        if key in out:
-            out[key] = max(out[key], vmem)
-        else:
-            out[key] = vmem
-    return out
-
-
 def summarize_kernels(result: KernelsResult) -> str:
     from .costs import _fmt_bytes
 

@@ -24,8 +24,9 @@ FLAGS = flags.FLAGS
 # tests/test_flags.py pins this against ops.ffn.FFN_ACTIVATIONS.
 _FFN_ACTIVATION_NAMES = ("geglu", "gelu", "reglu", "relu", "silu", "swiglu")
 
-# One-flag reproduction of the BASELINE.json benchmark configs: values land
-# on flags the user did NOT set explicitly (explicit flags always win).
+# The one table of the presets: the reference's five configurations
+# (BASELINE.json "configs") by name. Values land on flags the user did NOT
+# set explicitly (explicit flags always win).
 _PRESETS: dict[str, dict] = {
     "tiny": dict(num_layers=2, d_model=128, num_heads=4, dff=512, batch_size=64),
     "base": dict(num_layers=6, d_model=512, num_heads=8, dff=2048, batch_size=64),
@@ -86,13 +87,6 @@ def define_metrics_flags() -> None:
         "load in chrome://tracing / Perfetto. Answers and compiled programs "
         "are unaffected (contract-checked)")
     flags.DEFINE_boolean(
-        "profile_programs", True,
-        "per-program dispatch profiler (obs/profile.py): clock every canned "
-        "jitted program into perf_seconds_* histograms and roofline/drift "
-        "gauges, sentinel measured-vs-banked drift (perf.drift events). "
-        "Jaxpr-inert (contract-checked); report with "
-        "`python -m transformer_tpu.obs roofline <file>`")
-    flags.DEFINE_boolean(
         "flight_recorder", True,
         "always-on bounded flight recorder (obs/flight.py): keep the last "
         "seconds of events/spans/snapshots in memory and dump them to "
@@ -103,7 +97,8 @@ def define_metrics_flags() -> None:
 def define_flags() -> None:
     flags.DEFINE_enum(
         "preset", "", ["", *sorted(_PRESETS)],
-        "start from a BASELINE benchmark config (tiny/base/big/tied/long4k); "
+        "start from one of the reference's configurations (BASELINE.json: "
+        "tiny/base/big/tied/long4k); "
         "explicitly-passed flags override preset values")
     # --- reference-surface flags (utils.py:18-33 defaults) ---
     flags.DEFINE_string("dataset_path", "data", "directory with src/tgt line files")
@@ -422,8 +417,6 @@ def flags_to_telemetry():
         interval=FLAGS.metrics_interval,
         trace=FLAGS.trace and events is not None,
     )
-    if FLAGS.profile_programs:
-        telemetry.arm_profiler()
     if FLAGS.flight_recorder and FLAGS.metrics_jsonl:
         from transformer_tpu.obs.flight import flight_path_for
 

@@ -413,8 +413,8 @@ class TrainConfig:
     # Host-dispatch amortization: run this many optimizer steps inside ONE
     # jitted lax.scan per host→device dispatch (trainer.py
     # make_multistep_train_step). At small step times the per-step Python/
-    # runtime dispatch is a measurable share of wall clock (BASELINE.md
-    # [deviceloop] probe); K steps per dispatch divide it by K. Orthogonal
+    # runtime dispatch is a share of wall clock; K steps per dispatch divide
+    # it by K (never measured on the chip). Orthogonal
     # to grad_accum_steps (each inner step is still a full optimizer
     # update). Trade-off: preemption/log/eval granularity becomes K steps.
     # 1 = off.

@@ -1,4 +1,4 @@
-"""``python -m transformer_tpu.obs <summarize|trace|slo|roofline|postmortem>``
+"""``python -m transformer_tpu.obs <summarize|trace|slo|postmortem>``
 — telemetry CLI.
 
 - ``summarize`` aggregates a structured event log (docs/OBSERVABILITY.md
@@ -11,18 +11,11 @@
   ui.perfetto.dev; one lane per serve slot plus scheduler/intake/train.
 - ``slo`` evaluates declarative SLOs (``obs/slo.py``) as multi-window burn
   rates over the same log.
-- ``roofline`` joins an episode's measured per-program dispatch histograms
-  (``obs/profile.py``, the ``perf_seconds_*`` stream) against cost-model
-  predictions (``--costs`` = an ``analysis costs --format=json`` document)
-  and the banked baseline: tokens/s, effective bytes/s, roofline ratio,
-  and drift verdicts per program. ``--check`` exits 1 on a banked-band
-  breach; ``--update`` re-banks the measured p50s (the pass → perturb →
-  fail → ``--update`` → pass workflow the analysis families use).
 - ``postmortem`` reconstructs a fleet's last seconds from any mix of
   event logs, ``*.flight.json`` flight-recorder dumps, and the flight
   records the Supervisor embedded in ``route.postmortem`` events.
 
-All three accept MULTIPLE jsonl files (``--merge``): events are tagged with
+All accept MULTIPLE jsonl files (``--merge``): events are tagged with
 their source and clock-aligned via per-file skew estimation
 (``obs/merge.py``) — the cross-replica aggregation the scale-out roadmap
 item requires. ``--since TS`` / ``--last N{s,m,h}`` slice long soak logs.
@@ -37,15 +30,6 @@ import json
 import sys
 
 from transformer_tpu.obs.merge import filter_events, merge_events, parse_duration
-from transformer_tpu.obs.profile import (
-    BASELINE_PATH,
-    band_breaches,
-    load_baseline,
-    measured_from_events,
-    predictions_by_program,
-    roofline_report,
-    write_baseline,
-)
 from transformer_tpu.obs.quantiles import StreamingHistogram
 
 
@@ -462,16 +446,6 @@ def summarize_events(events: list[dict]) -> dict:
         if entry:
             report.setdefault("train", {})["predicted"] = entry
 
-    # ---- perf: measured programs vs the cost model (obs/profile.py) ------
-    # The profiler's per-program histograms ride metrics.snapshot; join
-    # them against the banked baseline's frozen predictions. Tolerant when
-    # either side is absent: no profiler stream -> no section; an unbanked
-    # program rows without the bytes/drift columns. `obs roofline` is the
-    # full report (this section skips the --costs join).
-    perf = roofline_report(events)
-    if perf.get("programs"):
-        report["perf"] = perf
-
     # ---- tracing (span volume only; `obs trace` renders the timeline) ----
     spans = [e for e in events if e.get("kind") == "trace.span"]
     if spans:
@@ -717,26 +691,6 @@ def render_text(report: dict) -> str:
             if pred.get("measured_over_predicted") is not None:
                 line += f" (measured/predicted {pred['measured_over_predicted']}x)"
             lines.append(line)
-    perf = report.get("perf")
-    if perf:
-        lines.append(
-            f"perf: {len(perf['programs'])} measured program(s) "
-            "(`obs roofline` renders the full join)"
-        )
-        for r in perf["programs"]:
-            line = (
-                f"  {r['program']}: p50 {r['p50_ms']:.3f}ms "
-                f"over {r['dispatches']} dispatches"
-            )
-            if r.get("measured_tokens_per_s"):
-                line += f", {r['measured_tokens_per_s']} tokens/s"
-            if r.get("roofline_ratio") is not None:
-                line += f", roofline {r['roofline_ratio']}"
-            if r.get("drift") is not None:
-                line += f", drift {r['drift']}x" + (
-                    "" if r.get("in_band", True) else " OUT OF BAND"
-                )
-            lines.append(line)
     tracing = report.get("tracing")
     if tracing:
         lines.append(
@@ -762,40 +716,6 @@ def render_text(report: dict) -> str:
         lines.append("sources: " + "; ".join(parts))
     if len(lines) == 1:
         lines.append("no serve/train/bench telemetry kinds found")
-    return "\n".join(lines)
-
-
-def render_roofline_text(report: dict) -> str:
-    rows = report.get("programs", [])
-    lines = [f"{len(rows)} measured program(s)"]
-    for r in rows:
-        line = (
-            f"  {r['program']}: p50 {r['p50_ms']:.3f}ms "
-            f"p95 {r['p95_ms']:.3f}ms over {r['dispatches']} dispatches"
-        )
-        if r.get("measured_tokens_per_s"):
-            line += f", {r['measured_tokens_per_s']} tokens/s"
-        if r.get("predicted_bytes_moved"):
-            line += (
-                f"; predicted {r['predicted_bytes_moved']}B moved -> "
-                f"{r['effective_bytes_per_s']:.4g} B/s effective"
-            )
-        if r.get("roofline_ratio") is not None:
-            line += f", roofline {r['roofline_ratio']}"
-        if r.get("measured_over_predicted_tokens") is not None:
-            line += (
-                f"; measured/predicted tokens/s "
-                f"{r['measured_over_predicted_tokens']}x"
-            )
-        if r.get("drift") is not None:
-            verdict = "in band" if r.get("in_band") else "OUT OF BAND"
-            line += f"; drift {r['drift']}x {r.get('band')} {verdict}"
-        lines.append(line)
-    if len(lines) == 1:
-        lines.append(
-            "no perf_seconds_* histograms found (profiler not armed, or "
-            "no metrics.snapshot flushed?)"
-        )
     return "\n".join(lines)
 
 
@@ -991,35 +911,6 @@ def main(argv: list[str] | None = None) -> int:
     p_slo.add_argument(
         "--format", choices=("text", "json"), default="text",
     )
-    p_roof = sub.add_parser(
-        "roofline",
-        help="measured-vs-predicted per-program report from the profiler "
-        "stream (perf_seconds_* histograms in metrics.snapshot)",
-    )
-    _add_common_args(p_roof)
-    p_roof.add_argument(
-        "--costs", default=None, metavar="JSON",
-        help="`analysis costs --format=json` document to join predictions "
-        "from (without it, the banked baseline's frozen predictions apply)",
-    )
-    p_roof.add_argument(
-        "--baseline", default=BASELINE_PATH,
-        help="banked roofline baseline (default: the checked-in "
-        "obs/roofline_baseline.json)",
-    )
-    p_roof.add_argument(
-        "--update", action="store_true",
-        help="re-bank the episode's measured p50s into --baseline "
-        "(absolute times are per-host: run on the box that enforces "
-        "the band)",
-    )
-    p_roof.add_argument(
-        "--check", action="store_true",
-        help="exit 1 when any banked program's measured p50 left its band",
-    )
-    p_roof.add_argument(
-        "--format", choices=("text", "json"), default="text",
-    )
     p_pm = sub.add_parser(
         "postmortem",
         help="reconstruct the fleet's last seconds from event logs, "
@@ -1079,56 +970,6 @@ def main(argv: list[str] | None = None) -> int:
             print(json.dumps(report, indent=2, sort_keys=True))
         else:
             print(render_text(report))
-        return 0
-
-    if args.cmd == "roofline":
-        costs_doc = None
-        if args.costs:
-            try:
-                with open(args.costs, encoding="utf-8") as f:
-                    costs_doc = json.load(f)
-            except (OSError, ValueError) as e:
-                print(f"cannot read --costs {args.costs}: {e}", file=sys.stderr)
-                return 2
-        if args.update:
-            measured = measured_from_events(events)
-            if not measured:
-                print(
-                    "no perf_seconds_* histograms in the episode; "
-                    "nothing to bank",
-                    file=sys.stderr,
-                )
-                return 2
-            prior = load_baseline(args.baseline)
-            # Predictions to freeze next to the banked p50s: a --costs
-            # document when given, else whatever the prior bank froze.
-            preds = (
-                predictions_by_program(costs_doc)
-                if costs_doc else dict(prior.get("programs") or {})
-            )
-            doc = write_baseline(args.baseline, measured, predictions=preds)
-            print(
-                f"banked {len(doc['programs'])} program(s) -> {args.baseline}"
-            )
-            return 0
-        report = roofline_report(
-            events, costs=costs_doc, baseline=load_baseline(args.baseline)
-        )
-        report.update(info)
-        if args.format == "json":
-            print(json.dumps(report, indent=2, sort_keys=True))
-        else:
-            print(render_roofline_text(report))
-        if args.check:
-            breaches = band_breaches(report)
-            if breaches:
-                for r in breaches:
-                    print(
-                        f"BAND BREACH {r['program']}: drift {r['drift']}x "
-                        f"outside {r['band']}",
-                        file=sys.stderr,
-                    )
-                return 1
         return 0
 
     if args.cmd == "trace":

@@ -71,10 +71,6 @@ The kinds this repo emits (schema in docs/OBSERVABILITY.md):
 - ``flight.dump`` — one per non-automatic flight-recorder dump
   (signal / explicit request / clean close; periodic autodumps stay
   silent): ``reason``, ``path``, and ring sizes.
-- ``perf.drift`` — one per measured-vs-banked breach-state transition
-  (``obs/profile.py``): ``program``, measured-over-banked p50 ``ratio``,
-  the ``band``, both p50s, and ``breached``. Same transition-only
-  discipline as ``slo.burn``.
 - ``metrics.snapshot`` — periodic full registry dump (histograms as
   count/sum/min/max/p50/p95/p99).
 
@@ -123,7 +119,6 @@ EVENT_CATALOGUE = {
     "ckpt.fallback": "trainer restored an older checkpoint after a bad one",
     "flight.dump": "non-automatic flight-recorder dump (signal/request/close)",
     "metrics.snapshot": "periodic full metrics-registry dump",
-    "perf.drift": "measured p50 left (or re-entered) its banked band",
     "route.answered": "HA journal: delivery mark for an accepted order",
     "route.canary": "canary slice lifecycle (started/promoted)",
     "route.dispatch": "router picked a replica for one request",

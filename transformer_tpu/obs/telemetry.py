@@ -88,24 +88,11 @@ class Telemetry:
         self._server = None
         self._t0 = time.time()
         self.tracer = Tracer(self.emit if trace else None)
-        # Armed on demand (arm_profiler / arm_flight): the per-program
-        # dispatch profiler (obs/profile.py) and the always-on flight
-        # recorder (obs/flight.py). None keeps both surfaces free.
-        self.profiler = None
+        # Armed on demand (arm_flight): the flight recorder
+        # (obs/flight.py). None keeps the surface free.
         self.flight = None
 
     # ---- optional subsystems ---------------------------------------------
-
-    def arm_profiler(self, baseline: dict | None = None):
-        """Attach a :class:`~transformer_tpu.obs.profile.ProgramProfiler`
-        bound to this bundle's registry and emit (perf_* metrics ride the
-        snapshot/prom sinks; perf.drift events ride the log)."""
-        from transformer_tpu.obs.profile import ProgramProfiler
-
-        self.profiler = ProgramProfiler(
-            registry=self.registry, emit=self.emit, baseline=baseline
-        )
-        return self.profiler
 
     def arm_flight(
         self, path: str | None, capacity: int = 256, autodump_s: float = 2.0
@@ -212,8 +199,6 @@ class Telemetry:
                 "dumps": self.flight.dumps,
                 "broken": self.flight._broken,
             }
-        if self.profiler is not None:
-            doc["profiler"] = dict(self.profiler.stats)
         spans = span_buffer()
         doc["spans"] = {
             "buffered": len(spans), "capacity": spans.capacity,

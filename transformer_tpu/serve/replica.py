@@ -68,11 +68,7 @@ replica -> router:
     {"type": "flight", "record": {...}|null}              dump reply: the
                                                           flight-recorder ring
                                                           (obs/flight.py)
-    {"type": "stats", "stats": {...}
-     [, "perf": {...}]}                                   final, at shutdown;
-                                                          "perf" = per-program
-                                                          measured rows when
-                                                          the profiler is armed
+    {"type": "stats", "stats": {...}}                     final, at shutdown
 
 **Router HA** (``--ha``): the worker additionally listens on a localhost
 TCP control socket (ephemeral port, announced in ``ready``). A warm-standby
@@ -415,7 +411,6 @@ def main(argv=None) -> None:
         telemetry = Telemetry(
             events=EventLog(args.metrics_jsonl), trace=args.trace
         )
-        telemetry.arm_profiler()
         # Tight autodump: the on-disk flight record is all a SIGKILL
         # leaves behind, and the Supervisor's postmortem capture reads it
         # — half a second bounds how much of the victim's last telemetry
@@ -880,10 +875,6 @@ def main(argv=None) -> None:
             out.send(hb)
     flush_answers()
     final = {"type": "stats", "stats": {**dict(sched.stats), **stats_extra}}
-    if telemetry is not None and telemetry.profiler is not None:
-        # Measured per-program rows ride the clean-shutdown stats so the
-        # router benchmarks read p50s without re-parsing replica JSONLs.
-        final["perf"] = telemetry.profiler.summary()
     out.send(final)
     if telemetry is not None:
         telemetry.close()

@@ -229,12 +229,9 @@ class ReplicaLink:
         # device topology beyond the string.
         self.mesh: str | None = None
         # What the replica's ready line says it runs on: platform,
-        # device_kind and the devices it holds (benchmarks name their
-        # device from this — the parent never asks jax).
+        # device_kind and the devices it holds (the parent never asks jax).
         self.device: dict | None = None
         self.control_port: int | None = None  # --ha takeover socket
-        self.final_stats: dict | None = None  # replica's shutdown report
-        self.final_perf: dict | None = None   # profiler rows in that report
         # Flight-recorder hooks (obs/flight.py): where this worker's
         # on-disk dumps land (parsed from --metrics_jsonl at spawn), and
         # the last record it shipped over the wire (a `dump` reply) — the
@@ -974,9 +971,6 @@ class Router:
         elif kind == "state_injected":
             if self._sup is not None:
                 self._sup.on_state_injected(link, msg)
-        elif kind == "stats":
-            link.final_stats = msg.get("stats")  # bench introspection
-            link.final_perf = msg.get("perf")    # profiler rows (ditto)
         elif kind == "flight":
             # A `dump` reply: hold the freshest wire-shipped flight record
             # for the Supervisor's postmortem capture.

@@ -1079,8 +1079,8 @@ class ContinuousScheduler:
             "admitted": 0, "steps": 0, "max_active": 0,
             # Prefix-cache accounting (host-side, filled at admission):
             # prompt tokens seen, tokens restored from stored blocks, and
-            # the prefill forwards actually dispatched — decode_bench's
-            # --prefix_reuse sweep derives "forwards saved" from these.
+            # the prefill forwards actually dispatched (a forward per chunk
+            # of the suffix: what a prefix hit saves shows here).
             "prompt_tokens": 0, "prefix_hit_tokens": 0, "prefill_forwards": 0,
             # Paged-KV accounting (kv_layout="paged"): prompt tokens whose
             # restore was pure device-side block-table ALIASING vs tokens
@@ -1105,24 +1105,6 @@ class ContinuousScheduler:
         # event log under --trace, and — the context-manager ones — into
         # the profiler's trace while a profiler session runs.
         self._tracer = mirrored_tracer(telemetry)
-        # Per-program dispatch profiler (obs/profile.py, armed via
-        # Telemetry.arm_profiler): clocks each canned program under the
-        # SAME base names the cost model prices, so the roofline report
-        # can join measured against predicted. The program this scheduler
-        # dispatches is fixed at construction by layout + kernel choice.
-        self._profiler = getattr(telemetry, "profiler", None)
-        if self._profiler is not None:
-            self._profiler.device_kind = jax.devices()[0].device_kind
-        _kind = (
-            "_paged_flash"
-            if self.paged and self.decode_kernel == "paged_flash"
-            else "_paged" if self.paged else ""
-        )
-        self._prog_step = "serve.pool_step" + _kind
-        self._prog_verify = "serve.pool_verify" + _kind
-        self._prog_prefill = "serve.slot_prefill" + (
-            "_paged" if self.paged else ""
-        )
         # Victim attribution for breaker transitions: the trace id of the
         # request whose fault is being recorded, set around the fallible
         # regions (admission, retirement feed, drafting) on the scheduler
@@ -2131,7 +2113,6 @@ class ContinuousScheduler:
                         self.pool.alloc.free_slot(slot)
                     n_suffix = prefill_len_for(L, self.prefill_chunk)
                     n = n_suffix
-            t_pf = time.perf_counter()
             if self.paged:
                 from transformer_tpu.kernels.kv_pool import KVPoolExhausted
 
@@ -2169,14 +2150,6 @@ class ContinuousScheduler:
         finally:
             if hit is not None:
                 hit.release()
-        if self._profiler is not None:
-            # Dispatch window (async: the device may still be prefilling —
-            # timed_call's caveat applies); tokens = the suffix actually
-            # fed through the forward, restored prefix excluded.
-            self._profiler.record(
-                self._prog_prefill, time.perf_counter() - t_pf,
-                tokens=n_suffix,
-            )
         if use_prefix and prefix_ok:
             # The cache served this admission end-to-end (hit or clean
             # miss): a half-open probe closes the breaker here.
@@ -2432,12 +2405,6 @@ class ContinuousScheduler:
                 dt_step = time.perf_counter() - t_step
                 self._m_step_s.observe(dt_step)
                 self._m_steps.inc()
-                if self._profiler is not None:
-                    # One token per slot that picked this step: the honest
-                    # token credit for a pool-step dispatch.
-                    self._profiler.record(
-                        self._prog_step, dt_step, tokens=len(picks)
-                    )
             self._step_publish()
             sp.set(retired=stepped - len(self._active))
         # Slots that produced an output token, those of them for which it
@@ -2650,12 +2617,6 @@ class ContinuousScheduler:
             dt_step = time.perf_counter() - t_step
             self._m_step_s.observe(dt_step)
             self._m_steps.inc()
-            if self._profiler is not None:
-                # W positions scored per fed row — the verify forward's
-                # honest work unit (cost-model tokens_per_step agrees).
-                self._profiler.record(
-                    self._prog_verify, dt_step, tokens=n_rows * W
-                )
             if drafted:
                 self._m_spec_drafted.inc(drafted)
                 if accepted:

@@ -605,9 +605,9 @@ class Trainer:
         if train_cfg.enable_function:
             if train_cfg.steps_per_dispatch > 1:
                 # K optimizer steps per host dispatch (one jitted scan):
-                # amortizes the per-step dispatch overhead the BASELINE.md
-                # [deviceloop] probe isolates. jit re-traces per distinct
-                # stacked shape (tail groups, length buckets) and caches.
+                # amortizes the per-step dispatch overhead. jit re-traces per
+                # distinct stacked shape (tail groups, length buckets) and
+                # caches.
                 self.multi_step = jax.jit(
                     make_multistep_train_step(
                         train_step,
@@ -645,21 +645,6 @@ class Trainer:
             self.train_step = timed_call(self.train_step, self._m_dispatch)
             if self.multi_step is not None:
                 self.multi_step = timed_call(self.multi_step, self._m_dispatch)
-        profiler = getattr(self.telemetry, "profiler", None)
-        if profiler is not None:
-            # Third sibling in the chain (same jaxpr-inertness contract):
-            # the roofline sentinel's train.step stream.
-            from transformer_tpu.obs.profile import profile_call
-
-            profiler.device_kind = jax.devices()[0].device_kind
-
-            self.train_step = profile_call(
-                self.train_step, profiler, "train.step"
-            )
-            if self.multi_step is not None:
-                self.multi_step = profile_call(
-                    self.multi_step, profiler, "train.step"
-                )
         self.train_step = traced_call(
             self.train_step, self._tracer, "train.step", lane="train"
         )
