@@ -18,7 +18,7 @@ import time
 import numpy as np
 
 from perfbench import program_api as api
-from perfbench import traffic
+from perfbench import program_api_spans, stalls, traffic
 
 # The program computes in bfloat16 and keeps keys and values in bfloat16; the
 # reference computes in float32. Over 30 layers the rounding of a logit adds up
@@ -50,6 +50,19 @@ def check(ctx, config, cell, sched) -> dict:
     ok = bool(np.isfinite(got).all() and diff <= LOGIT_REL_TOL * scale)
     return {"ok": ok, "max_abs_diff": diff, "max_abs_logit": scale, "rel": diff / scale,
             "argmax_agree": agree, "tolerance_rel": LOGIT_REL_TOL, "positions": int(got.shape[1])}
+
+
+def backlog_guard(cell_name: str, offered: int, in_window: list, t0: float, t1: float):
+    """A backlog cell measures a queue that is never empty. The least
+    ``sched.backlog`` over the window's steps, and, where it reads 0 (or the
+    window holds no step at all: the pool was idle), why the run is not
+    ``correct``: the fault is the cell's depth, not the program's speed."""
+    least = min((s[4] for s in in_window), default=0)
+    if least > 0:
+        return least, None
+    ran_out = next((s[1] for s in in_window if s[4] == 0), t0)
+    return least, (f"the backlog of {offered} requests ran out {t1 - ran_out:.1f} s before the window closed: the cell "
+                   f"{cell_name} is too short for this program; a benchmark PR extends backlog_total")
 
 
 class LoadGenerator(threading.Thread):
@@ -136,8 +149,10 @@ def run(ctx, config: dict, cell: dict) -> dict:
     gen = LoadGenerator(sched, requests, t_start)
     t_open, t_close = t_start + warmup_s, t_start + horizon
     gen.stop_at = t_close
+    gc_clock = stalls.GcClock()
     gen.start()
-    # Per sched.step() with a slot active: (t_before, t_after, active, blocks_in_use, backlog, tokens so far)
+    # Per sched.step() with a slot active: (t_before, t_after, active, blocks_in_use, backlog, tokens so far,
+    # this thread's CPU time so far)
     steps: list[tuple] = []
     admit_s = 0.0
     answers: list[dict] = []
@@ -173,7 +188,8 @@ def run(ctx, config: dict, cell: dict) -> dict:
             sched.step()
             t_c = time.perf_counter()
         if active:
-            steps.append((t_b, t_c, active, api.pool_usage(sched)[0], sched.backlog, api.generated_tokens(tel)))
+            steps.append((t_b, t_c, active, api.pool_usage(sched)[0], sched.backlog, api.generated_tokens(tel),
+                          time.thread_time()))
         if tokens_t0 is not None and tokens_t1 is None:
             admit_s += t_b - t_a
         with ctx.span("sched.drain"):
@@ -183,6 +199,7 @@ def run(ctx, config: dict, cell: dict) -> dict:
                 time.sleep(0.0005)
     gen.stop_at = float("-inf")
     gen.join()
+    gc_clock.close()
 
     t0, t1 = ctx.t0, ctx.t1
     rows = _request_rows(requests, spans, t_start, t1)
@@ -202,6 +219,14 @@ def run(ctx, config: dict, cell: dict) -> dict:
         v = sorted(r[key] for r in ok_rows if r.get(key) is not None)
         return scale * v[len(v) // 2] if v else None
 
+    backlog_min, ran_out = backlog_guard(ctx.workload, len(requests), in_window, t0, t1) if backlog else (None, None)
+    sent = [r["t_submit"] for r in requests if "t_submit" in r]
+
+    def spans_of(name):
+        return [(sp["t0_mono"], sp["dur_s"]) for sp in program_api_spans.spans(name, t_start, t1) or ()]
+
+    gaps = stalls.longest_gaps([(st[1], st[6]) for st in steps], t_start, (t0, t1), {
+        "gc": [g[:2] for g in gc_clock.events], "device_wait": spans_of("step.fetch"), "admit": spans_of("serve.admit")})
     third = max(len(in_window) // 3, 1)
     # Output tokens per second in each 5-second slice since the traffic began
     # (warm-up included): the warm-up is long enough when the slices of the
@@ -222,13 +247,22 @@ def run(ctx, config: dict, cell: dict) -> dict:
         "backlog_first_third_mean": float(np.mean([s[4] for s in in_window[:third]])) if in_window else None,
         "backlog_last_third_mean": float(np.mean([s[4] for s in in_window[-third:]])) if in_window else None,
         "backlog_at_close": in_window[-1][4] if in_window else None,
+        "backlog_min_in_window": backlog_min,
+        "submitting_took_s": max(sent) - t_start if sent else None,
+        "longest_gaps_between_step_ends": gaps,
+        "gc_in_window_ms": 1e3 * sum(g[1] for g in gc_clock.events if t0 <= g[0] <= t1),
         "scheduler_stats": {k: sched.stats[k] for k in ("admitted", "steps", "max_active", "prompt_tokens",
                                                         "prefill_forwards", "kv_preempted", "retries")},
     })
+    compared = {"logits_off_rel": [verdict["rel"], "<=", LOGIT_REL_TOL], "answers_of_another_length": [len(wrong_len), "<=", 0]}
+    if backlog:
+        compared["backlog_min_in_window"] = [backlog_min, ">=", 1]
     return {
-        "correct": bool(verdict["ok"] and not wrong_len),
+        "correct": bool(verdict["ok"] and not wrong_len and ran_out is None),
         "why_not_correct": ([] if verdict["ok"] else [f"logits off by {verdict['rel']:.3g} of the largest, over {LOGIT_REL_TOL:g}"])
-        + ([f"{len(wrong_len)} answers of another length than asked"] if wrong_len else []),
+        + ([f"{len(wrong_len)} answers of another length than asked"] if wrong_len else [])
+        + ([ran_out] if ran_out else []),
+        "compared": compared,
         "attempted": len(population),
         "failed": len(failed),
         "window_s": t1 - t0,

@@ -196,7 +196,8 @@ def check(ctx, config, cell, trainer) -> dict:
                      "worst_leaf": m_leaf},
             "as_configured": {"loss_rel": loss_rel, "global_grad_rel": p_global, "worst_leaf_rel": p_worst,
                               "worst_leaf": p_leaf},
-            "tolerance": {k: tol for k, (_, tol) in held.items()}}
+            "tolerance": {k: tol for k, (_, tol) in held.items()},
+            "compared": {k: [v, "<=", tol] for k, (v, tol) in held.items()}}
 
 
 def run(ctx, config: dict, cell: dict) -> dict:
@@ -236,6 +237,7 @@ def run(ctx, config: dict, cell: dict) -> dict:
     return {
         "correct": not why_not,
         "why_not_correct": why_not,
+        "compared": verdict["compared"],
         "attempted": wrapped.steps,
         "failed": 0,
         "window_s": window_s,

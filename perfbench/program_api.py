@@ -104,8 +104,7 @@ def program_loss_and_grads(params, src, tgt, config: dict, label_smoothing: floa
 
 
 class IdTokenizer:
-    """Tokens are ids ("3 17 5" -> [3, 17, 5]), copied from
-    ``benchmarks/decode_bench.py::_IdTok``. ``eos_id`` lies outside every
+    """Tokens are ids ("3 17 5" -> [3, 17, 5]). ``eos_id`` lies outside every
     vocabulary, so a request emits exactly ``max_new`` tokens."""
 
     bos_id, eos_id = 1, -1

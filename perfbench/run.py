@@ -66,7 +66,7 @@ def wanted_metrics(section: str, cell: str) -> list[dict]:
 
 class Context:
     def __init__(self, args, jax):
-        self.seed, self.seconds = args.seed, args.seconds
+        self.workload, self.seed, self.seconds = args.workload, args.seed, args.seconds
         self.trace, self.rehearse = bool(args.trace), args.rehearse
         self._jax = jax
         self.setup_s = None
@@ -179,6 +179,9 @@ def main() -> int:
                        "programs_compiled_or_loaded": len(ctx.compile_times), "setup_done_at_s": ctx.marks})
     why_not = list(record.get("why_not_correct", [])) + ([f"{compiles} programs compiled or loaded inside the window"] if compiles else [])
     correct = bool(record["correct"] and compiles == 0)
+    # Each number compared beside its limit, [reading, "<=" or ">=", limit]: the
+    # last lines of standard error and the last key of the result's line.
+    compared = {**record.get("compared", {}), "programs_compiled_in_window": [compiles, "<=", 0]}
     if not correct:
         print(f"perfbench: {args.workload} seed {args.seed} is not correct: {'; '.join(why_not) or 'no reason given'}",
               file=sys.stderr, flush=True)
@@ -211,6 +214,8 @@ def main() -> int:
         value = None if reader is None else reader(record)
         if value is not None:
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    for name, (value, holds, limit) in compared.items():
+        print(f"perfbench: compared {name} = {value} (has to be {holds} {limit})", file=sys.stderr, flush=True)
     if args.rehearse:
         # A CPU run gives no time, rate or share: only the names that WOULD be reported.
         print(json.dumps({"rehearsal": True, "correct": correct, "attempted": result["attempted"],
@@ -219,7 +224,8 @@ def main() -> int:
         return 0 if correct else 1
     result["metrics"] = metrics
     result["device"] = device
-    print(json.dumps(result), flush=True)
+    result["compared"] = compared
+    print(json.dumps(result, default=float), flush=True)
     return 0
 
 

@@ -77,24 +77,37 @@ def open_loop_requests(p: dict, seed: int, vocab: int, warmup_s: float, seconds:
     multiset of sizes and of gaps, in another order. Lengths count the BOS the
     scheduler prepends, so ``ids`` has ``prompt_len - 1`` entries. Each request:
     due_s, phase ("warmup" or "window"), ids (int32 in [3, vocab)), max_new.
+
+    A backlog grows at its END: ``backlog_total`` (absent = ``backlog_requests``)
+    appends ``backlog_total - backlog_requests`` requests, due at 0 like the
+    rest, whose sizes come from a stream of their own (``_rng(shape_seed, 3)``)
+    and whose ids continue on the seed's stream. The first ``backlog_requests``
+    stay what they were draw for draw, so a deeper queue leaves the window of
+    today's program the requests it had: writing a larger ``backlog_requests``
+    would move every draw (the gaps take ``n + 1`` numbers before the first
+    length).
     """
     shape_rng = _rng(p["shape_seed"], 0)
     ids_rng = _rng(seed, 2)
     pr, out = p["prompt"], p["output"]
+    appended = 0
     if p["arrivals"] == "backlog":
         phases = [("window", int(p["backlog_requests"]), 0.0, 0.0)]
+        appended = int(p.get("backlog_total", p["backlog_requests"])) - int(p["backlog_requests"])
+        if appended < 0:
+            raise ValueError("backlog_total is below backlog_requests: a backlog grows at its end only")
     elif p["arrivals"] == "poisson":
         phases = [("warmup", round(p["rate_rps"] * warmup_s), 0.0, warmup_s),
                   ("window", round(p["rate_rps"] * seconds), warmup_s, seconds)]
     else:
         raise ValueError(f"unknown arrivals {p['arrivals']!r}")
     reqs = []
-    for phase, n, begins, lasts in phases:
-        gaps = shape_rng.exponential(1.0, size=n + 1)
-        gaps *= lasts / gaps.sum()  # n arrivals strictly inside the phase; the last gap is its tail
-        prompt_len = lognormal_lengths(shape_rng, n, pr["median"], pr["sigma"], pr["min"], pr["max"])
-        out_len = lognormal_lengths(shape_rng, n, out["median"], out["sigma"], out["min"], out["max"])
-        due = begins + np.cumsum(gaps[:n])
+
+    def lengths(rng, n):
+        return (lognormal_lengths(rng, n, pr["median"], pr["sigma"], pr["min"], pr["max"]),
+                lognormal_lengths(rng, n, out["median"], out["sigma"], out["min"], out["max"]))
+
+    def add(phase, due, prompt_len, out_len):
         for d, pl, ol in zip(due, prompt_len, out_len):
             reqs.append({
                 "due_s": float(d),
@@ -102,4 +115,11 @@ def open_loop_requests(p: dict, seed: int, vocab: int, warmup_s: float, seconds:
                 "ids": ids_rng.integers(3, vocab, size=int(pl) - 1, dtype=np.int32),
                 "max_new": int(ol),
             })
+
+    for phase, n, begins, lasts in phases:
+        gaps = shape_rng.exponential(1.0, size=n + 1)
+        gaps *= lasts / gaps.sum()  # n arrivals strictly inside the phase; the last gap is its tail
+        add(phase, begins + np.cumsum(gaps[:n]), *lengths(shape_rng, n))
+    if appended:
+        add("window", np.zeros(appended), *lengths(_rng(p["shape_seed"], 3), appended))
     return reqs
