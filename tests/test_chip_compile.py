@@ -85,10 +85,11 @@ def test_flash_attention_fwd_and_grad(one_chip, seq, batch):
 # (id, slots, heads, KV heads, head size, block_tokens, table width, pool
 # blocks, S_q, int8). The first six are the base preset's width in its cache
 # variants (D = 64: every page of a compute block its own BlockSpec); ``cell``
-# is the serving cells' own shape, StarCoder2-3B's 24 heads over 2 KV heads of
-# 128 under 48 slots and a table of 128 pages of 16 (the pools left in HBM,
-# pages copied by hand), decode and verify rows, and its int8 twin; ``wide``
-# the same route with four packed sublanes of heads.
+# is the StarCoder2-3B cells' own shape, 24 heads over 2 KV heads of 128 under
+# 48 slots and a table of 128 pages of 16 (the pools left in HBM, live pages
+# copied by hand into blocks of 512 positions, a fold a width of live
+# sub-chunks), decode and verify rows, and its int8 twin; ``wide`` the same
+# route with four packed sublanes of heads.
 _PAGED_CASES = [
     (f"{variant}-{h_kv}-{block}-{s_q}", 8, 8, h_kv, 64, block, 8, 64, s_q,
      variant == "int8")
@@ -102,6 +103,17 @@ _PAGED_CASES = [
 ]
 
 
+def _assert_streamed_block(block_tokens, h_kv, d, nmax):
+    """A streamed call at a deployment's shape works in blocks of 512 key
+    positions in sub-chunks of 128, its four block buffers inside the budget
+    (the compile that follows holds the whole kernel to the chip's VMEM)."""
+    from transformer_tpu.kernels import paged_flash as pf
+
+    pages, chunk = pf._pages_per_block(block_tokens, h_kv, d, 2, False, nmax, True)
+    assert (pages * block_tokens, chunk * block_tokens) == (512, 128)
+    assert 4 * pages * pf._page_vmem_bytes(block_tokens, h_kv, d, 2) <= pf._BUFFER_BUDGET
+
+
 @pytest.mark.parametrize(
     "n,h,h_kv,d,block_tokens,nmax,num_blocks,s_q,quantized",
     [c[1:] for c in _PAGED_CASES], ids=[c[0] for c in _PAGED_CASES],
@@ -109,7 +121,10 @@ _PAGED_CASES = [
 def test_paged_flash_attention(
     one_chip, n, h, h_kv, d, block_tokens, nmax, num_blocks, s_q, quantized
 ):
-    from transformer_tpu.kernels.paged_flash import paged_flash_attention
+    from transformer_tpu.kernels.paged_flash import _streamable, paged_flash_attention
+
+    if not quantized and _streamable(h_kv, d, BF16):
+        _assert_streamed_block(block_tokens, h_kv, d, nmax)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -135,11 +150,18 @@ def test_paged_flash_attention(
     assert f"[{num_blocks},{block_tokens},{h_kv},{d}]" in call.group(0)
 
 
-@pytest.mark.parametrize("heads,window", [(72, 512), (48, 0)], ids=["laguna-window", "laguna-full"])
-def test_paged_flash_attention_band(one_chip, heads, window):
+@pytest.mark.parametrize("heads,window,s_q", [(72, 512, 1), (48, 0, 1), (72, 512, 3), (48, 0, 3)],
+                         ids=["laguna-window", "laguna-full", "laguna-window-verify", "laguna-full-verify"])
+def test_paged_flash_attention_band(one_chip, heads, window, s_q):
     """The Laguna cell's two layer kinds over one pool: 8 KV heads of 128,
-    pages of 16, a table 256 wide; the window layer's band is static."""
-    from transformer_tpu.kernels.paged_flash import paged_flash_attention
+    pages of 16, a table 256 wide, 32 slots; the window layer's band is static
+    and does not bound the block: both kinds work in blocks of 512 positions
+    (two K/V buffers of 4 MB between them, the unpacked heads of the widest
+    fold beside them: the compile holds that to the chip's scoped VMEM)."""
+    from transformer_tpu.kernels.paged_flash import _streamable, paged_flash_attention
+
+    assert _streamable(8, 128, BF16)
+    _assert_streamed_block(16, 8, 128, 256)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -149,7 +171,7 @@ def test_paged_flash_attention_band(one_chip, heads, window):
     def fn(q, k_pool, v_pool, table, lengths):
         return paged_flash_attention(q, k_pool, v_pool, table, lengths, window=window, interpret=False)
 
-    text = _compile(fn, sds((32, 1, heads, 128), BF16), pool, pool, sds((32, 256), jnp.int32),
+    text = _compile(fn, sds((32, s_q, heads, 128), BF16), pool, pool, sds((32, 256), jnp.int32),
                     sds((32,), jnp.int32)).as_text()
     assert re.search(r"%paged_flash_attention[\w.]* = [^\n]*custom-call\(", text) and text.count("tpu_custom_call") == 1
 

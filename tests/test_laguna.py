@@ -212,39 +212,51 @@ def test_tile_rows_follow_the_rows_an_expert_expects():
 # ------------------------------------------------- the paged kernel's band
 
 LENGTHS = [1, 15, 16, 17, 39, 40, 41, 63, 64, 65, 130, 384]
+# Under a table of two streamed compute blocks and a quarter (1,152 positions):
+# the band starts in the first page, mid-page, on a sub-chunk's edge (128), mid
+# block and in the block before the slot's last, and reaches over two blocks.
+WIDE_LENGTHS = [1, 127, 128, 129, 300, 512, 513, 700, 1024, 1025, 1152]
 
 
-@pytest.mark.parametrize("route,head", [("streamed", 128), ("tiled", 64)])
-@pytest.mark.parametrize("window", [16, 40, 100])
-def test_paged_kernel_band_against_the_xla_oracle(route, head, window):
-    """Lengths below, at and above the window and across block (16) and
-    compute-block (64) edges; both routes of the kernel."""
+@pytest.mark.parametrize(
+    "route,head,window,lengths,nmax",
+    [(route, head, window, LENGTHS, 24) for route, head in [("streamed", 128), ("tiled", 64)] for window in (16, 40, 100)]
+    + [("streamed", 128, window, WIDE_LENGTHS, 72) for window in (100, 300, 520)],
+)
+def test_paged_kernel_band_against_the_xla_oracle(route, head, window, lengths, nmax):
+    """Lengths below, at and above the window and across page (16), sub-chunk
+    (128) and compute-block (64 tiled, 512 streamed) edges; both routes of the
+    kernel, and the streamed one over a table several blocks wide."""
     from transformer_tpu.kernels.paged_flash import _streamable
 
     assert _streamable(2, head, jnp.float32) == (route == "streamed")
-    n, nmax = len(LENGTHS), 24
+    n = len(lengths)
     k = jax.random.split(jax.random.PRNGKey(window), 3)
     q = jax.random.normal(k[0], (n, 1, 6, head))
     kp, vp = (jax.random.normal(k[i], (1 + n * nmax, 16, 2, head)) for i in (1, 2))
     table = jnp.asarray(1 + np.arange(n * nmax).reshape(n, nmax), jnp.int32)
-    lengths = jnp.asarray(LENGTHS, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
     want = paged_attention(q, kp, vp, table, lengths, impl="xla", window=window)
     got = paged_attention(q, kp, vp, table, lengths, impl="paged_flash", window=window)
     np.testing.assert_allclose(got, want, atol=2e-6)
     full = paged_attention(q, kp, vp, table, lengths, impl="xla")
-    short = np.asarray(LENGTHS) <= window
+    short = np.asarray(lengths) <= window
     np.testing.assert_allclose(np.asarray(want)[short], np.asarray(full)[short], atol=1e-6)  # the band cuts nothing yet
     assert np.abs(np.asarray(want)[~short] - np.asarray(full)[~short]).max() > 1e-3
 
 
-def test_paged_kernel_band_on_verify_rows():
+@pytest.mark.parametrize("lengths,nmax,window", [([5, 70, 190], 12, 33), ([130, 514, 650], 41, 130)],
+                         ids=["one_block", "rows_and_band_across_sub_chunk_and_block_edges"])
+def test_paged_kernel_band_on_verify_rows(lengths, nmax, window):
+    """The second case: three query rows at 127-129 (a sub-chunk's edge), at 511-513 (a block's) and at 647-649 with
+    the band's first position (row 0's) at 518, six positions into the second block."""
     k = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(k[0], (3, 3, 4, 128))
-    kp, vp = (jax.random.normal(k[i], (40, 16, 2, 128)) for i in (1, 2))
-    table = jnp.asarray(1 + np.arange(36).reshape(3, 12), jnp.int32)
-    lengths = jnp.asarray([5, 70, 190], jnp.int32)
-    want = paged_attention(q, kp, vp, table, lengths, impl="xla", window=33)
-    np.testing.assert_allclose(paged_attention(q, kp, vp, table, lengths, impl="paged_flash", window=33), want, atol=2e-6)
+    kp, vp = (jax.random.normal(k[i], (1 + 3 * nmax, 16, 2, 128)) for i in (1, 2))
+    table = jnp.asarray(1 + np.arange(3 * nmax).reshape(3, nmax), jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    want = paged_attention(q, kp, vp, table, lengths, impl="xla", window=window)
+    np.testing.assert_allclose(paged_attention(q, kp, vp, table, lengths, impl="paged_flash", window=window), want, atol=2e-6)
 
 
 # ------------------------------------------------ the model, the reference

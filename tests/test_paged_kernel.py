@@ -100,17 +100,21 @@ _KERNEL_VARIANTS = {
 }
 
 
-def _block_positions(variant: str, block_tokens: int) -> int:
-    """Key positions in one compute block of the kernel for this variant's
-    shapes under a table wide enough not to bound it, by the kernel's rule."""
-    from transformer_tpu.kernels.paged_flash import _pages_per_block
+def _block_positions(variant: str, block_tokens: int) -> tuple[int, int]:
+    """Key positions in one compute block of the kernel, and in one of its
+    sub-chunks (the block itself where it has none), for this variant's
+    shapes under a table wide enough not to bound it, by the kernel's rule:
+    the route decides, and a window layer's band does not."""
+    from transformer_tpu.kernels.paged_flash import _pages_per_block, _streamable
 
     spec = _KERNEL_VARIANTS[variant]
+    d = spec.get("d", 8)
     itemsize = 1 if spec["quantized"] else jnp.dtype(spec["dtype"]).itemsize
-    return block_tokens * _pages_per_block(
-        block_tokens, spec["h_kv"], spec.get("d", 8), itemsize,
-        spec["quantized"], 1 << 20,
+    streamed = not spec["quantized"] and _streamable(spec["h_kv"], d, spec["dtype"])
+    pages, chunk = _pages_per_block(
+        block_tokens, spec["h_kv"], d, itemsize, spec["quantized"], 1 << 20, streamed
     )
+    return block_tokens * pages, block_tokens * chunk
 
 
 def _pool_case(
@@ -124,20 +128,23 @@ def _pool_case(
 
     Narrow (the default): 7 blocks under a table of 4 entries, one compute
     block. ``wide``: a table of two compute blocks and a half, so not a whole
-    number of them, over slots whose newest position lies (a) inside the first
-    page, (b) on the last position of the first compute block, (c) on the
-    first of the second, (d) at the table's full width, beside (e) a free slot:
-    length ``s_q`` under an all-sink table."""
+    number of them (1,280 positions under the streamed route's 512), over
+    slots whose newest position lies (a) inside the first page, (b) on the
+    last position of the first compute block, (c) on the first of the second,
+    (d) at the table's full width, (e) on the last position of the first
+    sub-chunk and (f) on the first of the next (with ``s_q`` > 1 the query
+    rows then straddle that edge), beside (g) a free slot: length ``s_q``
+    under an all-sink table."""
     spec = _KERNEL_VARIANTS[variant]
     block_tokens = block_tokens or spec.get("block_tokens", 8)
     rng = np.random.default_rng(seed)
     d = spec.get("d", 8)
     if wide:
-        positions = _block_positions(variant, block_tokens)
+        positions, chunk = _block_positions(variant, block_tokens)
         nmax = 5 * positions // (2 * block_tokens)
         lengths = np.array(
             [max(s_q, block_tokens // 2), positions, positions + 1,
-             nmax * block_tokens, s_q], np.int32,
+             nmax * block_tokens, chunk, chunk + 1, s_q], np.int32,
         )
         owned = -(-lengths // block_tokens)
         owned[-1] = 0
@@ -242,17 +249,24 @@ def test_kernel_rejects_untileable_block_tokens():
 
 
 @pytest.mark.parametrize(
-    "variant,wide", [("bf16", False), ("bf16", True), ("serve_bf16", True)],
-    ids=["last_entry", "every_dead_entry", "every_dead_entry_streamed"],
+    "variant,wide,window",
+    [("bf16", False, 0), ("bf16", True, 0), ("serve_bf16", True, 0),
+     ("bf16", True, 24), ("serve_bf16", True, 200)],
+    ids=["last_entry", "every_dead_entry", "every_dead_entry_streamed",
+         "before_the_band", "before_the_band_streamed"],
 )
-def test_kernel_skips_sink_blocks(variant, wide):
+def test_kernel_skips_sink_blocks(variant, wide, window):
     """Out-of-length table entries are never read: rewriting them to
     arbitrary (even out-of-range-of-length) block ids leaves the output
     bit-identical, pinning the stale-row/sink masking the pool's free
     list relies on. Narrow: the one dead entry of each slot. Wide: every
-    entry past each slot's length, those inside a live compute block and
-    those of whole dead blocks, on both ways of fetching a block."""
+    entry past each slot's length, those inside a live compute block (and
+    inside a live sub-chunk) and those of whole dead blocks, on both ways of
+    fetching a block. With a band: every entry whose page ends before it
+    too, in the band's first block and in the blocks before it."""
     q, k, v, table, lengths, kw = _pool_case(variant, 1, wide=wide)
+    if window:
+        kw["window"] = window
     base = paged_attention(
         q, k, v, table, lengths, impl="paged_flash", interpret=True, **kw
     )
@@ -260,6 +274,10 @@ def test_kernel_skips_sink_blocks(variant, wide):
         block_tokens, blocks = k.shape[1], k.shape[0]
         entry = jnp.arange(table.shape[1])[None, :]
         dead = entry * block_tokens >= lengths[:, None]
+        if window:
+            before = (entry + 1) * block_tokens <= lengths[:, None] - window
+            assert int(before.sum()) > table.shape[1] // 2
+            dead |= before
         noise = jnp.asarray(
             np.random.default_rng(1).integers(0, blocks, table.shape), jnp.int32
         )
@@ -271,6 +289,12 @@ def test_kernel_skips_sink_blocks(variant, wide):
         q, k, v, hostile, lengths, impl="paged_flash", interpret=True, **kw
     )
     np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
+    if window:
+        want = paged_attention(q, k, v, table, lengths, impl="xla", **kw)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=_TOL[variant], atol=_TOL[variant],
+        )
 
 
 # --------------------------------------------------------------------------

@@ -1184,8 +1184,9 @@ def _canned_entries() -> dict[str, Callable[[], tuple[Callable, tuple]]]:
     def paged_streamed():
         # Pages that fill whole tiles (D a lane row, both heads one packed
         # sublane) take the kernel's streamed route: pools left in HBM, hand-
-        # made page copies, a loop by length. The table is wider than one
-        # compute block (4 pages of 16).
+        # made copies of the live pages, a loop by length, a fold a width of
+        # live sub-chunks. The table is wider than one compute block (32
+        # pages of 16 in sub-chunks of 8); slot 1 ends in its second block.
         rng = np.random.default_rng(0)
         table = jnp.asarray(rng.integers(1, 24, (2, 40)), jnp.int32)
         lengths = jnp.asarray([18, 600], jnp.int32)
@@ -1419,7 +1420,7 @@ def analyze_entries(
             if eqn is not None and _has_data_dependent_loop(eqn):
                 site_notes.append(
                     "a loop's trip count is read at run time: FLOPs count one "
-                    "trip a grid step"
+                    "trip a grid step, and every branch of that trip"
                 )
             for msg in conf.violations + race:
                 violations.append(f"{site}: {msg}")
