@@ -25,10 +25,13 @@ PAD_ID = 0
 
 @dataclasses.dataclass(frozen=True)
 class AttentionKind:
-    """One kind of self-attention layer in a model whose layers differ
-    (``ModelConfig.attention_kinds`` / ``layer_pattern``): its query heads,
-    its causal window and its rotary frequencies. Every kind shares the
-    model's KV heads and head size, so all layers fit one KV pool."""
+    """One kind of layer in a model whose layers differ
+    (``ModelConfig.attention_kinds`` / ``layer_pattern``): a self-attention
+    layer's query heads, its causal window and its rotary frequencies (every
+    attention kind shares the model's KV heads and head size, so all of them
+    fit one KV pool), or, with ``conv_kernel``, a gated short convolution in
+    the attention sublayer's place (``ops/short_conv.py``), whose state is
+    ``conv_kernel - 1`` rows a sequence and no KV rows at all."""
 
     name: str
     num_heads: int = 0  # query heads; 0 = ModelConfig.num_heads
@@ -46,6 +49,9 @@ class AttentionKind:
     yarn_beta_slow: float = 1.0
     # Multiplies cos and sin (YaRN's attention factor; 1 = none).
     rope_attention_factor: float = 1.0
+    # Taps of the causal depthwise convolution of a short-convolution layer
+    # (0 = an attention layer; the fields above then say which).
+    conv_kernel: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,6 +172,18 @@ class ModelConfig:
     # behave like a checkpoint's set these (PERF.md section 6, PR 28).
     moe_router_init_scale: float = 1.0
     moe_out_init_scale: float = 1.0
+    # The dropless router's scores: "softmax" over all experts, the top-k
+    # renormalised; or "sigmoid" (the auxiliary-loss-free balancing of
+    # arXiv:2408.15664): the top-k of ``score + bias`` where
+    # ``moe_select_bias`` gives the router a float32 bias that takes part in
+    # the choice only, weights ``score / (sum of the chosen scores +
+    # moe_renorm_epsilon)`` from the scores without it.
+    moe_score: str = "softmax"  # "softmax" | "sigmoid"
+    moe_select_bias: bool = False
+    moe_renorm_epsilon: float = 1e-6
+    # RMSNorm over each head's channels of q and of k (own scales), before
+    # the rotation; its epsilon is ``layernorm_epsilon``.
+    qk_norm: bool = False
     # Block options of the RMSNorm / no-bias families.
     norm: str = "layernorm"  # "layernorm" | "rmsnorm" (parameter: scale only)
     use_bias: bool = True  # biases on projections, FFN and the untied head
@@ -200,6 +218,10 @@ class ModelConfig:
                 raise ValueError(f"attention kind {k} does not fit this model")
             if int(self.head_dim * k.rotary_share) % 2:
                 raise ValueError(f"attention kind {k.name!r} rotates an odd number of channels")
+            if k.conv_kernel < 0 or k.conv_kernel == 1:
+                raise ValueError(f"layer kind {k.name!r}: conv_kernel is 0 (attention) or 2 and more taps")
+        if self.moe_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_score must be 'softmax' or 'sigmoid', got {self.moe_score!r}")
         if self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got {self.norm!r}")
         if self.attention_gate not in ("", "per_head"):
@@ -266,11 +288,15 @@ class ModelConfig:
         if not dropless and (
             self.moe_experts_held or self.moe_expert_offset or self.moe_shared_dff
             or self.moe_dff or self.moe_routed_scale != 1.0
+            or self.moe_score != "softmax" or self.moe_select_bias
         ):
             raise ValueError(
-                "a share of the experts, a shared expert, an expert width and a "
-                "routed scale need moe_dispatch='dropless'"
+                "a share of the experts, a shared expert, an expert width, a "
+                "routed scale, sigmoid scores and a selection bias need "
+                "moe_dispatch='dropless'"
             )
+        if self.moe_select_bias and self.moe_score != "sigmoid":
+            raise ValueError("moe_select_bias goes with moe_score='sigmoid'")
         if self.moe_router_init_scale <= 0 or self.moe_out_init_scale <= 0:
             raise ValueError(
                 "moe_router_init_scale and moe_out_init_scale must be > 0 (got "
@@ -313,6 +339,14 @@ class ModelConfig:
         name = self.layer_pattern[layer_index % len(self.layer_pattern)]
         kind = next(k for k in self.attention_kinds if k.name == name)
         return kind if kind.num_heads else dataclasses.replace(kind, num_heads=self.num_heads)
+
+    @property
+    def state_layers(self) -> tuple[int, ...]:
+        """Layers whose state a sequence carries is not KV rows: the
+        short-convolution layers."""
+        return tuple(
+            i for i in range(self.num_layers) if self.layer_kind(i).conv_kernel
+        )
 
     @property
     def experts_held(self) -> int:

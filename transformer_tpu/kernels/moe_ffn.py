@@ -34,6 +34,18 @@ from transformer_tpu.ops.ffn import _GATED_ACTIVATIONS, _ffn_tile
 # width: on the chip 8 % faster at decode than slices of 256 or 512, PERF.md
 # PR 28) at a 3,072-wide model take 38 MB; the default scoped limit is 16.
 _VMEM_LIMIT = 64 * 1024 * 1024
+# What the three double-buffered weight slices of a grid step may take of it
+# (the rows, the accumulator and the output tile are small beside them).
+_SLICE_BUDGET = 48 * 1024 * 1024
+
+
+def expert_slice_width(m: int, dff: int, itemsize: int) -> int:
+    """Columns of the expert width one grid step reads: the whole expert
+    where its three matrices, double-buffered, fit ``_SLICE_BUDGET`` (one
+    step an expert: no slice's fixed cost paid twice), else the widest lane
+    tile that divides the width and does."""
+    fits = _SLICE_BUDGET // (2 * 3 * m * itemsize)
+    return dff if dff <= fits else _ffn_tile(dff, fits)
 
 
 def _kernel(
@@ -88,7 +100,7 @@ def moe_expert_ffn(
     *,
     tile_rows: int,
     activation: str = "swiglu",
-    block_dff: int = 1024,
+    block_dff: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """``act(x @ w_gate[e]) * (x @ w_in[e]) @ w_out[e]`` for each row tile's
@@ -101,6 +113,8 @@ def moe_expert_ffn(
       tile_group: (tiles,) int32, the expert of each tile; entries at or past
         ``live_tiles`` repeat the last live tile's.
       live_tiles: () int32, how many leading tiles hold rows.
+      block_dff: columns of the expert width a grid step reads (None:
+        ``expert_slice_width``).
 
     Returns ((tiles + 1) * tile_rows, M): the rows of ``x``'s tiles, then a
     spare tile that takes what the dead grid steps write back. Rows of tiles
@@ -109,6 +123,8 @@ def moe_expert_ffn(
     rows, m = x.shape
     tiles = rows // tile_rows
     dff = w_gate.shape[2]
+    if block_dff is None:
+        block_dff = expert_slice_width(m, dff, jnp.dtype(w_gate.dtype).itemsize)
     bf = _ffn_tile(dff, block_dff)
     nf = dff // bf
     if interpret is None:

@@ -18,9 +18,15 @@ from __future__ import annotations
 from typing import Any
 
 import jax
+import jax.numpy as jnp
 
 from transformer_tpu.config import ModelConfig
 from transformer_tpu.ops.attention import init_cache, mha_apply, mha_init
+from transformer_tpu.ops.short_conv import (
+    init_conv_state,
+    short_conv_apply,
+    short_conv_init,
+)
 from transformer_tpu.ops.nn import (
     Params,
     embedding_init,
@@ -44,8 +50,15 @@ def decoder_layer_init(
     key: jax.Array, cfg: ModelConfig, layer_index: int = 0
 ) -> Params:
     k1, k2, k3 = jax.random.split(key, 3)
+    taps = cfg.layer_kind(layer_index).conv_kernel
+    # The layer's mixer: self-attention, or a short convolution in its place.
+    mixer = (
+        {"conv": short_conv_init(k1, cfg.d_model, taps, cfg.params_dtype)}
+        if taps
+        else {"self_mha": attention_init(k1, cfg, layer_index)}
+    )
     params: Params = {
-        "self_mha": attention_init(k1, cfg, layer_index),
+        **mixer,
         **_ffn_sublayer_init(k3, cfg, layer_uses_moe(cfg, layer_index)),
         "ln1": norm_init(cfg.d_model, cfg.params_dtype, cfg.norm),
         "ln_ffn": norm_init(cfg.d_model, cfg.params_dtype, cfg.norm),
@@ -83,11 +96,22 @@ def decoder_layer_apply(
     decode steps don't re-project the static encoder output every token.
     ``layer_index`` (static) picks the layer's attention kind where the
     model's layers differ: its window and its rotary frequencies (its heads
-    are the parameters' shape).
+    are the parameters' shape). A short-convolution layer's cache is
+    ``{"conv_state", "index"}``; it reads the state only past position 0, so
+    whatever an earlier sequence left in it is never seen.
     """
     r1, r2, r3 = (None, None, None) if rng is None else jax.random.split(rng, 3)
     boxes: list[Any] = [None, None, None]
     aux_box: list = [None]
+
+    def short_conv(h):
+        state = None
+        if cache is not None:
+            state = jnp.where(cache["index"] > 0, cache["conv_state"], 0)
+        out, state = short_conv_apply(params["conv"], h, state)
+        if cache is not None:
+            boxes[2] = {"conv_state": state, "index": cache["index"] + h.shape[1]}
+        return out
 
     def self_attn(h):
         out, w, new_cache = mha_apply(
@@ -100,11 +124,13 @@ def decoder_layer_apply(
             flash_block_q=cfg.flash_block_q,
             flash_block_k=cfg.flash_block_k,
             rope=layer_rope(cfg, layer_index),
+            qk_norm_epsilon=cfg.layernorm_epsilon,
         )
         boxes[0], boxes[2] = w, new_cache
         return out
 
-    x = _sublayer(cfg, params["ln1"], x, self_attn, r1, deterministic)
+    mixer = short_conv if "conv" in params else self_attn
+    x = _sublayer(cfg, params["ln1"], x, mixer, r1, deterministic)
 
     if not cfg.decoder_only:
         if enc_out is None:
@@ -261,17 +287,24 @@ def init_decoder_caches(
 ) -> list[dict[str, Any]]:
     """One self-attention KV cache per decoder layer (int8-quantized when
     ``cfg.kv_cache_int8``; a rolling O(window) buffer when
-    ``cfg.attention_window``). Caches start at position 0; fill the prompt
-    in one pass with ``decoder_prefill`` and decode incrementally from
+    ``cfg.attention_window``), or, for a short-convolution layer, its state
+    and the position it stands at. Caches start at position 0; fill the
+    prompt in one pass with ``decoder_prefill`` and decode incrementally from
     there (``transformer_decode_step``)."""
-    return [
-        init_cache(
+    def one(i):
+        taps = cfg.layer_kind(i).conv_kernel
+        if taps:
+            return {
+                "conv_state": init_conv_state(batch_size, cfg.d_model, taps, cfg.compute_dtype),
+                "index": jnp.array(0, dtype=jnp.int32),
+            }
+        return init_cache(
             batch_size, max_len, cfg.kv_heads, cfg.head_dim,
             cfg.compute_dtype, quantize=cfg.kv_cache_int8,
             window=cfg.attention_window,
         )
-        for _ in range(cfg.num_layers)
-    ]
+
+    return [one(i) for i in range(cfg.num_layers)]
 
 
 def precompute_cross_kvs(

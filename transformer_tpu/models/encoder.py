@@ -51,7 +51,7 @@ def attention_init(key: jax.Array, cfg: ModelConfig, layer_index: int) -> Params
     return mha_init(
         key, cfg.d_model, cfg.layer_kind(layer_index).num_heads, cfg.params_dtype,
         num_kv_heads=cfg.kv_heads, head_dim=cfg.head_dim, use_bias=cfg.use_bias,
-        gate=cfg.attention_gate == "per_head",
+        gate=cfg.attention_gate == "per_head", qk_norm=cfg.qk_norm,
     )
 
 
@@ -70,6 +70,7 @@ def _ffn_sublayer_init(key: jax.Array, cfg: ModelConfig, use_moe: bool) -> dict:
                 cfg.params_dtype, experts_held=cfg.moe_experts_held,
                 activation=cfg.ffn_activation, shared_dff=cfg.moe_shared_dff,
                 router_scale=cfg.moe_router_init_scale, out_scale=cfg.moe_out_init_scale,
+                select_bias=cfg.moe_select_bias,
             )
         }
     return {
@@ -120,6 +121,7 @@ def dropless_moe(moe_params: Params, h: jax.Array, cfg: ModelConfig, token_mask,
         moe_params, h,
         num_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
         expert_offset=cfg.moe_expert_offset, routed_scale=cfg.moe_routed_scale,
+        score=cfg.moe_score, renorm_epsilon=cfg.moe_renorm_epsilon,
         activation=cfg.ffn_activation, token_mask=token_mask, interpret=interpret,
     )
 

@@ -35,7 +35,11 @@ that holds ``length - window``). A dropless expert layer runs inside the
 step too (``ops/moe.py moe_apply_dropless``: the grouped kernel reads the
 experts the step's tokens hit), free slots routed nowhere; its counts of
 picks held here and of experts hit accumulate in the pool pytree
-(``MOE_COUNTS``), where the scheduler reads them every few steps.
+(``MOE_COUNTS``), where the scheduler reads them every few steps. A
+short-convolution layer (``ops/short_conv.py``) has no pool: its entry of
+the pool pytree is a fixed state row a slot, ``{"conv_state": (N, L - 1,
+d_model)}``, which the step reads past position 0 and rolls for the live
+slots alone.
 
 Scope guards (the gather path remains the general fallback): decoder-only
 LM configs, no model-wide ``attention_window`` (that option makes the dense
@@ -63,14 +67,22 @@ from transformer_tpu.models.encoder import (
     layer_rope,
 )
 from transformer_tpu.models.transformer import project_logits
-from transformer_tpu.ops.attention import _project, _quantize_kv, kv_buffer_keys, merge_heads
+from transformer_tpu.ops.attention import (
+    _project,
+    _quantize_kv,
+    kv_buffer_keys,
+    merge_heads,
+    normalise_qk,
+)
 from transformer_tpu.ops.ffn import fused_ln_ffn
 from transformer_tpu.ops.nn import Params, norm_apply
 from transformer_tpu.ops.positional import apply_rope
+from transformer_tpu.ops.short_conv import short_conv_apply
 
 # Key of the pool pytree (in the first expert layer's dict) under which a
 # dropless model's decode steps accumulate int32 [picks held here, experts
-# hit, steps]: the programs return (logits, pools) and nothing else.
+# hit, rows of the most-loaded expert, steps]: the programs return (logits,
+# pools) and nothing else.
 MOE_COUNTS = "moe_counts"
 
 
@@ -128,7 +140,8 @@ def paged_decode_forward(
       params: full transformer params (decoder-only config).
       toks: (N, S_q) int32 token ids — S_q = 1 for plain decode, k + 1 for
         speculative verify (scored causally inside the row).
-      pool_caches: per-layer ``init_block_pool`` buffers.
+      pool_caches: per-layer ``init_block_pool`` buffers (a
+        short-convolution layer's: its ``conv_state``).
       table: (N, nmax) int32 device block table.
       index: (N,) int32 per-slot positions BEFORE this forward; slot s's
         tokens sit at absolute positions ``index[s] .. index[s] + S_q - 1``.
@@ -138,7 +151,7 @@ def paged_decode_forward(
     Returns ((N, S_q, vocab) logits for every fed position, updated pools).
     Free slots (index 0, all-sink tables) produce garbage logits into rows
     the host discards and write only sink rows — same contract as the
-    gather twins.
+    gather twins; a short-convolution layer's state is theirs untouched.
     """
     dec = params["decoder"]
     n, s_q = toks.shape
@@ -169,6 +182,7 @@ def paged_decode_forward(
             q = _project(mp["query"], h, dtype)
             k = _project(mp["key"], h, dtype)
             v = _project(mp["value"], h, dtype)
+            q, k = normalise_qk(mp, q, k, cfg.layernorm_epsilon)
             if rope:
                 rot = jax.vmap(
                     lambda t, off: apply_rope(t[None], off + jnp.arange(s_q), **rope)[0]
@@ -184,7 +198,17 @@ def paged_decode_forward(
             )
             return merge_heads(mp, out, h)
 
-        x = _sublayer(cfg, layer["ln1"], x, self_attn, None, True)
+        def short_conv(h, layer=layer, pool_box=pool_box):
+            # A slot past position 0 reads its own state; one at position 0
+            # (free, fed PAD) reads zeros and keeps whatever it held.
+            live = (index > 0)[:, None, None]
+            old = pool_box[0]["conv_state"]
+            out, state = short_conv_apply(layer["conv"], h, jnp.where(live, old, 0))
+            pool_box[0] = {"conv_state": jnp.where(live, state, old)}
+            return out
+
+        mixer = short_conv if "conv" in layer else self_attn
+        x = _sublayer(cfg, layer["ln1"], x, mixer, None, True)
         new_pools.append(pool_box[0])
 
         if "moe" in layer and cfg.moe_dispatch == "dropless":

@@ -97,6 +97,7 @@ def mha_init(
     head_dim: int | None = None,
     use_bias: bool = True,
     gate: bool = False,
+    qk_norm: bool = False,
 ) -> Params:
     """Parameters for multi-head attention: q/k/v projections shaped
     (d_model, heads, head_dim) and an output projection (heads, head_dim,
@@ -110,7 +111,8 @@ def mha_init(
     ``head_dim`` is ``d_model / num_heads`` unless given (a model whose heads
     are wider than that: the out projection is then (H, D, d_model) with
     ``H * D != d_model``). ``use_bias=False`` leaves the four biases out;
-    ``gate`` adds the (d_model, H) kernel of a per-head output gate."""
+    ``gate`` adds the (d_model, H) kernel of a per-head output gate;
+    ``qk_norm`` the two (head_dim,) scales of ``normalise_qk``."""
     head_dim = head_dim or d_model // num_heads
     kv_heads = num_kv_heads or num_heads
     kq, kk, kv, ko = jax.random.split(key, 4)
@@ -141,6 +143,9 @@ def mha_init(
                 jax.random.fold_in(key, 4), (d_model, num_heads), param_dtype, d_model, num_heads
             )
         }
+    if qk_norm:
+        params["q_norm"] = {"scale": jnp.ones((head_dim,), param_dtype)}
+        params["k_norm"] = {"scale": jnp.ones((head_dim,), param_dtype)}
     return params
 
 
@@ -148,6 +153,22 @@ def _project(p: Params, x: jax.Array, dtype) -> jax.Array:
     # (B, S, M) @ (M, H, D) -> (B, S, H, D)
     y = jnp.einsum("bsm,mhd->bshd", x.astype(dtype), p["kernel"].astype(dtype))
     return y + p["bias"].astype(dtype) if "bias" in p else y
+
+
+def normalise_qk(
+    params: Params, q: jax.Array, k: jax.Array, epsilon: float
+) -> tuple[jax.Array, jax.Array]:
+    """RMSNorm over each head's channels of (B, S, H, D) q and k, each with
+    its own scale, where the layer has them (``mha_init(qk_norm=True)``):
+    before the rotation, so the cache holds k normalised and rotated."""
+    if "q_norm" not in params:
+        return q, k
+    from transformer_tpu.ops.nn import norm_apply
+
+    return (
+        norm_apply(params["q_norm"], q, epsilon, "rmsnorm"),
+        norm_apply(params["k_norm"], k, epsilon, "rmsnorm"),
+    )
 
 
 def merge_heads(params: Params, out: jax.Array, x_q: jax.Array) -> jax.Array:
@@ -200,6 +221,7 @@ def mha_apply(
     flash_block_q: int = 128,
     flash_block_k: int = 128,
     rope: bool | dict = False,
+    qk_norm_epsilon: float = 1e-6,
 ) -> tuple[jax.Array, jax.Array | None, dict[str, Any] | None]:
     """Multi-head attention forward.
 
@@ -239,6 +261,7 @@ def mha_apply(
         when decoding, else ``arange(S_q)``. A dict gives ``apply_rope``'s
         keyword arguments (``ops.positional.kind_rope``: base, rotated share
         of the head, YaRN); ``True`` is the plain rotation at base 10,000.
+      qk_norm_epsilon: of ``normalise_qk``, for a layer that has its scales.
 
     Returns ``(out, weights|None, cache|None)``.
     """
@@ -257,6 +280,7 @@ def mha_apply(
     else:
         k = _project(params["key"], x_kv, dtype)
         v = _project(params["value"], x_kv, dtype)
+    q, k = normalise_qk(params, q, k, qk_norm_epsilon)
 
     if rope:
         from transformer_tpu.ops.positional import apply_rope
@@ -452,7 +476,10 @@ def kv_buffer_keys(cache: dict[str, Any]) -> tuple[str, ...]:
     ``k_scale``/``v_scale`` rows for int8-quantized ones. The ONE listing of
     the layout's buffer names — ``_store_kv``, ``slice_kv_blocks``, and
     ``insert_kv_blocks`` all iterate it, so a future layout (new buffer key)
-    cannot desynchronize the write, export, and restore paths."""
+    cannot desynchronize the write, export, and restore paths. A
+    short-convolution layer's entry (``conv_state``) holds no such rows."""
+    if "conv_state" in cache:
+        return ()
     if "k_scale" in cache:
         return ("k", "k_scale", "v", "v_scale")
     return ("k", "v")

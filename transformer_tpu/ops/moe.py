@@ -86,6 +86,7 @@ def moe_init(
     shared_dff: int = 0,
     router_scale: float = 1.0,
     out_scale: float = 1.0,
+    select_bias: bool = False,
 ) -> Params:
     """Router plus the experts' FFNs stacked on a leading E axis. Per-expert
     fan-in/fan-out matches ``ffn_init`` so a 1-expert MoE is
@@ -99,7 +100,9 @@ def moe_init(
     the shared expert every token takes, a plain gated FFN without biases.
     ``router_scale`` and ``out_scale`` multiply the Glorot draw of the router's
     kernel and of the routed experts' out kernels (``ModelConfig``'s
-    ``moe_*_init_scale``: seeded weights that behave like a checkpoint's)."""
+    ``moe_*_init_scale``: seeded weights that behave like a checkpoint's).
+    ``select_bias`` gives the router the float32 bias of ``_route``'s sigmoid
+    form (zeros: a checkpoint's is what balancing left there)."""
     from transformer_tpu.ops.ffn import is_gated
 
     k_router, k_in, k_out = jax.random.split(key, 3)
@@ -117,6 +120,8 @@ def moe_init(
         "in": {"kernel": stacked(k_in, d_model, dff)},
         "out": {"kernel": stacked(k_out, dff, d_model, out_scale)},
     }
+    if select_bias:
+        params["router"]["bias"] = jnp.zeros((num_experts,), jnp.float32)
     if not is_gated(activation):
         params["in"]["bias"] = jnp.zeros((E, dff), param_dtype)
         params["out"]["bias"] = jnp.zeros((E, d_model), param_dtype)
@@ -139,14 +144,30 @@ def expert_capacity(
     return max(1, min(seq_len, math.ceil(even * capacity_factor)))
 
 
-def _route(params: Params, x: jax.Array, k: int):
+def _route(
+    params: Params, x: jax.Array, k: int, score: str = "softmax", renorm_epsilon: float = 1e-6
+):
     """The router both dispatches share, in fp32 from the start: softmax over
     all experts, the ``k`` largest, renormalised to sum 1 (GShard's top-2
     convention; ``norm_topk_prob``). Returns (probs (..., E), gates (..., k),
-    expert ids (..., k))."""
+    expert ids (..., k)).
+
+    ``score="sigmoid"`` (the dropless layer alone): each expert's score is
+    the sigmoid of its logit; the ``k`` experts with the largest ``score +
+    bias`` are chosen (the router's ``bias`` leaf, where it has one, takes
+    part in the choice and in nothing else), and the gates are the chosen
+    SCORES over their sum plus ``renorm_epsilon``."""
     logits = jnp.einsum(
         "...m,me->...e", x.astype(jnp.float32), params["router"]["kernel"].astype(jnp.float32)
     )
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        bias = params["router"].get("bias")
+        choice = scores if bias is None else scores + bias.astype(jnp.float32)
+        _, indices = jax.lax.top_k(choice, k)
+        gates = jnp.take_along_axis(scores, indices, axis=-1)
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + renorm_epsilon)
+        return scores, gates, indices
     probs = jax.nn.softmax(logits, axis=-1)
     gates, indices = jax.lax.top_k(probs, k)
     gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
@@ -258,16 +279,19 @@ def moe_apply_dropless(
     top_k: int,
     expert_offset: int = 0,
     routed_scale: float = 1.0,
+    score: str = "softmax",
+    renorm_epsilon: float = 1e-6,
     activation: str = "swiglu",
     token_mask: jax.Array | None = None,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """(..., M) -> ((..., M), int32 [picks that landed here, experts hit]).
+    """(..., M) -> ((..., M), int32 [picks that landed here, experts hit,
+    rows of the most-loaded expert]).
 
     A routed layer of gated experts that drops no token, told which experts
     it holds: the stacks in ``params`` are experts ``expert_offset ..
     expert_offset + E_held - 1`` of the router's ``num_experts``. Every token
-    is routed over ALL experts (``_route``); its picks that fall on an expert
+    is routed over ALL experts (``_route``, in the form ``score`` names); its picks that fall on an expert
     held here are grouped by expert (a stable sort by expert id, group sizes,
     each group padded to whole row tiles) and go through one grouped gated
     FFN (``kernels/moe_ffn.py``) that reads only the experts hit; picks that
@@ -285,7 +309,7 @@ def moe_apply_dropless(
     T = xt.shape[0]
     k = min(top_k, num_experts)
     held = params["in"]["kernel"].shape[0]
-    _, gates, indices = _route(params, xt, k)  # (T, k)
+    _, gates, indices = _route(params, xt, k, score, renorm_epsilon)  # (T, k)
     local = indices - expert_offset
     here = (local >= 0) & (local < held)
     if token_mask is not None:
@@ -328,5 +352,6 @@ def moe_apply_dropless(
     y = jnp.einsum("tk,tkm->tm", weights, rows).astype(xt.dtype)
     if "shared" in params:
         y = y + ffn_apply(params["shared"], xt, activation)
-    counts = jnp.stack([jnp.sum(here), jnp.sum(sizes > 0)]).astype(jnp.int32)
+    # A grouped product's time follows its longest group: the third count.
+    counts = jnp.stack([jnp.sum(here), jnp.sum(sizes > 0), jnp.max(sizes)]).astype(jnp.int32)
     return y.reshape(*lead, m), counts

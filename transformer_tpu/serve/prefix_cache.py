@@ -231,6 +231,12 @@ class PrefixCache:
                 "absolute position, which a rolling buffer evicts on wrap — "
                 "the same policy that refuses speculative rollback"
             )
+        if cfg.state_layers:
+            raise ValueError(
+                "prefix cache cannot serve a model with a stateful layer: a "
+                "cached prefix holds KV rows and no snapshot of the "
+                "short-convolution state at its end"
+            )
         if block_tokens < 1:
             raise ValueError(f"block_tokens must be >= 1, got {block_tokens}")
         if budget_mb < 1:

@@ -140,6 +140,7 @@ from transformer_tpu.ops.attention import (
     kv_buffer_keys,
     slice_kv_blocks,
 )
+from transformer_tpu.ops.short_conv import init_conv_state, state_buffer_keys
 from transformer_tpu.serve.resilience import (
     BREAKER_STATE_VALUE,
     CircuitBreaker,
@@ -312,9 +313,32 @@ def _slot_read_blocks(pool_caches, slot, start, n: int):
 # call, like the pick positions), so rollback is pure table truncation.
 
 
+def init_paged_pools(
+    cfg: ModelConfig, num_slots: int, pool_blocks: int, block_tokens: int
+) -> list:
+    """The paged pool's per-layer device state, two kinds in one list: ONE
+    block pool of KV rows for each attention layer (every slot reaches it
+    through the block table), and for each short-convolution layer a fixed
+    state row a slot, ``{"conv_state": (num_slots, L - 1, d_model)}``, and no
+    blocks at all: the pool's bytes a token are the attention layers'."""
+    from transformer_tpu.ops.attention import init_block_pool
+
+    def one(i):
+        taps = cfg.layer_kind(i).conv_kernel
+        if taps:
+            return {"conv_state": init_conv_state(num_slots, cfg.d_model, taps, cfg.compute_dtype)}
+        return init_block_pool(
+            pool_blocks, block_tokens, cfg.kv_heads, cfg.head_dim,
+            cfg.compute_dtype, quantize=cfg.kv_cache_int8,
+        )
+
+    return [one(i) for i in range(cfg.num_layers)]
+
+
 def _paged_views(pool_caches, table, index, buf_len: int):
     """Per-layer stacked slot views, structurally identical to the dense
-    SlotPool pytree: leaves (N, 1, buf_len, H, D) + per-slot ``index``."""
+    SlotPool pytree: leaves (N, 1, buf_len, H, D) + per-slot ``index`` (a
+    short-convolution layer's: its (N, 1, L - 1, d_model) state)."""
     from transformer_tpu.kernels.kv_pool import gather_block_views
 
     views = []
@@ -323,6 +347,8 @@ def _paged_views(pool_caches, table, index, buf_len: int):
             key: gather_block_views(layer[key], table, buf_len)[:, None]
             for key in kv_buffer_keys(layer)
         }
+        for key in state_buffer_keys(layer):
+            view[key] = layer[key][:, None]
         view["index"] = index
         views.append(view)
     return views
@@ -334,7 +360,8 @@ def _paged_scatter(pool_caches, new_views, table, index, s_q: int,
     ``[index, index + s_q)`` of its view — back into the pool buffers, in
     storage layout (the view's buffers were written by the same _store_kv
     the dense path uses, so the pool rows are bit-identical to a dense
-    cache's). Free slots (index 0, all-sink tables) land in the sink."""
+    cache's). Free slots (index 0, all-sink tables) land in the sink, and
+    keep the short-convolution state they held."""
     from transformer_tpu.kernels.kv_pool import block_row_ids, scatter_rows
 
     n = table.shape[0]
@@ -348,6 +375,10 @@ def _paged_scatter(pool_caches, new_views, table, index, s_q: int,
             )(view[key], index)  # (N, s_q, ...)
             new[key] = scatter_rows(
                 layer[key], rids, rows.reshape(n * s_q, *rows.shape[2:])
+            )
+        for key in state_buffer_keys(layer):
+            new[key] = jnp.where(
+                (index > 0)[:, None, None], view[key][:, 0], layer[key]
             )
         out.append(new)
     return out
@@ -460,7 +491,10 @@ def _slot_prefill_paged(
     chunked prefill, then scatter the written suffix rows ``[start, start
     + n)`` into the slot's blocks. ``slot`` and ``start`` stay traced (no
     recompile per slot or hit length); NOT donated, for the same
-    admission-error isolation as the dense prefill."""
+    admission-error isolation as the dense prefill. A short-convolution
+    layer's view is the slot's state row: read as the chunk's left edge where
+    ``start > 0`` (zeros at 0, whatever the slot held), written back as the
+    chunk's last gated inputs."""
     from transformer_tpu.kernels.kv_pool import gather_block_views, scatter_rows
 
     row = jax.lax.dynamic_slice_in_dim(table, slot, 1, axis=0)  # (1, nmax)
@@ -468,6 +502,10 @@ def _slot_prefill_paged(
         {
             key: gather_block_views(layer[key], row, buf_len)
             for key in kv_buffer_keys(layer)
+        }
+        | {
+            key: jax.lax.dynamic_slice_in_dim(layer[key], slot, 1, axis=0)
+            for key in state_buffer_keys(layer)
         }
         for layer in pool_caches
     ]
@@ -486,6 +524,10 @@ def _slot_prefill_paged(
         for key in kv_buffer_keys(layer):
             rows = jax.lax.dynamic_slice_in_dim(c[key], start, n, axis=1)[0]
             new[key] = scatter_rows(layer[key], rids, rows)
+        for key in state_buffer_keys(layer):
+            new[key] = jax.lax.dynamic_update_slice_in_dim(
+                layer[key], c[key], slot, axis=0
+            )
         new_pool.append(new)
     return logits, new_pool
 
@@ -561,19 +603,12 @@ def abstract_paged_pool(
     pool_blocks: int, block_tokens: int,
 ):
     """The paged pool's device layout as ShapeDtypeStructs — per-layer
-    block-pool buffers plus the (num_slots, slot_blocks) table and (N,)
-    index — the ONE statement the abstract analyses (contracts, costs)
-    share with what ``SlotPool(kv_layout="paged")`` actually allocates."""
-    from transformer_tpu.ops.attention import init_block_pool
-
+    block-pool buffers (``init_paged_pools``) plus the (num_slots,
+    slot_blocks) table and (N,) index — the ONE statement the abstract
+    analyses (contracts, costs) share with what
+    ``SlotPool(kv_layout="paged")`` actually allocates."""
     pool = jax.eval_shape(
-        lambda: [
-            init_block_pool(
-                pool_blocks, block_tokens, cfg.kv_heads, cfg.head_dim,
-                cfg.compute_dtype, quantize=cfg.kv_cache_int8,
-            )
-            for _ in range(cfg.num_layers)
-        ]
+        lambda: init_paged_pools(cfg, num_slots, pool_blocks, block_tokens)
     )
     slot_blocks = -(-max_total // block_tokens)
     table = jax.ShapeDtypeStruct((num_slots, slot_blocks), np.int32)
@@ -724,8 +759,9 @@ class _Active:
 class SlotPool:
     """A fixed pool of per-slot decoder KV storage: stacked dense caches
     (``kv_layout="dense"``, the historical layout) or ONE block pool per
-    layer shared by every slot through block tables (``"paged"``,
-    kernels/kv_pool.py — resident KV proportional to used tokens)."""
+    attention layer shared by every slot through block tables (``"paged"``,
+    kernels/kv_pool.py — resident KV proportional to used tokens) beside a
+    fixed state row a slot for each short-convolution layer."""
 
     def __init__(
         self,
@@ -760,7 +796,6 @@ class SlotPool:
                     "wrap); serve this config with kv_layout='dense'"
                 )
             from transformer_tpu.kernels.kv_pool import KVPool
-            from transformer_tpu.ops.attention import init_block_pool
 
             if kv_block < 1:
                 raise ValueError(f"kv_block must be >= 1, got {kv_block}")
@@ -778,13 +813,7 @@ class SlotPool:
             self.alloc = KVPool(
                 num_blocks, kv_block, num_slots, self.slot_blocks
             )
-            self.caches = [
-                init_block_pool(
-                    num_blocks, kv_block, cfg.kv_heads, cfg.head_dim,
-                    cfg.compute_dtype, quantize=cfg.kv_cache_int8,
-                )
-                for _ in range(cfg.num_layers)
-            ]
+            self.caches = init_paged_pools(cfg, num_slots, num_blocks, kv_block)
             return
         per_slot = [
             init_decoder_caches(cfg, 1, max_total) for _ in range(num_slots)
@@ -860,6 +889,30 @@ class ContinuousScheduler:
                 "(attention_window evicts absolute-position rows on wrap); "
                 "serve this config without --prefix_cache_mb"
             )
+        if cfg.state_layers:
+            # A layer whose state is not KV rows (a short convolution's last
+            # gated inputs) has one state a slot, overwritten by every token:
+            # nothing below can bring an earlier one back.
+            if speculate_k:
+                raise ValueError(
+                    "speculative decoding cannot serve a model with a stateful "
+                    "layer: a rejected draft's tokens have already rolled the "
+                    "short-convolution state, and it cannot be rolled back; "
+                    "serve this config with speculate_k=0"
+                )
+            if prefix_cache is not None:
+                raise ValueError(
+                    "prefix cache cannot serve a model with a stateful layer: a "
+                    "cached prefix holds KV rows and no snapshot of the "
+                    "short-convolution state at its end; serve this config "
+                    "without --prefix_cache_mb"
+                )
+            if mesh is not None:
+                raise ValueError(
+                    "a sharded replica (--mesh) places the pool by its KV "
+                    "layout, and a model with a stateful layer has a state row "
+                    "a slot beside it; serve this config on one device"
+                )
         self.params, self.cfg, self.tok = params, cfg, tokenizer
         # ---- live-weights control plane (serve/upgrade.py) ----------------
         # The TWO-VERSION param slot: `params` serves; a staged
@@ -958,8 +1011,10 @@ class ContinuousScheduler:
             check_paged_flash_config(cfg)
         self.decode_kernel = decode_kernel
         # ---- counts a model of several layer kinds adds to a step ---------
-        # A windowed kind: the positions its layers attend beside the full
-        # layers' (host arithmetic over the step's positions). A dropless
+        # The positions its full layers attend and, with a windowed kind,
+        # those its window layers do (host arithmetic over the step's
+        # positions); the layers whose state is no KV rows and the bytes a
+        # slot holds for them (constants, beside the expert counts). A dropless
         # expert model on the fused step: picks held here and experts hit,
         # accumulated on the device in the pool pytree (the pool programs
         # return logits and pools, nothing else) and fetched every
@@ -967,14 +1022,20 @@ class ContinuousScheduler:
         self._band = next(
             (k.window for k in cfg.attention_kinds if k.window), 0
         )
+        self._state_layers = len(cfg.state_layers)
+        self._state_bytes = sum(
+            (cfg.layer_kind(i).conv_kernel - 1) * cfg.d_model
+            * cfg.compute_dtype.itemsize
+            for i in cfg.state_layers
+        )
         self._moe_layer = None
         if decode_kernel == "paged_flash" and cfg.moe_dispatch == "dropless":
             self._moe_layer = next(
                 (i for i in range(cfg.num_layers) if layer_uses_moe(cfg, i)), None
             )
         if self._moe_layer is not None:
-            self.pool.caches[self._moe_layer][MOE_COUNTS] = jnp.zeros((3,), jnp.int32)
-        self._moe_read = np.zeros((3,), np.int64)  # the device's totals at the last fetch
+            self.pool.caches[self._moe_layer][MOE_COUNTS] = jnp.zeros((4,), jnp.int32)
+        self._moe_read = np.zeros((4,), np.int64)  # the device's totals at the last fetch
         self._moe_unread = [0, 0]  # steps and stepped slots since then
         self._kernel_interpret = jax.default_backend() != "tpu"
         # ---- program dispatch: module-level jits or sharded twins ---------
@@ -1205,6 +1266,10 @@ class ContinuousScheduler:
                         "serve_moe_experts_hit_total",
                         "experts that received a token, summed over expert "
                         "layers and decode steps")
+                    self._m_moe_max_load = reg.counter(
+                        "serve_moe_max_load_total",
+                        "rows the most-loaded held expert received, summed "
+                        "over expert layers and decode steps")
                 if prefix_cache is not None:
                     self._m_alias_tokens = reg.counter(
                         "serve_prefix_alias_tokens_total",
@@ -1212,6 +1277,15 @@ class ContinuousScheduler:
                         "aliasing (zero host<->device copies) — a subset "
                         "of serve_prefix_hit_tokens_total; the remainder "
                         "was restored through a host block copy")
+            if self._state_layers:
+                reg.gauge(
+                    "serve_state_layers",
+                    "layers whose per-slot state is not KV rows (short "
+                    "convolutions)").set(self._state_layers)
+                reg.gauge(
+                    "serve_state_bytes_per_slot",
+                    "bytes a slot holds for those layers' state",
+                ).set(self._state_bytes)
             self._m_deadline = reg.counter(
                 "serve_deadline_expired_total",
                 "requests answered with a deadline error")
@@ -1339,6 +1413,12 @@ class ContinuousScheduler:
         pairs = self._paged_alloc(
             lambda: self.pool.alloc.make_writable(slot, start, end)
         )
+        if pairs and self._state_layers:
+            raise ValueError(
+                "a copy-on-write fork cannot serve a model with a stateful "
+                "layer: the blocks a fork shares hold KV rows, and the "
+                "short-convolution state at the fork's position is not kept"
+            )
         if pairs:
             src = jnp.asarray(_pow2_pad([s for s, _ in pairs]), jnp.int32)
             dst = jnp.asarray(_pow2_pad([d for _, d in pairs]), jnp.int32)
@@ -2341,14 +2421,16 @@ class ContinuousScheduler:
                 groups.setdefault(
                     (st.sample, st.top_k, st.top_p), []
                 ).append(slot)
-            if self._band:
+            if self.cfg.layer_pattern:
                 # Positions this step's attention reads: all of a slot's on a
                 # full layer, the band's on a window layer.
                 lengths = positions[list(self._active)].astype(np.int64) + 1
-                step_span.set(
-                    attn_pos_full=int(lengths.sum()),
-                    attn_pos_band=int(np.minimum(lengths, self._band).sum()),
-                )
+                attended = {"attn_pos_full": int(lengths.sum())}
+                if self._band:
+                    attended["attn_pos_band"] = int(
+                        np.minimum(lengths, self._band).sum()
+                    )
+                step_span.set(**attended)
             # Only what the pool step reads is copied before it is enqueued.
             d_toks, d_positions = jnp.asarray(toks), jnp.asarray(positions)
             table = self.pool.alloc.table_device() if self.paged else None
@@ -2424,16 +2506,23 @@ class ContinuousScheduler:
         # The device's int32 totals are never reset and wrap (after hours of
         # serving): what was added since the last fetch is the difference
         # modulo 2**32, far above what 32 steps can add.
-        assign, hit, steps = ((total - self._moe_read) & 0xFFFFFFFF).tolist()
+        assign, hit, max_load, steps = (
+            (total - self._moe_read) & 0xFFFFFFFF
+        ).tolist()
         self._moe_read = total
         step_span.set(
-            moe_assign=assign, moe_hit=hit, moe_steps=steps,
-            moe_tokens=self._moe_unread[1],
+            moe_assign=assign, moe_hit=hit, moe_max_load=max_load,
+            moe_steps=steps, moe_tokens=self._moe_unread[1],
         )
+        if self._state_layers:
+            step_span.set(
+                state_layers=self._state_layers, state_bytes=self._state_bytes
+            )
         self._moe_unread = [0, 0]
         if self._tel is not None:
             self._m_moe_assign.inc(assign)
             self._m_moe_hit.inc(hit)
+            self._m_moe_max_load.inc(max_load)
 
     def _step_verify(self, step_span) -> None:
         """One speculative verify step: every occupied slot feeds its
