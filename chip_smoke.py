@@ -259,11 +259,15 @@ def check_decode_programs(sched, on_tpu: bool) -> None:
 def check_kernel_parity() -> None:
     """``paged_flash_attention`` against the XLA gather oracle at the base
     head shape, decode and verify rows — the kernel tests' own comparison,
-    run by the chip's compiler instead of the interpreter."""
+    run by the chip's compiler instead of the interpreter. Both ways the
+    pool may hold these heads: by heads (the tiled route) and, as
+    ``init_block_pool`` lays 8 bf16 heads of 64 out, two a lane row (the
+    streamed route, the one the server below runs)."""
     import jax.numpy as jnp
     import numpy as np
 
     from transformer_tpu.kernels.flash_attention import paged_attention
+    from transformer_tpu.kernels.paged_flash import heads_per_lane_row
 
     rng = np.random.default_rng(0)
     n, h, d, block, blocks, nmax = 8, 8, 64, 16, 41, 5
@@ -274,9 +278,15 @@ def check_kernel_parity() -> None:
         table = jnp.asarray(rng.permutation(np.arange(1, blocks))[: n * nmax].reshape(n, nmax), jnp.int32)
         lengths = jnp.asarray(rng.integers(s_q, nmax * block, (n,)), jnp.int32)
         want = np.asarray(paged_attention(q, k, v, table, lengths, impl="xla"), np.float32)
-        got = np.asarray(paged_attention(q, k, v, table, lengths, impl="paged_flash"), np.float32)
-        np.testing.assert_allclose(got, want, rtol=KERNEL_TOL, atol=KERNEL_TOL)
-        say(f"paged_flash_attention vs XLA oracle, S_q={s_q}: max|diff| {np.abs(got - want).max():.4g} (tolerance {KERNEL_TOL})")
+        for per_row in sorted({1, heads_per_lane_row(h, d, k.dtype)}):
+            page = (blocks, block, h // per_row, d * per_row)
+            got = np.asarray(
+                paged_attention(q, k.reshape(page), v.reshape(page), table, lengths, impl="paged_flash"),
+                np.float32,
+            )
+            np.testing.assert_allclose(got, want, rtol=KERNEL_TOL, atol=KERNEL_TOL)
+            say(f"paged_flash_attention vs XLA oracle, S_q={s_q}, {per_row} head(s) a lane row: "
+                f"max|diff| {np.abs(got - want).max():.4g} (tolerance {KERNEL_TOL})")
 
 
 def serve_phase(export_dir: str, vocab: str, on_tpu: bool):

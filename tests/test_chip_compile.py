@@ -83,8 +83,13 @@ def test_flash_attention_fwd_and_grad(one_chip, seq, batch):
 
 
 # (id, slots, heads, KV heads, head size, block_tokens, table width, pool
-# blocks, S_q, int8). The first six are the base preset's width in its cache
-# variants (D = 64: every page of a compute block its own BlockSpec); ``cell``
+# blocks, S_q, int8), the pool laid out as ``init_block_pool`` lays it out.
+# The first six are the base preset's width in its cache variants: 8 bf16
+# heads of 64 are kept two a lane row and streamed (a table of 128 positions:
+# one short block); the int8 pool and 2 bf16 heads of 64 (half a packed
+# sublane) keep pages by heads, every page of a compute block its own
+# BlockSpec. ``lfm2`` is the LFM2 cell's shape, 32 heads over 8 KV heads of 64
+# as 4 lane rows under 128 slots, decode and verify rows; ``cell``
 # is the StarCoder2-3B cells' own shape, 24 heads over 2 KV heads of 128 under
 # 48 slots and a table of 128 pages of 16 (the pools left in HBM, live pages
 # copied by hand into blocks of 512 positions, a fold a width of live
@@ -100,6 +105,8 @@ _PAGED_CASES = [
     ("cell-bf16-4", 48, 24, 2, 128, 16, 128, 6145, 4, False),
     ("cell-int8-1", 48, 24, 2, 128, 32, 64, 3073, 1, True),
     ("wide-bf16-3", 8, 16, 8, 128, 16, 40, 330, 3, False),
+    ("lfm2-bf16-1", 128, 32, 8, 64, 16, 256, 9216, 1, False),
+    ("lfm2-bf16-2", 128, 32, 8, 64, 16, 256, 9216, 2, False),
 ]
 
 
@@ -121,15 +128,22 @@ def _assert_streamed_block(block_tokens, h_kv, d, nmax):
 def test_paged_flash_attention(
     one_chip, n, h, h_kv, d, block_tokens, nmax, num_blocks, s_q, quantized
 ):
-    from transformer_tpu.kernels.paged_flash import _streamable, paged_flash_attention
+    from transformer_tpu.kernels.paged_flash import (
+        _streamable,
+        heads_per_lane_row,
+        paged_flash_attention,
+    )
 
-    if not quantized and _streamable(h_kv, d, BF16):
-        _assert_streamed_block(block_tokens, h_kv, d, nmax)
+    per_row = heads_per_lane_row(h_kv, d, BF16, quantized)
+    page = (h_kv // per_row, d * per_row)
+    assert (per_row == 2) == (h_kv == 8 and d == 64 and not quantized)
+    if not quantized and _streamable(*page, BF16) and nmax * block_tokens >= 512:
+        _assert_streamed_block(block_tokens, *page, nmax)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = sds((num_blocks, block_tokens, h_kv, d), jnp.int8 if quantized else BF16)
+    pool = sds((num_blocks, block_tokens, *page), jnp.int8 if quantized else BF16)
     args = [sds((n, s_q, h, d), BF16), pool, pool, sds((n, nmax), jnp.int32),
             sds((n,), jnp.int32)]
     if quantized:
@@ -147,7 +161,7 @@ def test_paged_flash_attention(
     # shape: what the benchmark's kernel metrics find it by.
     call = re.search(r"%paged_flash_attention[\w.]* = [^\n]*custom-call\([^\n]*", text)
     assert call and text.count("tpu_custom_call") == 1, text[-2000:]
-    assert f"[{num_blocks},{block_tokens},{h_kv},{d}]" in call.group(0)
+    assert f"[{num_blocks},{block_tokens},{page[0]},{page[1]}]" in call.group(0)
 
 
 @pytest.mark.parametrize("heads,window,s_q", [(72, 512, 1), (48, 0, 1), (72, 512, 3), (48, 0, 3)],

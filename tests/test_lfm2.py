@@ -408,6 +408,125 @@ def test_step_spans_count_positions_the_most_loaded_expert_and_the_state(cfg, pa
     assert reg.gauge("serve_state_bytes_per_slot", "").value == 6 * 2 * 64 * 4
 
 
+# ------------------- heads that pack two a lane row, through the scheduler
+#
+# The cell's rehearsal model has 2 KV heads of 16, which never meet the rule
+# that lays the published 8 x 64 out as 4 x 128: these models' heads DO pack
+# (2 KV heads of 64 in float32: one lane row), so the CPU suite runs the pool
+# kept by lane rows, its writers and readers, and the streamed route over it.
+
+
+def _packing(model, **over) -> tuple[ModelConfig, dict]:
+    kinds = [{**k, "num_heads": 4} if k["name"] == "full_attention" else k for k in model["attention_kinds"]]
+    cfg = ModelConfig(**{**model, "num_layers": 4, "head_size": 64, "attention_kinds": kinds, **over})
+    return cfg, transformer_init(jax.random.PRNGKey(5), cfg)
+
+
+def _attention_only(model, **over):
+    """The attention mixer alone (speculation and the prefix cache refuse a
+    stateful layer): q/k normalisation, GQA, the rotary base, and the two
+    leading dense SwiGLU layers (so no expert layer is left)."""
+    return _packing(model, **{"num_layers": 2, "layer_pattern": ["full_attention"], **over})
+
+
+def _answers(cfg, params, requests, **deployment):
+    sched = _scheduler(cfg, params, num_slots=2, **deployment)
+    out = sched.run([dict(r) for r in requests])
+    assert all(r.get("continuation") for r in out), out
+    return [r["continuation"] for r in out], sched
+
+
+def _requests(lengths, **extra):
+    rng = np.random.default_rng(11)
+    return [{"prompt": " ".join(map(str, rng.integers(3, VOCAB, n - 1))), "max_new": 5, **extra} for n in lengths]
+
+
+def test_heads_that_pack_are_kept_by_lane_rows_and_answer_alike_on_every_route(model):
+    """Conv, conv, attention, conv with 2 KV heads of 64: the attention
+    layer's pool is (blocks, 16, 1, 128), the fused step streams it (the
+    gauges say so), and five requests over two slots (a slot freed and
+    admitted again, prompts prefilled to 16 that walk their tails) answer
+    byte for byte as the gather step and the dense layout do."""
+    from transformer_tpu.obs.telemetry import Telemetry
+
+    cfg, params = _packing(model)
+    requests = _requests((20, 17, 30, 19, 23))
+    tel = Telemetry(interval=1e12)
+    got, sched = _answers(cfg, params, requests, telemetry=tel)
+    pools = [c for c in sched.pool.caches if "k" in c]
+    assert [c["k"].shape[2:] for c in pools] == [(1, 128)] and cfg.kv_heads * cfg.head_dim == 128
+    assert tel.registry.gauge("serve_attn_layers_streamed", "").value == 1
+    assert tel.registry.gauge("serve_attn_layers_tiled", "").value == 0
+    assert got == _answers(cfg, params, requests, decode_kernel="xla")[0]
+    assert got == _answers(cfg, params, requests, kv_layout="dense", decode_kernel="xla")[0]
+
+
+@pytest.mark.parametrize("speculate_k", [0, 1], ids=["plain", "speculate_1"])
+def test_heads_that_pack_through_speculation_and_the_prefix_cache(model, speculate_k):
+    """The attention-only twin, greedy and seeded-sampled, plain and with
+    ``speculate_k=1`` (verify rows, S_q = 2, on the streamed route), a prefix
+    cache attached (the second wave's hits alias device blocks): the fused
+    step, the gather step and the dense layout give the same bytes."""
+    from transformer_tpu.serve.prefix_cache import PrefixCache
+
+    cfg, params = _attention_only(model)
+    waves = [_requests((20, 34)), _requests((20, 34, 27), temperature=0.8, seed=4)]
+
+    def serve(**deployment):
+        sched = _scheduler(cfg, params, num_slots=2, speculate_k=speculate_k,
+                           prefix_cache=PrefixCache(cfg, block_tokens=16, budget_mb=8), **deployment)
+        return [[r["continuation"] for r in sched.run([dict(r) for r in wave])] for wave in waves], sched
+
+    got, sched = serve()
+    assert sched.pool.caches[0]["k"].shape[2:] == (1, 128)
+    assert 0 < sched.stats["prefix_hit_tokens"] == sched.stats["prefix_alias_tokens"]
+    assert got == serve(decode_kernel="xla")[0]
+    assert got == serve(kv_layout="dense", decode_kernel="xla")[0]
+    sched.pool.alloc.check_consistency()
+
+
+def test_a_block_of_a_pool_kept_by_lane_rows_is_the_host_format_byte_for_byte(model):
+    """Spill-to-host reads a pool block by heads, (1, B, H, D), the dense
+    export's bytes; a host-tier hit writes it back into whatever rows the
+    pool keeps; the same request then answers as before."""
+    from transformer_tpu.serve.prefix_cache import PrefixCache
+
+    cfg, params = _attention_only(model)
+    request = _requests((40,))
+    ids = [1, *map(int, request[0]["prompt"].split())]
+
+    def serve(**deployment):
+        cache = PrefixCache(cfg, block_tokens=16, budget_mb=8)
+        sched = _scheduler(cfg, params, num_slots=2, prefix_cache=cache, **deployment)
+        return sched.run([dict(request[0])])[0]["continuation"], sched, cache
+
+    want, _, dense_cache = serve(kv_layout="dense", decode_kernel="xla")
+    got, sched, cache = serve()
+    assert got == want and sched.pool.caches[0]["k"].shape[2:] == (1, 128)
+    assert cache.release_device_blocks(1 << 30) == 2  # both whole blocks of the prompt go to the host trie
+    hit, dense_hit = cache.match(ids), dense_cache.match(ids)
+    assert hit.tokens == dense_hit.tokens == 32
+    for ours, theirs in zip(hit.stacked(64), dense_hit.stacked(64)):
+        for key in ("k", "v"):
+            assert ours[key].shape == (1, 32, 2, 64)
+            np.testing.assert_array_equal(ours[key], theirs[key])
+    hit.release(), dense_hit.release()
+    before = sched.stats["prefix_alias_tokens"]
+    assert sched.run([dict(request[0])])[0]["continuation"] == want  # restored through _pool_write_blocks
+    assert sched.stats["prefix_alias_tokens"] == before and sched.stats["prefix_hit_tokens"] >= 32
+
+
+def test_an_int8_pool_keeps_its_heads_and_the_tiled_route(model):
+    from transformer_tpu.obs.telemetry import Telemetry
+
+    cfg, params = _attention_only(model, num_layers=1, kv_cache_int8=True)
+    tel = Telemetry(interval=1e12)
+    sched = _scheduler(cfg, params, telemetry=tel)
+    assert sched.pool.caches[0]["k"].shape[2:] == (2, 64) and sched.pool.caches[0]["k_scale"].shape[2:] == (2, 1)
+    assert tel.registry.gauge("serve_attn_layers_streamed", "").value == 0
+    assert tel.registry.gauge("serve_attn_layers_tiled", "").value == 1
+
+
 # ------------------------- the other served configurations' programs stand
 
 

@@ -77,6 +77,8 @@ WAVES = [
 _TOL = {
     "fp32": 5e-6, "bf16": 3e-2, "int8": 3e-2, "gqa": 3e-2,
     "serve_bf16": 3e-2, "serve_int8": 3e-2, "lane_fp32": 5e-6, "lane_int8": 3e-2,
+    "pair_bf16": 3e-2, "pair_fp32": 5e-6, "pair2_fp32": 5e-6, "pair4_bf16": 3e-2,
+    "lone_bf16": 3e-2, "odd_fp32": 5e-6, "half_bf16": 3e-2,
 }
 
 # The first four are the toy widths (D = 8): their pages do not fill a lane
@@ -84,7 +86,12 @@ _TOL = {
 # The others have pages of whole tiles (D = 128), which the kernel copies by
 # hand from the pool left in HBM, except ``serve_int8``: two int8 heads are
 # half a packed sublane. ``serve_*`` are the serving cells' ratios at toy
-# size: 2 KV heads of 128 under 12 query heads each, pages of 16.
+# size: 2 KV heads of 128 under 12 query heads each, pages of 16. ``pair*``
+# are heads of 64 that fill whole lane rows, which the pool keeps two a row
+# (``heads_per_lane_row``) and the kernel streams: the LFM2 cell's 8 under 32
+# query heads in both dtypes, and the fewest heads that pair in each. The last
+# three are heads of 64 that do NOT pair (one head, three, and two bf16 heads:
+# one lane row of bf16 is half a packed sublane): pages by heads, tiled.
 _KERNEL_VARIANTS = {
     "fp32": dict(dtype=jnp.float32, h_q=2, h_kv=2, quantized=False),
     "bf16": dict(dtype=jnp.bfloat16, h_q=2, h_kv=2, quantized=False),
@@ -97,7 +104,28 @@ _KERNEL_VARIANTS = {
     "lane_fp32": dict(dtype=jnp.float32, h_q=4, h_kv=2, quantized=False, d=128),
     "lane_int8": dict(dtype=jnp.bfloat16, h_q=8, h_kv=4, quantized=True,
                       d=128, block_tokens=32),
+    "pair_bf16": dict(dtype=jnp.bfloat16, h_q=32, h_kv=8, quantized=False,
+                      d=64, block_tokens=16),
+    "pair_fp32": dict(dtype=jnp.float32, h_q=32, h_kv=8, quantized=False, d=64),
+    "pair2_fp32": dict(dtype=jnp.float32, h_q=4, h_kv=2, quantized=False, d=64),
+    "pair4_bf16": dict(dtype=jnp.bfloat16, h_q=4, h_kv=4, quantized=False,
+                       d=64, block_tokens=16),
+    "lone_bf16": dict(dtype=jnp.bfloat16, h_q=4, h_kv=1, quantized=False, d=64),
+    "odd_fp32": dict(dtype=jnp.float32, h_q=6, h_kv=3, quantized=False, d=64),
+    "half_bf16": dict(dtype=jnp.bfloat16, h_q=2, h_kv=2, quantized=False, d=64),
 }
+_PAIRED = ("pair_bf16", "pair_fp32", "pair2_fp32", "pair4_bf16")
+
+
+def _page(variant: str) -> tuple[int, int]:
+    """A token's page as ``init_block_pool`` lays it out for this variant:
+    (H_kv, D), or whole lane rows of ``128 // D`` heads each."""
+    from transformer_tpu.kernels.paged_flash import heads_per_lane_row
+
+    spec = _KERNEL_VARIANTS[variant]
+    d = spec.get("d", 8)
+    per_row = heads_per_lane_row(spec["h_kv"], d, spec["dtype"], spec["quantized"])
+    return spec["h_kv"] // per_row, d * per_row
 
 
 def _block_positions(variant: str, block_tokens: int) -> tuple[int, int]:
@@ -108,11 +136,11 @@ def _block_positions(variant: str, block_tokens: int) -> tuple[int, int]:
     from transformer_tpu.kernels.paged_flash import _pages_per_block, _streamable
 
     spec = _KERNEL_VARIANTS[variant]
-    d = spec.get("d", 8)
+    rows, lanes = _page(variant)
     itemsize = 1 if spec["quantized"] else jnp.dtype(spec["dtype"]).itemsize
-    streamed = not spec["quantized"] and _streamable(spec["h_kv"], d, spec["dtype"])
+    streamed = not spec["quantized"] and _streamable(rows, lanes, spec["dtype"])
     pages, chunk = _pages_per_block(
-        block_tokens, spec["h_kv"], d, itemsize, spec["quantized"], 1 << 20, streamed
+        block_tokens, rows, lanes, itemsize, spec["quantized"], 1 << 20, streamed
     )
     return block_tokens * pages, block_tokens * chunk
 
@@ -134,7 +162,11 @@ def _pool_case(
     (d) at the table's full width, (e) on the last position of the first
     sub-chunk and (f) on the first of the next (with ``s_q`` > 1 the query
     rows then straddle that edge), beside (g) a free slot: length ``s_q``
-    under an all-sink table."""
+    under an all-sink table.
+
+    The pool comes out in the layout ``init_block_pool`` gives these shapes
+    (``_page``): drawn by heads, then the same bytes as whole lane rows where
+    the heads pair."""
     spec = _KERNEL_VARIANTS[variant]
     block_tokens = block_tokens or spec.get("block_tokens", 8)
     rng = np.random.default_rng(seed)
@@ -167,6 +199,7 @@ def _pool_case(
         lengths = index + s_q
     kf = rng.standard_normal((blocks, block_tokens, spec["h_kv"], d))
     vf = rng.standard_normal((blocks, block_tokens, spec["h_kv"], d))
+    kf, vf = (x.reshape(blocks, block_tokens, *_page(variant)) for x in (kf, vf))
     q = jnp.asarray(
         rng.standard_normal((n, s_q, spec["h_q"], d)), spec["dtype"]
     )
@@ -251,9 +284,11 @@ def test_kernel_rejects_untileable_block_tokens():
 @pytest.mark.parametrize(
     "variant,wide,window",
     [("bf16", False, 0), ("bf16", True, 0), ("serve_bf16", True, 0),
-     ("bf16", True, 24), ("serve_bf16", True, 200)],
+     ("bf16", True, 24), ("serve_bf16", True, 200),
+     ("pair_bf16", True, 0), ("pair_bf16", True, 200)],
     ids=["last_entry", "every_dead_entry", "every_dead_entry_streamed",
-         "before_the_band", "before_the_band_streamed"],
+         "before_the_band", "before_the_band_streamed",
+         "every_dead_entry_paired", "before_the_band_paired"],
 )
 def test_kernel_skips_sink_blocks(variant, wide, window):
     """Out-of-length table entries are never read: rewriting them to
@@ -294,6 +329,81 @@ def test_kernel_skips_sink_blocks(variant, wide, window):
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want, np.float32),
             rtol=_TOL[variant], atol=_TOL[variant],
+        )
+
+
+@pytest.mark.parametrize(
+    "h_kv,d,dtype,quantized,per_row",
+    [(2, 128, "bfloat16", False, 1), (8, 128, "bfloat16", False, 1),
+     (8, 64, "bfloat16", False, 2), (8, 64, "float32", False, 2),
+     (8, 64, "bfloat16", True, 1), (2, 64, "float32", False, 2),
+     (2, 64, "bfloat16", False, 1), (1, 64, "bfloat16", False, 1),
+     (3, 64, "float32", False, 1), (8, 32, "bfloat16", False, 4),
+     (2, 8, "float32", False, 1), (4, 96, "float32", False, 1)],
+    ids=["starcoder2-3b", "laguna-s-2.1", "lfm2-8b-a1b", "lfm2-fp32", "lfm2-int8",
+         "two-fp32", "two-bf16-half-a-sublane", "one-head", "three-heads",
+         "four-a-row", "toy", "no-divisor"],
+)
+def test_heads_per_lane_row_rule(h_kv, d, dtype, quantized, per_row):
+    """The one rule that lays a pool out: heads narrower than a lane row are
+    kept ``128 // D`` a row exactly where they fill whole rows and such a page
+    streams. Of the three served configurations' shapes only LFM2's packs;
+    ``init_block_pool`` allocates what the rule says, the same bytes either
+    way, and the kernel's route follows from the pool it is handed."""
+    from transformer_tpu.kernels.paged_flash import heads_per_lane_row, streams
+    from transformer_tpu.ops.attention import init_block_pool
+
+    assert heads_per_lane_row(h_kv, d, jnp.dtype(dtype), quantized) == per_row
+    pool = jax.eval_shape(
+        lambda: init_block_pool(5, 16, h_kv, d, jnp.dtype(dtype), quantize=quantized)
+    )
+    assert pool["k"].shape == pool["v"].shape == (5, 16, h_kv // per_row, d * per_row)
+    if per_row > 1:
+        assert streams(pool["k"].shape, pool["k"].dtype, quantized) and pool["k"].shape[-1] == 128
+    if quantized:
+        assert pool["k_scale"].shape == (5, 16, h_kv, 1) and not streams(pool["k"].shape, pool["k"].dtype, True)
+
+
+@pytest.mark.parametrize("variant", sorted(_KERNEL_VARIANTS))
+def test_kernel_route_by_variant(variant):
+    """Which way each variant's pool is fetched, by the kernel's own rule
+    over the pool as laid out: the paired heads stream; heads of 64 that do
+    not pair keep pages by heads and the tiled route."""
+    from transformer_tpu.kernels.paged_flash import streams
+
+    _, k, _, _, _, kw = _pool_case(variant, 1)
+    spec = _KERNEL_VARIANTS[variant]
+    paired = variant in _PAIRED
+    assert (k.shape[-1] != spec.get("d", 8)) == paired
+    streamed = {"serve_bf16", "lane_fp32", *_PAIRED}
+    assert streams(k.shape, k.dtype, bool(kw)) == (variant in streamed)
+
+
+@pytest.mark.parametrize("variant", _PAIRED)
+def test_paired_pool_is_the_same_bytes(variant):
+    """A pool kept two heads a lane row is a reshape of the pool by heads:
+    the XLA oracle reads both to the same bits (so the parity matrix's oracle
+    on a paired pool IS the dense mathematics), and the kernel handed the
+    by-heads shape (the tiled route) agrees with the streamed one."""
+    q, k, v, table, lengths, _ = _pool_case(variant, 3)
+    spec = _KERNEL_VARIANTS[variant]
+    by_heads = [x.reshape(*x.shape[:2], spec["h_kv"], spec["d"]) for x in (k, v)]
+    want = paged_attention(q, *by_heads, table, lengths, impl="xla")
+    got = paged_attention(q, k, v, table, lengths, impl="xla")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    tiled = paged_attention(q, *by_heads, table, lengths, impl="paged_flash", interpret=True)
+    streamed = paged_attention(q, k, v, table, lengths, impl="paged_flash", interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(streamed, np.float32), np.asarray(tiled, np.float32),
+        rtol=_TOL[variant], atol=_TOL[variant],
+    )
+
+
+def test_kernel_rejects_a_pool_of_another_head_size():
+    q, k, v, table, lengths, _ = _pool_case("pair_fp32", 1)
+    with pytest.raises(ValueError, match="head_dim mismatch"):
+        paged_attention(
+            q[..., :48], k, v, table, lengths, impl="paged_flash", interpret=True
         )
 
 

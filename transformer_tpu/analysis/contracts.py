@@ -330,7 +330,8 @@ def check_paged_alias_parity(
     )
     dense = abstract_pool_caches(cfg, num_slots, max_total)
     views = jax.eval_shape(
-        lambda p, t, i: _paged_views(p, t, i, max_total), pool, table, index
+        lambda p, t, i: _paged_views(p, t, i, max_total, cfg.head_dim),
+        pool, table, index,
     )
     a, b = _tree_spec(views), _tree_spec(dense)
     assert a == b, (

@@ -800,7 +800,9 @@ def paged_attention(
         ``lengths[s] - S_q .. lengths[s] - 1`` (decode: S_q = 1 at the
         newest position, already written into the pool).
       k_pool, v_pool: (num_blocks, B, H_kv, D) pool buffers — bf16/fp32
-        values, or int8 codes paired with ``k_scale``/``v_scale``.
+        values, or int8 codes paired with ``k_scale``/``v_scale`` — or the
+        same bytes with narrow heads kept ``128 // D`` a lane row
+        (``ops.attention.init_block_pool``), on every impl.
       table: (N, nmax) int32 block table (``kernels/kv_pool.KVPool``).
       lengths: (N,) int32 valid KV length per sequence — positions
         ``>= lengths[s]`` (stale rows, sink gathers) are masked out.
@@ -840,8 +842,9 @@ def paged_attention(
         raise ValueError(f"paged_attention impl={impl!r} has no window band")
     from transformer_tpu.kernels.kv_pool import gather_block_views
 
-    k = gather_block_views(k_pool, table, width=width)  # (N, L, H_kv, D)
-    v = gather_block_views(v_pool, table, width=width)
+    d = q.shape[-1]  # by heads, whatever rows the pool keeps them in
+    k = gather_block_views(k_pool, table, width=width, head_dim=d)  # (N, L, H_kv, D)
+    v = gather_block_views(v_pool, table, width=width, head_dim=d)
     if k_scale is not None:
         k = k.astype(q.dtype) * gather_block_views(
             k_scale, table, width=width

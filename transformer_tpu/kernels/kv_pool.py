@@ -335,14 +335,30 @@ class KVPool:
 # device-side pure helpers (used inside jitted programs)
 
 
-def gather_block_views(buf, table, width: int | None = None):
+def heads_view(rows, head_dim: int | None):
+    """A token's stored rows (..., R, C) as its heads (..., H_kv, D). A pool
+    whose heads are narrower than a lane row may keep ``128 // D`` of them a
+    row (``ops.attention.init_block_pool``): row-major that is the same bytes
+    in the same order, so the heads are a reshape away. Every other buffer
+    (``C == D``, an int8 pool's size-1 scales, ``head_dim`` not given) is
+    returned as it is."""
+    per_row = rows.shape[-1] // head_dim if head_dim else 1
+    if per_row <= 1:
+        return rows
+    return rows.reshape(*rows.shape[:-2], rows.shape[-2] * per_row, head_dim)
+
+
+def gather_block_views(
+    buf, table, width: int | None = None, head_dim: int | None = None
+):
     """Gather per-sequence dense-ordered KV views through block tables:
     ``buf`` (num_blocks, B, ...) x ``table`` (N, nmax) -> (N, L, ...) where
     ``L = width`` (sliced from nmax*B; ``None`` keeps the full nmax*B).
     Slicing to the dense buffer length keeps the attention reduction the
     SAME shape as the dense layout — a precondition of bitwise parity.
     Unmapped entries gather the sink block; its rows land at positions the
-    offset causal mask hides."""
+    offset causal mask hides. With ``head_dim`` the view comes out by heads,
+    (N, L, H_kv, D), whatever rows the pool keeps them in (``heads_view``)."""
     import jax.numpy as jnp
 
     n, nmax = table.shape
@@ -350,17 +366,20 @@ def gather_block_views(buf, table, width: int | None = None):
     view = view.reshape(n, nmax * buf.shape[1], *buf.shape[2:])
     if width is not None and width < view.shape[1]:
         view = view[:, :width]
-    return view
+    return heads_view(view, head_dim)
 
 
 def scatter_rows(buf, row_ids, rows):
     """Write flat pool rows: ``buf`` (num_blocks, B, ...), ``row_ids``
-    (M,) flat row indices (block*B + offset), ``rows`` (M, ...). Row ids
+    (M,) flat row indices (block*B + offset), ``rows`` (M, ...): a token's
+    values by heads, (M, H_kv, D), written in whatever rows the pool keeps
+    them (the same bytes in the same order). Row ids
     may repeat ONLY on sink rows (free slots all write there); the sink's
     content is never read unmasked, so the scatter's pick order is
     irrelevant."""
     nb, bt = buf.shape[0], buf.shape[1]
     flat = buf.reshape(nb * bt, *buf.shape[2:])
+    rows = rows.reshape(rows.shape[0], *buf.shape[2:])
     return flat.at[row_ids].set(rows).reshape(buf.shape)
 
 

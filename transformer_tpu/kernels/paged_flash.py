@@ -16,9 +16,11 @@ wider dimension and the online-softmax update runs once a block, not once a
 page. A table that is not a whole number of blocks wide is padded with the
 sink id. A block is fetched one of two ways, by the pages' shape:
 
-- **streamed** (pages that fill whole tiles: ``D`` a multiple of 128 and the
-  heads of a token filling 32-bit sublanes, e.g. bf16 with 2 or 8 KV heads of
-  128): the grid walks the slots only; the pools stay in HBM
+- **streamed** (pages that fill whole tiles: a minor axis in whole lane rows
+  and a token's rows filling 32-bit sublanes, e.g. bf16 with 2 or 8 KV heads
+  of 128, or 8 KV heads of 64 that the pool keeps two a lane row as 4 rows of
+  128: ``heads_per_lane_row``): the grid walks the slots only; the pools stay
+  in HBM
   (``memory_space=ANY``) and the kernel copies pages by hand into one of two
   VMEM buffers. A block aims for ``_BLOCK_POSITIONS`` = 512 key positions in
   sub-chunks of ``_CHUNK_POSITIONS`` = 128, because a block costs about
@@ -40,10 +42,19 @@ sink id. A block is fetched one of two ways, by the pages' shape:
   copies of block ``i+1`` (the slot's next, or the next slot's first) are
   started before the products of block ``i``. Head ``h`` of the token-major block (tokens, H_kv, D) is a
   sublane-strided read of rows ``h::H_kv`` (of the packed 32-bit words for
-  bf16, the head then shifted out): no transpose.
-- **tiled** (every other shape: ``D`` under a lane row, a head count that
-  leaves part of a packed sublane empty, e.g. one bf16 head, and every int8
-  pool, whose scales' size-1 minor axis is such a shape): these pages cannot
+  bf16, the head then shifted out): no transpose. Where a lane row holds
+  several heads the kernel's "head" IS the lane row: the wrapper hands it the
+  queries of the heads that share the row, each zero outside its own head's
+  lanes, and takes each head's lanes of the result, so the body below is the
+  same for both and no lane is shuffled (the products carry zeros and the
+  call is bound by bytes: 8 KV heads of 64 under 32 query heads, 128 slots
+  of mean length 790, read 457 us a call this way against 3,895 on the tiled
+  route and 253 for the bytes alone; PERF.md PR 34).
+- **tiled** (every other shape: heads under a lane row that fill no whole
+  lane rows and sublanes, e.g. an odd count of 64-wide heads or two of them
+  in bf16, a head count that leaves part of a packed sublane empty, e.g. one
+  bf16 head, and every int8 pool, whose scales' size-1 minor axis is such a
+  shape; also a pool of narrow heads handed over BY heads): these pages cannot
   be sliced out of HBM by hand, so the same pool is handed to the call once
   per page of the block, each BlockSpec resolving its own table entry; the
   grid walks (slot, block), blocks past a slot's length skip their compute,
@@ -51,8 +62,10 @@ sink id. A block is fetched one of two ways, by the pages' shape:
   steps cost the same dead or alive (measured at the serving cells' shape:
   three times the streamed route's time), which is why it is only the
   fallback. Its block stays at ``_TILED_BLOCK_POSITIONS`` = 64 and is folded
-  whole: no cell runs it, 128 -> 512 positions read 570 -> 520 us a call
-  (PR 27), and at 512 the pool would be handed to the call 64 to 128 times.
+  whole: no cell runs it since PR 34 (the LFM2 cell's 8 heads of 64 read
+  3,895 us a call on it, fifteen times their bytes' time), 128 -> 512
+  positions read 570 -> 520 us a call (PR 27), and at 512 the pool would be
+  handed to the call 64 to 128 times.
 
 What is not copied, and why that is harmless: a streamed block's pages at or
 past the slot's length, or before its band, keep what the buffer held. The
@@ -166,17 +179,40 @@ def _pages_per_block(
 
 def _streamable(h_kv: int, d: int, dtype) -> bool:
     """Whether (..., H_kv, D) pages of ``dtype`` tile without padding: D in
-    whole lane rows and a token's heads in whole 32-bit sublanes, a power of
+    whole lane rows and a token's rows in whole 32-bit sublanes, a power of
     two up to 8 or a multiple of 8 of them. Only then can a page be sliced
-    out of HBM by hand, and head ``h`` be read as rows ``h::H_kv`` of the
-    block's (tokens*H_kv, D) view. An int8 pool never is: its scales' size-1
-    minor axis cannot be sliced, and they decide for the codes."""
+    out of HBM by hand, and row ``h`` be read as rows ``h::H_kv`` of the
+    block's (tokens*H_kv, D) view. The page is the pool's own: for heads kept
+    several a lane row (``heads_per_lane_row``) ``h_kv`` counts the lane rows
+    and ``d`` is 128. An int8 pool never is: its scales' size-1 minor axis
+    cannot be sliced, and they decide for the codes."""
     itemsize = jnp.dtype(dtype).itemsize
     packing = 4 // itemsize
     if itemsize not in (2, 4) or d % _LANES or h_kv % packing:
         return False
     rows = h_kv // packing
     return rows in (1, 2, 4, 8) or rows % 8 == 0
+
+
+def heads_per_lane_row(h_kv: int, d: int, dtype, quantized: bool = False) -> int:
+    """How many of a token's KV heads the pool keeps in one row of 128 lanes
+    (``ops.attention.init_block_pool``): ``128 // D`` where a head is narrower
+    than a lane row, the heads fill whole rows and a page of those rows
+    streams; 1, the (H_kv, D) page, for every other shape. A minor axis under
+    128 is padded to a lane row on the chip, which a hand-made copy cannot
+    slice and which cost the LFM2 cell a relayout of each pool every step
+    (PERF.md PR 33); row-major, ``128 // D`` heads a row are the same bytes
+    in the same order with nothing to pad."""
+    if quantized or d >= _LANES or _LANES % d or (h_kv * d) % _LANES:
+        return 1
+    return _LANES // d if _streamable(h_kv * d // _LANES, _LANES, dtype) else 1
+
+
+def streams(pool_shape, dtype, quantized: bool) -> bool:
+    """Whether ``paged_flash_attention`` takes the streamed route over a K or
+    V pool of this shape, (num_blocks, B, rows, lanes) as stored: the page it
+    holds is what decides."""
+    return not quantized and _streamable(*pool_shape[2:], dtype)
 
 
 def _head_rows(pages_ref, h: int):
@@ -471,7 +507,11 @@ def paged_flash_attention(
         ``lengths[s] - S_q .. lengths[s] - 1`` (decode S_q = 1; speculative
         verify S_q = k + 1, causal inside the row).
       k_pool, v_pool: (num_blocks, B, H_kv, D) pool buffers — bf16/fp32
-        values, or int8 codes when ``k_scale``/``v_scale`` are given.
+        values, or int8 codes when ``k_scale``/``v_scale`` are given — or
+        the same bytes as (num_blocks, B, H_kv * D // 128, 128), heads
+        narrower than a lane row kept ``128 // D`` a row (what
+        ``init_block_pool`` allocates where ``heads_per_lane_row`` says so):
+        told apart by the minor axis against ``q``'s, and streamed.
       table: (N, nmax) int32 block table (``kernels/kv_pool.KVPool``);
         entries past a slot's owned count point at the pinned sink block 0.
       lengths: (N,) int32 valid KV length per sequence (including the S_q
@@ -490,13 +530,17 @@ def paged_flash_attention(
     Returns (N, S_q, H, D) attention outputs in q's dtype.
     """
     n, s_q, h, d = q.shape
-    num_blocks, block_tokens, h_kv, d_k = k_pool.shape
-    if d_k != d:
-        raise ValueError(f"head_dim mismatch: q {d} vs pool {d_k}")
-    if h % h_kv:
-        raise ValueError(f"query heads {h} must be a multiple of kv heads {h_kv}")
+    num_blocks, block_tokens, rows, d_k = k_pool.shape
     if (k_scale is None) != (v_scale is None):
         raise ValueError("int8 pools need BOTH k_scale and v_scale")
+    quantized = k_scale is not None
+    # A pool kept several heads a lane row says so by its minor axis.
+    per_row = 1 if d_k == d else _LANES // d
+    if d_k != d * per_row or (per_row > 1 and quantized):
+        raise ValueError(f"head_dim mismatch: q {d} vs pool {d_k}")
+    h_kv = rows * per_row
+    if h % h_kv:
+        raise ValueError(f"query heads {h} must be a multiple of kv heads {h_kv}")
     # Mosaic packs the pool's token axis into (sublane, lane) vregs whose
     # sublane count depends on the element width: 8 rows for fp32, 16 for
     # bf16, 32 for int8. A block_tokens that neither divides nor is a
@@ -509,7 +553,6 @@ def paged_flash_attention(
             f"{jnp.dtype(k_pool.dtype).name} pool's native sublane tiling "
             f"({sublane}): it must divide {sublane} or be a multiple of it"
         )
-    quantized = k_scale is not None
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     group = h // h_kv
@@ -526,14 +569,26 @@ def paged_flash_attention(
         .reshape(n, h_kv, group, s_q, d)
         .reshape(n, h_kv, gs, d)
     )
+    if per_row > 1:
+        # Heads kept `per_row` to a lane row: the kernel's "head" is a lane row,
+        # its queries the rows of the heads that live there, each zero but on
+        # its own head's lanes (head j of a row, folded row r, sits at row
+        # j*G*S_q + r). The score product then picks that head's keys out of
+        # the row, the value product fills all 128 lanes and the head's are
+        # taken below: the same sums with zeros added, no lane shuffled, each
+        # row of keys and values read once for the heads that share it.
+        own = jnp.eye(per_row, dtype=bool)[None, None, :, None, :, None]
+        qf = jnp.where(own, qf.reshape(n, rows, per_row, gs, 1, d), 0)
+        qf = qf.reshape(n, rows, per_row * gs, d_k)
+    q_rows = per_row * gs
 
     # The compute block: `pages` table entries a step, chosen from the
     # shapes. A table that is not a whole number of blocks wide is padded
     # with the sink id (no entry past a slot's length is ever dereferenced).
     itemsize = jnp.dtype(k_pool.dtype).itemsize
-    streamed = not quantized and _streamable(h_kv, d, k_pool.dtype)
+    streamed = streams(k_pool.shape, k_pool.dtype, quantized)
     pages, chunk = _pages_per_block(
-        block_tokens, h_kv, d, itemsize, quantized, nmax, streamed
+        block_tokens, rows, d_k, itemsize, quantized, nmax, streamed
     )
     nblk = -(-nmax // pages)
     if nblk * pages > nmax:
@@ -554,11 +609,11 @@ def paged_flash_attention(
 
         return index
 
-    q_spec = pl.BlockSpec((1, h_kv, gs, d), _at_seq)
+    q_spec = pl.BlockSpec((1, rows, q_rows, d_k), _at_seq)
     softmax_state = [
-        pltpu.VMEM((h_kv, gs, _LANES), jnp.float32),  # running max
-        pltpu.VMEM((h_kv, gs, _LANES), jnp.float32),  # normalizer
-        pltpu.VMEM((h_kv, gs, d), jnp.float32),       # output accumulator
+        pltpu.VMEM((rows, q_rows, _LANES), jnp.float32),  # running max
+        pltpu.VMEM((rows, q_rows, _LANES), jnp.float32),  # normalizer
+        pltpu.VMEM((rows, q_rows, d_k), jnp.float32),     # output accumulator
     ]
     if streamed:
         # The pools stay in HBM as they are; the kernel copies the pages it
@@ -600,10 +655,10 @@ def paged_flash_attention(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, h_kv, gs, d), _at_seq),
+            out_specs=q_spec,
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((n, h_kv, gs, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
         # Streamed slots run in order: each starts the next one's first copies.
         compiler_params=_compiler_params(
             ("arbitrary",) if streamed else ("parallel", "arbitrary")
@@ -611,6 +666,10 @@ def paged_flash_attention(
         interpret=bool(interpret),
         name="paged_flash_attention",
     )(table, lengths, *inputs)
+    if per_row > 1:
+        # Head j of a lane row: its own query rows, its own lanes.
+        out = out.reshape(n, rows, per_row, gs, per_row, d)
+        out = jnp.stack([out[:, :, j, :, j] for j in range(per_row)], axis=2)
     # Unfold (N, H_kv, G*S_q, D) -> (N, S_q, H, D).
     return (
         out.reshape(n, h_kv, group, s_q, d)

@@ -598,15 +598,27 @@ def init_block_pool(
     """One layer's PAGED KV pool: the per-position buffers of
     ``init_cache``, re-shaped from one (B, max_len, H, D) run per slot
     into a single (num_blocks, block_tokens, H, D) pool every slot
-    addresses through a block table (``kernels/kv_pool.py``). Buffer KEYS
-    and storage layouts are identical to the dense cache's — int8 codes
-    with fp32 scales, GQA kv-head counts — so ``kv_buffer_keys`` iterates
-    both, a pool block read IS a host-format prefix-cache block, and the
-    dense <-> paged round trip is bit-transparent. No ``index`` (per-slot
+    addresses through a block table (``kernels/kv_pool.py``). Buffer KEYS,
+    dtypes and a token's bytes in their order are identical to the dense
+    cache's — int8 codes with fp32 scales, GQA kv-head counts — so
+    ``kv_buffer_keys`` iterates both and the dense <-> paged round trip is
+    bit-transparent. The SHAPE of a token's page is the pool's own: heads
+    narrower than a lane row that fill whole rows are kept ``128 // D`` a row,
+    (num_blocks, block_tokens, H * D // 128, 128), where the decode kernel can
+    stream such a page (``kernels/paged_flash.heads_per_lane_row``, the one
+    rule, read from the shapes: bf16 8 x 64 -> 4 x 128; 2 x 128, 8 x 128, an
+    odd count of 64 and every int8 pool keep (H, D)). A minor axis of 64
+    would be padded to 128 lanes on the chip; row-major the two shapes are the
+    same bytes, so writers hand ``scatter_rows`` rows by heads and readers
+    take them back by heads (``kv_pool.heads_view``), the host-format
+    prefix-cache block (1, B, H, D) among them. No ``index`` (per-slot
     position bookkeeping lives with the table) and no rolling variant
     (rolling windows evict absolute-position rows — the same refusal the
     prefix cache and speculative rollback enforce)."""
-    shape = (num_blocks, block_tokens, num_heads, head_dim)
+    from transformer_tpu.kernels.paged_flash import heads_per_lane_row
+
+    per_row = heads_per_lane_row(num_heads, head_dim, dtype, quantize)
+    shape = (num_blocks, block_tokens, num_heads // per_row, head_dim * per_row)
     if quantize:
         return {
             "k": jnp.zeros(shape, dtype=jnp.int8),

@@ -335,16 +335,17 @@ def init_paged_pools(
     return [one(i) for i in range(cfg.num_layers)]
 
 
-def _paged_views(pool_caches, table, index, buf_len: int):
+def _paged_views(pool_caches, table, index, buf_len: int, head_dim: int):
     """Per-layer stacked slot views, structurally identical to the dense
-    SlotPool pytree: leaves (N, 1, buf_len, H, D) + per-slot ``index`` (a
+    SlotPool pytree: leaves (N, 1, buf_len, H, D), by heads of ``head_dim``
+    whatever rows the pool keeps them in, + per-slot ``index`` (a
     short-convolution layer's: its (N, 1, L - 1, d_model) state)."""
     from transformer_tpu.kernels.kv_pool import gather_block_views
 
     views = []
     for layer in pool_caches:
         view = {
-            key: gather_block_views(layer[key], table, buf_len)[:, None]
+            key: gather_block_views(layer[key], table, buf_len, head_dim)[:, None]
             for key in kv_buffer_keys(layer)
         }
         for key in state_buffer_keys(layer):
@@ -395,7 +396,7 @@ def _pool_step_paged(
 ):
     """Paged ``_pool_step``: gather views -> the SAME vmapped batch-1
     decode step -> scatter each slot's one new row back into its block."""
-    views = _paged_views(pool_caches, table, index, buf_len)
+    views = _paged_views(pool_caches, table, index, buf_len, cfg.head_dim)
 
     def one(tok, caches):
         pos = caches[0]["index"]
@@ -422,7 +423,7 @@ def _pool_verify_paged(
     """Paged ``_pool_verify``: W-wide rows through the same static-shape
     verify forward; rejected tails are erased by HOST table truncation
     (blocks return to the pool), not a device index rollback."""
-    views = _paged_views(pool_caches, table, index, buf_len)
+    views = _paged_views(pool_caches, table, index, buf_len, cfg.head_dim)
 
     def one(tok_row, caches):
         pos = caches[0]["index"]
@@ -500,7 +501,7 @@ def _slot_prefill_paged(
     row = jax.lax.dynamic_slice_in_dim(table, slot, 1, axis=0)  # (1, nmax)
     views = [
         {
-            key: gather_block_views(layer[key], row, buf_len)
+            key: gather_block_views(layer[key], row, buf_len, cfg.head_dim)
             for key in kv_buffer_keys(layer)
         }
         | {
@@ -537,7 +538,9 @@ def _pool_write_blocks(pool_caches, bids, blocks):
     """Write host-format prefix blocks into pool blocks ``bids`` — the
     paged restore for HOST-tier hits (and the warm-up/disaggregation
     inject path). ``blocks`` is per-layer dicts of (n_pad, B, H, D)
-    buffers in storage layout; ``bids`` is padded to a power-of-two count
+    buffers in storage layout, written in whatever rows the pool keeps a
+    token's heads (the same bytes in the same order); ``bids`` is padded to
+    a power-of-two count
     with sink ids + zero rows (compile set O(log pool), never one per hit
     length). Device-tier hits never reach here — they are pure table
     aliasing with zero host<->device copies."""
@@ -545,22 +548,26 @@ def _pool_write_blocks(pool_caches, bids, blocks):
     for layer, b in zip(pool_caches, blocks):
         new = dict(layer)
         for key in kv_buffer_keys(layer):
-            new[key] = layer[key].at[bids].set(b[key])
+            rows = b[key].reshape(b[key].shape[0], *layer[key].shape[1:])
+            new[key] = layer[key].at[bids].set(rows)
         out.append(new)
     return out
 
 
-@jax.jit
-def _pool_read_block(pool_caches, bid):
+@partial(jax.jit, static_argnames=("head_dim",))
+def _pool_read_block(pool_caches, bid, head_dim: int):
     """One pool block in host prefix-cache format: per-layer dicts of
-    (1, B, H, D) storage-layout buffers — byte-compatible with the dense
+    (1, B, H, D) storage-layout buffers, by heads of ``head_dim`` whatever
+    rows the pool keeps them in — byte-compatible with the dense
     ``_slot_read_blocks`` export, so spill-to-host, ``--disaggregate``
     KV handoff, and supervisor cache-warming keep their wire format."""
+    from transformer_tpu.kernels.kv_pool import heads_view
+
     return [
         {
-            key: jax.lax.dynamic_slice_in_dim(layer[key], bid, 1, axis=0)[0][
-                None
-            ]
+            key: heads_view(
+                jax.lax.dynamic_slice_in_dim(layer[key], bid, 1, axis=0), head_dim
+            )
             for key in kv_buffer_keys(layer)
         }
         for layer in pool_caches
@@ -1277,6 +1284,26 @@ class ContinuousScheduler:
                         "aliasing (zero host<->device copies) — a subset "
                         "of serve_prefix_hit_tokens_total; the remainder "
                         "was restored through a host block copy")
+            if self.decode_kernel == "paged_flash":
+                # Which way each attention layer's decode kernel fetches its
+                # pages, by the kernel's own rule over the pool as allocated.
+                from transformer_tpu.kernels.paged_flash import streams
+
+                routes = [
+                    streams(layer["k"].shape, layer["k"].dtype, "k_scale" in layer)
+                    for layer in self.pool.caches if "k" in layer
+                ]
+                reg.gauge(
+                    "serve_attn_layers_streamed",
+                    "attention layers whose paged_flash decode attention "
+                    "copies pool pages by hand (the streamed route)",
+                ).set(sum(routes))
+                reg.gauge(
+                    "serve_attn_layers_tiled",
+                    "attention layers whose paged_flash decode attention "
+                    "takes the tiled fallback (int8 pools, heads that fill "
+                    "no whole lane rows)",
+                ).set(len(routes) - sum(routes))
             if self._state_layers:
                 reg.gauge(
                     "serve_state_layers",
@@ -1374,7 +1401,9 @@ class ContinuousScheduler:
         supervisor cache warming). The device-resident HIT path never
         reaches here (pinned by test)."""
         return jax.device_get(
-            self._fn_pool_read_block(self.pool.caches, jnp.int32(bid))
+            self._fn_pool_read_block(
+                self.pool.caches, jnp.int32(bid), self.cfg.head_dim
+            )
         )
 
     def _paged_alloc(self, fn):

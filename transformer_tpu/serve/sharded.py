@@ -181,7 +181,7 @@ class ShardedPrograms:
             smod._pool_write_blocks, ins=(L, R, R), outs=L,
         )
         self.pool_read_block = twin(
-            smod._pool_read_block, ins=(L, R), outs=R,
+            smod._pool_read_block, statics=("head_dim",), ins=(L, R), outs=R,
         )
         self.pool_copy_blocks = twin(
             smod._pool_copy_blocks, ins=(L, R, R), outs=L,
