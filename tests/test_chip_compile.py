@@ -209,6 +209,63 @@ def test_moe_expert_ffn_at_laguna_widths(one_chip, tokens):
     assert re.search(r"%moe_expert_ffn[\w.]* = [^\n]*custom-call\(", text) and text.count("tpu_custom_call") == 1
 
 
+def test_moe_expert_ffn_at_kimi_widths(one_chip):
+    """The same layer at the Kimi Linear cell's decode shapes: 128 held experts
+    of 3 x 2304 x 1024 (a model width of 18 lane rows), 8 picks a token over
+    256 sigmoid scores with a selection bias, 256 slots a step."""
+    from transformer_tpu.ops.moe import moe_apply_dropless, moe_init
+
+    p = jax.eval_shape(lambda: moe_init(jax.random.PRNGKey(0), 2304, 1024, 256, BF16, experts_held=128,
+                                        activation="swiglu", shared_dff=1024, select_bias=True))
+    x = jax.ShapeDtypeStruct((256, 2304), BF16, sharding=one_chip)
+
+    def fn(p, x):
+        return moe_apply_dropless(p, x, num_experts=256, top_k=8, routed_scale=2.446, score="sigmoid",
+                                  renorm_epsilon=1e-20, interpret=False)
+
+    text = _compile(fn, _placed(p, one_chip), x).as_text()
+    assert re.search(r"%moe_expert_ffn[\w.]* = [^\n]*custom-call\(", text) and text.count("tpu_custom_call") == 1
+
+
+def test_kda_step_at_kimi_widths(one_chip):
+    """The delta-rule state update at the published widths: 32 heads of 128 x
+    128 float32 (2.10 MB a slot) under the cell's 256 slots, one slot's state
+    a block, the state aliased input to output. The kernel is named."""
+    from transformer_tpu.kernels.kda_step import kda_step
+
+    sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    n, h, d = 256, 32, 128
+
+    def fn(state, q, k, v, g, beta, live):
+        return kda_step(state, q, k, v, g, beta, live, interpret=False)
+
+    compiled = _compile(fn, sds((n, h, d, d)), sds((n, h, d)), sds((n, h, d)), sds((n, h, d)), sds((n, h, d)),
+                        sds((n, h)), sds((n,), jnp.int32), donate_argnums=(0,))
+    text = compiled.as_text()
+    assert re.search(r"%kda_step[\w.]* = [^\n]*custom-call\(", text) and text.count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().alias_size_in_bytes >= n * h * d * d * 4  # in place: no second 537 MB
+
+
+def test_paged_latent_attention_at_kimi_widths(one_chip):
+    """The latent decode attention at the published widths: 32 query heads
+    against rows of 512 + 64 channels kept in 640 lanes, 256 slots, a table of
+    512 pages of 16 (8,192 positions), the pool left in HBM. The kernel is
+    named."""
+    from transformer_tpu.kernels.paged_latent import paged_latent_attention
+    from transformer_tpu.ops.mla import latent_width
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    n, width = 256, latent_width(512, 64)
+    assert width == 640
+
+    def fn(q, pool, table, lengths):
+        return paged_latent_attention(q, pool, table, lengths, rank=512, interpret=False)
+
+    text = _compile(fn, sds((n, 32, width), BF16), sds((42480, 16, width), BF16), sds((n, 512), jnp.int32),
+                    sds((n,), jnp.int32)).as_text()
+    assert re.search(r"%paged_latent_attention[\w.]* = [^\n]*custom-call\(", text) and text.count("tpu_custom_call") == 1
+
+
 def test_fused_ln_ffn(one_chip):
     from transformer_tpu.ops.ffn import ffn_init, fused_ln_ffn
     from transformer_tpu.ops.nn import layernorm_init

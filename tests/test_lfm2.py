@@ -361,20 +361,20 @@ def test_scheduler_answers_are_the_full_forwards_greedy_tokens(cfg, params, depl
 def test_prefix_cache_speculation_forks_and_a_mesh_refuse_a_stateful_layer(cfg, params):
     from transformer_tpu.serve.prefix_cache import PrefixCache
 
-    with pytest.raises(ValueError, match="cached prefix holds KV rows and no snapshot"):
+    with pytest.raises(ValueError, match="cached prefix holds rows a position and no snapshot"):
         PrefixCache(cfg, block_tokens=16)
     plain = ModelConfig(num_layers=1, d_model=32, num_heads=2, dff=64, input_vocab_size=64, target_vocab_size=64,
                         decoder_only=True, position_scheme="rope", dtype="float32", max_position=64)
-    with pytest.raises(ValueError, match="no snapshot of the short-convolution state"):
+    with pytest.raises(ValueError, match="no snapshot of the state a slot keeps beside them"):
         _scheduler(cfg, params, prefix_cache=PrefixCache(plain, block_tokens=16))
     with pytest.raises(ValueError, match="rejected draft.*cannot be rolled back"):
         _scheduler(cfg, params, speculate_k=2)
-    with pytest.raises(ValueError, match="state row a slot"):
+    with pytest.raises(ValueError, match="state a slot beside it"):
         _scheduler(cfg, params, kv_layout="dense", decode_kernel="xla", mesh=2)
     sched = _scheduler(cfg, params)
     sched._paged_ensure(0, 16)
     sched.pool.alloc.extend(1, bid=int(sched.pool.alloc.table_device()[0, 0]))  # slot 1 shares slot 0's block
-    with pytest.raises(ValueError, match="copy-on-write fork.*state at the fork's position is not kept"):
+    with pytest.raises(ValueError, match="copy-on-write fork.*at the fork's position is not kept"):
         sched._paged_cow(1, 0, 16)
 
 

@@ -1235,6 +1235,28 @@ def _canned_entries() -> dict[str, Callable[[], tuple[Callable, tuple]]]:
         )
         return fn, (bf16(96, 128), bf16(4, 128, 256), bf16(4, 128, 256), bf16(4, 256, 128))
 
+    def kda_state_step():
+        # The delta-rule layer's decode step: 3 slots (one free) of 4 heads
+        # of 128, the state aliased input to output.
+        from transformer_tpu.kernels.kda_step import kda_step
+
+        f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+        fn = lambda s, q, k, v, g, b, live: kda_step(  # noqa: E731
+            s, q, k, v, g, b, live, interpret=True
+        )
+        vec = f32(3, 4, 128)
+        return fn, (f32(3, 4, 128, 128), vec, vec, vec, vec, f32(3, 4), i32(3))
+
+    def paged_latent():
+        # The latent layer's decode attention: 2 slots, 8 heads against rows
+        # of 256 lanes (rank 128), a table of 8 pages of 16 in one block.
+        from transformer_tpu.kernels.paged_latent import paged_latent_attention
+
+        fn = lambda q, pool, table, lengths: paged_latent_attention(  # noqa: E731
+            q, pool, table, lengths, rank=128, interpret=True
+        )
+        return fn, (bf16(2, 8, 256), bf16(24, 16, 256), i32(2, 8), i32(2))
+
     def _serve_entry(variant):
         # Mirror costs.canned_cost_reports()'s fused paged serve program
         # exactly — the kernels verified here are the ones costs prices.
@@ -1272,6 +1294,8 @@ def _canned_entries() -> dict[str, Callable[[], tuple[Callable, tuple]]]:
         "ffn.fused[relu,bf16]": ffn_relu,
         "ffn.fused[swiglu,bf16]": ffn_swiglu,
         "moe.expert_ffn[swiglu,bf16]": moe_ffn,
+        "kda.step[fp32]": kda_state_step,
+        "paged_latent[bf16]": paged_latent,
     }
     for variant in ("lm_bf16", "lm_int8_cache", "lm_gqa"):
         entries[f"serve.pool_step_paged_flash[{variant}]"] = functools.partial(

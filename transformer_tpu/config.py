@@ -29,9 +29,16 @@ class AttentionKind:
     (``ModelConfig.attention_kinds`` / ``layer_pattern``): a self-attention
     layer's query heads, its causal window and its rotary frequencies (every
     attention kind shares the model's KV heads and head size, so all of them
-    fit one KV pool), or, with ``conv_kernel``, a gated short convolution in
-    the attention sublayer's place (``ops/short_conv.py``), whose state is
-    ``conv_kernel - 1`` rows a sequence and no KV rows at all."""
+    fit one KV pool), or one of three other mixers in the attention
+    sublayer's place (``mixer`` says which): with ``conv_kernel``, a gated
+    short convolution (``ops/short_conv.py``), whose state is
+    ``conv_kernel - 1`` rows a sequence and no KV rows at all; with
+    ``kda_heads``, delta-rule linear attention (``ops/kda.py``), whose state
+    is a ``kda_head_dim`` x ``kda_head_dim`` float32 matrix a head and the
+    last ``kda_conv_kernel - 1`` inputs of three short convolutions; with
+    ``latent_rank``, latent attention (``ops/mla.py``), which keeps ONE row
+    of ``latent_rank + latent_shared_dim`` channels a position that every
+    head reads, in a pool entry of its own."""
 
     name: str
     num_heads: int = 0  # query heads; 0 = ModelConfig.num_heads
@@ -52,6 +59,37 @@ class AttentionKind:
     # Taps of the causal depthwise convolution of a short-convolution layer
     # (0 = an attention layer; the fields above then say which).
     conv_kernel: int = 0
+    # Delta-rule linear attention (KDA): heads, the width of a head's keys
+    # and of its values, the taps of the causal depthwise convolutions on q,
+    # k and v, and the rank of the decay gate and of the output gate (0 = the
+    # head's width). 0 heads = not such a layer.
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
+    kda_gate_rank: int = 0
+    # Latent attention (MLA): the latent's rank, a head's key part made from
+    # the latent, the key part all heads share (no rotation is applied), and
+    # a head's value width. Rank 0 = not such a layer.
+    latent_rank: int = 0
+    latent_nope_dim: int = 0
+    latent_shared_dim: int = 0
+    latent_value_dim: int = 0
+    # Seeded (not loaded) weights only, as ``ModelConfig.moe_router_init_scale``:
+    # a factor on the Glorot draw of a latent layer's query kernel. A trained
+    # attention is peaked; a Glorot draw's scores are so flat that over
+    # thousands of positions the values average away and nothing the scores
+    # are made of shows in the layer's output.
+    latent_query_init_scale: float = 1.0
+
+    @property
+    def mixer(self) -> str:
+        """What stands in the attention sublayer: ``"attention"``,
+        ``"conv"``, ``"kda"`` or ``"mla"``."""
+        if self.conv_kernel:
+            return "conv"
+        if self.kda_heads:
+            return "kda"
+        return "mla" if self.latent_rank else "attention"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -220,6 +258,20 @@ class ModelConfig:
                 raise ValueError(f"attention kind {k.name!r} rotates an odd number of channels")
             if k.conv_kernel < 0 or k.conv_kernel == 1:
                 raise ValueError(f"layer kind {k.name!r}: conv_kernel is 0 (attention) or 2 and more taps")
+            if sum(map(bool, (k.conv_kernel, k.kda_heads, k.latent_rank))) > 1:
+                raise ValueError(f"layer kind {k.name!r} names more than one mixer")
+            if k.kda_heads and (k.kda_head_dim < 1 or k.kda_conv_kernel < 2 or k.kda_gate_rank < 0):
+                raise ValueError(
+                    f"layer kind {k.name!r}: a delta-rule layer needs kda_head_dim "
+                    "and 2 and more taps"
+                )
+            if k.latent_rank and min(k.latent_nope_dim, k.latent_shared_dim, k.latent_value_dim) < 1:
+                raise ValueError(
+                    f"layer kind {k.name!r}: a latent layer needs latent_nope_dim, "
+                    "latent_shared_dim and latent_value_dim"
+                )
+            if k.latent_query_init_scale <= 0:
+                raise ValueError(f"layer kind {k.name!r}: latent_query_init_scale must be > 0")
         if self.moe_score not in ("softmax", "sigmoid"):
             raise ValueError(f"moe_score must be 'softmax' or 'sigmoid', got {self.moe_score!r}")
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -342,10 +394,12 @@ class ModelConfig:
 
     @property
     def state_layers(self) -> tuple[int, ...]:
-        """Layers whose state a sequence carries is not KV rows: the
-        short-convolution layers."""
+        """Layers whose state a sequence carries is not rows a position: the
+        short-convolution and the delta-rule layers (a latent layer keeps a
+        row a position and is not one)."""
         return tuple(
-            i for i in range(self.num_layers) if self.layer_kind(i).conv_kernel
+            i for i in range(self.num_layers)
+            if self.layer_kind(i).mixer in ("conv", "kda")
         )
 
     @property

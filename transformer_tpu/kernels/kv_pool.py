@@ -341,8 +341,9 @@ def heads_view(rows, head_dim: int | None):
     row (``ops.attention.init_block_pool``): row-major that is the same bytes
     in the same order, so the heads are a reshape away. Every other buffer
     (``C == D``, an int8 pool's size-1 scales, ``head_dim`` not given) is
-    returned as it is."""
-    per_row = rows.shape[-1] // head_dim if head_dim else 1
+    returned as it is, and so is a buffer with no head axis at all: a latent
+    layer's rows (``ops/mla.py``), (blocks or slots, tokens, lanes)."""
+    per_row = rows.shape[-1] // head_dim if head_dim and rows.ndim > 3 else 1
     if per_row <= 1:
         return rows
     return rows.reshape(*rows.shape[:-2], rows.shape[-2] * per_row, head_dim)

@@ -22,10 +22,13 @@ import jax.numpy as jnp
 
 from transformer_tpu.config import ModelConfig
 from transformer_tpu.ops.attention import init_cache, mha_apply, mha_init
+from transformer_tpu.ops.kda import init_kda_state, kda_apply, kda_init
+from transformer_tpu.ops.mla import init_latent_cache, mla_apply, mla_init
 from transformer_tpu.ops.short_conv import (
     init_conv_state,
     short_conv_apply,
     short_conv_init,
+    state_buffer_keys,
 )
 from transformer_tpu.ops.nn import (
     Params,
@@ -50,13 +53,23 @@ def decoder_layer_init(
     key: jax.Array, cfg: ModelConfig, layer_index: int = 0
 ) -> Params:
     k1, k2, k3 = jax.random.split(key, 3)
-    taps = cfg.layer_kind(layer_index).conv_kernel
-    # The layer's mixer: self-attention, or a short convolution in its place.
-    mixer = (
-        {"conv": short_conv_init(k1, cfg.d_model, taps, cfg.params_dtype)}
-        if taps
-        else {"self_mha": attention_init(k1, cfg, layer_index)}
-    )
+    kind = cfg.layer_kind(layer_index)
+    # The layer's mixer: self-attention, or one of the three in its place.
+    if kind.mixer == "conv":
+        mixer = {"conv": short_conv_init(k1, cfg.d_model, kind.conv_kernel, cfg.params_dtype)}
+    elif kind.mixer == "kda":
+        mixer = {"kda": kda_init(
+            k1, cfg.d_model, kind.kda_heads, kind.kda_head_dim, kind.kda_conv_kernel,
+            kind.kda_gate_rank, cfg.params_dtype,
+        )}
+    elif kind.mixer == "mla":
+        mixer = {"mla": mla_init(
+            k1, cfg.d_model, kind.num_heads, kind.latent_rank, kind.latent_nope_dim,
+            kind.latent_shared_dim, kind.latent_value_dim, cfg.params_dtype,
+            query_scale=kind.latent_query_init_scale,
+        )}
+    else:
+        mixer = {"self_mha": attention_init(k1, cfg, layer_index)}
     params: Params = {
         **mixer,
         **_ffn_sublayer_init(k3, cfg, layer_uses_moe(cfg, layer_index)),
@@ -96,9 +109,11 @@ def decoder_layer_apply(
     decode steps don't re-project the static encoder output every token.
     ``layer_index`` (static) picks the layer's attention kind where the
     model's layers differ: its window and its rotary frequencies (its heads
-    are the parameters' shape). A short-convolution layer's cache is
-    ``{"conv_state", "index"}``; it reads the state only past position 0, so
-    whatever an earlier sequence left in it is never seen.
+    are the parameters' shape). A stateful layer's cache is its state and
+    ``index`` (``{"conv_state"}``; a delta-rule layer's ``{"kda_state",
+    "kda_conv"}``); it reads the state only past position 0, so whatever an
+    earlier sequence left in it is never seen. A latent layer's is
+    ``{"ckv", "index"}``. The mixer is picked by the parameters' key.
     """
     r1, r2, r3 = (None, None, None) if rng is None else jax.random.split(rng, 3)
     boxes: list[Any] = [None, None, None]
@@ -111,6 +126,25 @@ def decoder_layer_apply(
         out, state = short_conv_apply(params["conv"], h, state)
         if cache is not None:
             boxes[2] = {"conv_state": state, "index": cache["index"] + h.shape[1]}
+        return out
+
+    def kda(h):
+        state = None
+        if cache is not None:
+            state = {
+                key: jnp.where(cache["index"] > 0, cache[key], 0)
+                for key in state_buffer_keys(cache)
+            }
+        out, state = kda_apply(params["kda"], h, state, cfg.layernorm_epsilon)
+        if cache is not None:
+            boxes[2] = {
+                **{key: state[key].astype(cache[key].dtype) for key in state},
+                "index": cache["index"] + h.shape[1],
+            }
+        return out
+
+    def latent_attn(h):
+        out, boxes[2] = mla_apply(params["mla"], h, cache, cfg.layernorm_epsilon)
         return out
 
     def self_attn(h):
@@ -129,7 +163,8 @@ def decoder_layer_apply(
         boxes[0], boxes[2] = w, new_cache
         return out
 
-    mixer = short_conv if "conv" in params else self_attn
+    mixers = {"conv": short_conv, "kda": kda, "mla": latent_attn}
+    mixer = next((fn for key, fn in mixers.items() if key in params), self_attn)
     x = _sublayer(cfg, params["ln1"], x, mixer, r1, deterministic)
 
     if not cfg.decoder_only:
@@ -287,17 +322,23 @@ def init_decoder_caches(
 ) -> list[dict[str, Any]]:
     """One self-attention KV cache per decoder layer (int8-quantized when
     ``cfg.kv_cache_int8``; a rolling O(window) buffer when
-    ``cfg.attention_window``), or, for a short-convolution layer, its state
+    ``cfg.attention_window``); for a latent layer, its cache of latent rows;
+    for a stateful layer (a short convolution, a delta-rule layer), its state
     and the position it stands at. Caches start at position 0; fill the
     prompt in one pass with ``decoder_prefill`` and decode incrementally from
     there (``transformer_decode_step``)."""
     def one(i):
-        taps = cfg.layer_kind(i).conv_kernel
-        if taps:
+        kind = cfg.layer_kind(i)
+        if kind.mixer in ("conv", "kda"):
             return {
-                "conv_state": init_conv_state(batch_size, cfg.d_model, taps, cfg.compute_dtype),
+                **init_layer_state(cfg, i, batch_size),
                 "index": jnp.array(0, dtype=jnp.int32),
             }
+        if kind.mixer == "mla":
+            return init_latent_cache(
+                batch_size, max_len, kind.latent_rank, kind.latent_shared_dim,
+                cfg.compute_dtype,
+            )
         return init_cache(
             batch_size, max_len, cfg.kv_heads, cfg.head_dim,
             cfg.compute_dtype, quantize=cfg.kv_cache_int8,
@@ -305,6 +346,23 @@ def init_decoder_caches(
         )
 
     return [one(i) for i in range(cfg.num_layers)]
+
+
+def init_layer_state(cfg: ModelConfig, layer_index: int, batch_size: int) -> dict[str, Any]:
+    """The state before position 0 of a layer that keeps a fixed state a
+    sequence (``cfg.state_layers``), ``batch_size`` sequences (or pool slots)
+    of it, under the keys ``ops.short_conv.state_buffer_keys`` lists."""
+    kind = cfg.layer_kind(layer_index)
+    if kind.mixer == "kda":
+        return init_kda_state(
+            batch_size, kind.kda_heads, kind.kda_head_dim, kind.kda_conv_kernel,
+            cfg.compute_dtype,
+        )
+    return {
+        "conv_state": init_conv_state(
+            batch_size, cfg.d_model, kind.conv_kernel, cfg.compute_dtype
+        )
+    }
 
 
 def precompute_cross_kvs(

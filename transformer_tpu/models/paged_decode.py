@@ -39,7 +39,14 @@ picks held here and of experts hit accumulate in the pool pytree
 short-convolution layer (``ops/short_conv.py``) has no pool: its entry of
 the pool pytree is a fixed state row a slot, ``{"conv_state": (N, L - 1,
 d_model)}``, which the step reads past position 0 and rolls for the live
-slots alone.
+slots alone. A delta-rule layer (``ops/kda.py``) has none either: its entry is
+the matrix state ``kda_state`` (N, H, D, D) float32 and the three
+convolutions' ``kda_conv`` rows; the step runs the projections and the
+one-token convolutions in XLA and ``kernels/kda_step.py`` over the states,
+in place, the live slots alone. A latent layer (``ops/mla.py``) has a pool of
+ONE row a position (``ckv``): the step scatters the new row, absorbs the
+projections into the queries and reads the pool through
+``kernels/paged_latent.py``.
 
 Scope guards (the gather path remains the general fallback): decoder-only
 LM configs, no model-wide ``attention_window`` (that option makes the dense
@@ -58,7 +65,9 @@ import jax.numpy as jnp
 
 from transformer_tpu.config import ModelConfig
 from transformer_tpu.kernels.flash_attention import paged_attention
+from transformer_tpu.kernels.kda_step import kda_step
 from transformer_tpu.kernels.kv_pool import block_row_ids, scatter_rows
+from transformer_tpu.kernels.paged_latent import paged_latent_attention
 from transformer_tpu.models.encoder import (
     _ffn_sublayer_apply,
     _sublayer,
@@ -75,6 +84,8 @@ from transformer_tpu.ops.attention import (
     normalise_qk,
 )
 from transformer_tpu.ops.ffn import fused_ln_ffn
+from transformer_tpu.ops.kda import kda_inputs, kda_output
+from transformer_tpu.ops.mla import absorbed_output, absorbed_queries, latent_rows
 from transformer_tpu.ops.nn import Params, norm_apply
 from transformer_tpu.ops.positional import apply_rope
 from transformer_tpu.ops.short_conv import short_conv_apply
@@ -207,7 +218,40 @@ def paged_decode_forward(
             pool_box[0] = {"conv_state": jnp.where(live, state, old)}
             return out
 
-        mixer = short_conv if "conv" in layer else self_attn
+        def kda(h, layer=layer, pool_box=pool_box):
+            # As ``short_conv``: a free slot reads zeros and keeps what it
+            # held (the kernel writes a slot that is not live back unchanged).
+            if s_q != 1:
+                raise ValueError("a delta-rule layer steps one position at a time")
+            live = index > 0
+            old = pool_box[0]
+            q, k, v, g, beta, conv = kda_inputs(
+                layer["kda"], h, jnp.where(live[:, None, None], old["kda_conv"], 0)
+            )
+            o, state = kda_step(
+                old["kda_state"], q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], live,
+                interpret=interpret,
+            )
+            pool_box[0] = {
+                "kda_state": state,
+                "kda_conv": jnp.where(live[:, None, None], conv.astype(old["kda_conv"].dtype), old["kda_conv"]),
+            }
+            return kda_output(layer["kda"], h, o[:, None], cfg.layernorm_epsilon)
+
+        def latent_attn(h, layer=layer, pool_box=pool_box, rank=cfg.layer_kind(i).latent_rank):
+            if s_q != 1:
+                raise ValueError("a latent layer's paged kernel reads one query position a slot")
+            mp = layer["mla"]
+            rows = latent_rows(mp, h, cfg.layernorm_epsilon).astype(pool_box[0]["ckv"].dtype)
+            pool_box[0] = {"ckv": scatter_rows(pool_box[0]["ckv"], rids, rows[:, 0])}
+            ctx = paged_latent_attention(
+                absorbed_queries(mp, h)[:, 0], pool_box[0]["ckv"], table, lengths,
+                rank=rank, interpret=interpret,
+            )
+            return absorbed_output(mp, ctx[:, None])
+
+        mixers = {"conv": short_conv, "kda": kda, "mla": latent_attn}
+        mixer = next((fn for key, fn in mixers.items() if key in layer), self_attn)
         x = _sublayer(cfg, layer["ln1"], x, mixer, None, True)
         new_pools.append(pool_box[0])
 

@@ -476,10 +476,17 @@ def kv_buffer_keys(cache: dict[str, Any]) -> tuple[str, ...]:
     ``k_scale``/``v_scale`` rows for int8-quantized ones. The ONE listing of
     the layout's buffer names — ``_store_kv``, ``slice_kv_blocks``, and
     ``insert_kv_blocks`` all iterate it, so a future layout (new buffer key)
-    cannot desynchronize the write, export, and restore paths. A
-    short-convolution layer's entry (``conv_state``) holds no such rows."""
-    if "conv_state" in cache:
+    cannot desynchronize the write, export, and restore paths. A latent
+    layer's entry (``ops/mla.py``) keeps ONE buffer, ``ckv``: a row a position
+    that is key and value at once. An entry that holds a fixed state a
+    sequence (``ops.short_conv.state_buffer_keys``: a short convolution's
+    rows, a delta-rule layer's matrix) holds no such rows."""
+    from transformer_tpu.ops.short_conv import state_buffer_keys
+
+    if state_buffer_keys(cache):
         return ()
+    if "ckv" in cache:
+        return ("ckv",)
     if "k_scale" in cache:
         return ("k", "k_scale", "v", "v_scale")
     return ("k", "v")

@@ -50,11 +50,18 @@ def init_conv_state(batch: int, d_model: int, kernel: int, dtype=jnp.bfloat16) -
     return jnp.zeros((batch, kernel - 1, d_model), dtype)
 
 
+# Every key that holds a fixed state a sequence, whatever the layer's kind.
+_STATE_KEYS = ("conv_state", "kda_state", "kda_conv")
+
+
 def state_buffer_keys(cache: dict) -> tuple[str, ...]:
     """The keys of one layer's cache or pool entry that hold a fixed state a
     sequence (``ops.attention.kv_buffer_keys`` lists the per-position ones):
-    ``("conv_state",)`` for a short-convolution layer, nothing otherwise."""
-    return ("conv_state",) if "conv_state" in cache else ()
+    ``("conv_state",)`` for a short-convolution layer, ``("kda_state",
+    "kda_conv")`` for a delta-rule layer (``ops/kda.py``: the matrix a head
+    and the three convolutions' inputs), nothing otherwise. The ONE listing:
+    the prefill's read and write, the views and the scatter iterate it."""
+    return tuple(key for key in _STATE_KEYS if key in cache)
 
 
 def short_conv_apply(

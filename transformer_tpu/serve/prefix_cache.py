@@ -234,8 +234,9 @@ class PrefixCache:
         if cfg.state_layers:
             raise ValueError(
                 "prefix cache cannot serve a model with a stateful layer: a "
-                "cached prefix holds KV rows and no snapshot of the "
-                "short-convolution state at its end"
+                "cached prefix holds rows a position and no snapshot of the "
+                "state a slot keeps beside them (a short convolution's "
+                "inputs, a delta-rule matrix) at the prefix's end"
             )
         if block_tokens < 1:
             raise ValueError(f"block_tokens must be >= 1, got {block_tokens}")

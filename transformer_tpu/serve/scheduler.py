@@ -122,7 +122,7 @@ import numpy as np
 
 from transformer_tpu.config import PAD_ID, ModelConfig
 from transformer_tpu.data.seeding import keyed_rng
-from transformer_tpu.models.decoder import init_decoder_caches
+from transformer_tpu.models.decoder import init_decoder_caches, init_layer_state
 from transformer_tpu.models.encoder import layer_uses_moe
 from transformer_tpu.models.paged_decode import (
     MOE_COUNTS,
@@ -140,7 +140,9 @@ from transformer_tpu.ops.attention import (
     kv_buffer_keys,
     slice_kv_blocks,
 )
-from transformer_tpu.ops.short_conv import init_conv_state, state_buffer_keys
+from transformer_tpu.ops.kda import CHUNK as KDA_CHUNK
+from transformer_tpu.ops.mla import latent_width
+from transformer_tpu.ops.short_conv import state_buffer_keys
 from transformer_tpu.serve.resilience import (
     BREAKER_STATE_VALUE,
     CircuitBreaker,
@@ -316,17 +318,27 @@ def _slot_read_blocks(pool_caches, slot, start, n: int):
 def init_paged_pools(
     cfg: ModelConfig, num_slots: int, pool_blocks: int, block_tokens: int
 ) -> list:
-    """The paged pool's per-layer device state, two kinds in one list: ONE
-    block pool of KV rows for each attention layer (every slot reaches it
-    through the block table), and for each short-convolution layer a fixed
-    state row a slot, ``{"conv_state": (num_slots, L - 1, d_model)}``, and no
-    blocks at all: the pool's bytes a token are the attention layers'."""
+    """The paged pool's per-layer device state, two kinds in one list. Rows a
+    position, which every slot reaches through the block table: ONE block pool
+    of K and V rows for each attention layer, one of latent rows for each
+    latent layer (``ops/mla.py``: a row of ``latent_width`` lanes stored once).
+    A fixed state a slot, and no blocks at all, for each short-convolution
+    layer (``{"conv_state": (num_slots, L - 1, d_model)}``) and each delta-rule
+    layer (``ops/kda.py``: ``kda_state`` (num_slots, H, D, D) float32 and the
+    three convolutions' ``kda_conv`` rows). The pool's bytes a token are the
+    attention and latent layers'."""
     from transformer_tpu.ops.attention import init_block_pool
+    from transformer_tpu.ops.mla import init_latent_pool
 
     def one(i):
-        taps = cfg.layer_kind(i).conv_kernel
-        if taps:
-            return {"conv_state": init_conv_state(num_slots, cfg.d_model, taps, cfg.compute_dtype)}
+        kind = cfg.layer_kind(i)
+        if kind.mixer in ("conv", "kda"):
+            return init_layer_state(cfg, i, num_slots)
+        if kind.mixer == "mla":
+            return init_latent_pool(
+                pool_blocks, block_tokens, kind.latent_rank,
+                kind.latent_shared_dim, cfg.compute_dtype,
+            )
         return init_block_pool(
             pool_blocks, block_tokens, cfg.kv_heads, cfg.head_dim,
             cfg.compute_dtype, quantize=cfg.kv_cache_int8,
@@ -338,8 +350,9 @@ def init_paged_pools(
 def _paged_views(pool_caches, table, index, buf_len: int, head_dim: int):
     """Per-layer stacked slot views, structurally identical to the dense
     SlotPool pytree: leaves (N, 1, buf_len, H, D), by heads of ``head_dim``
-    whatever rows the pool keeps them in, + per-slot ``index`` (a
-    short-convolution layer's: its (N, 1, L - 1, d_model) state)."""
+    whatever rows the pool keeps them in (a latent layer's: (N, 1, buf_len,
+    lanes)), + per-slot ``index`` (a stateful layer's: its state, (N, 1,
+    ...))."""
     from transformer_tpu.kernels.kv_pool import gather_block_views
 
     views = []
@@ -362,7 +375,8 @@ def _paged_scatter(pool_caches, new_views, table, index, s_q: int,
     storage layout (the view's buffers were written by the same _store_kv
     the dense path uses, so the pool rows are bit-identical to a dense
     cache's). Free slots (index 0, all-sink tables) land in the sink, and
-    keep the short-convolution state they held."""
+    keep the state they held (a short convolution's rows, a delta-rule
+    layer's matrix)."""
     from transformer_tpu.kernels.kv_pool import block_row_ids, scatter_rows
 
     n = table.shape[0]
@@ -378,9 +392,8 @@ def _paged_scatter(pool_caches, new_views, table, index, s_q: int,
                 layer[key], rids, rows.reshape(n * s_q, *rows.shape[2:])
             )
         for key in state_buffer_keys(layer):
-            new[key] = jnp.where(
-                (index > 0)[:, None, None], view[key][:, 0], layer[key]
-            )
+            live = (index > 0)[(slice(None),) + (None,) * (layer[key].ndim - 1)]
+            new[key] = jnp.where(live, view[key][:, 0].astype(layer[key].dtype), layer[key])
         out.append(new)
     return out
 
@@ -492,10 +505,12 @@ def _slot_prefill_paged(
     chunked prefill, then scatter the written suffix rows ``[start, start
     + n)`` into the slot's blocks. ``slot`` and ``start`` stay traced (no
     recompile per slot or hit length); NOT donated, for the same
-    admission-error isolation as the dense prefill. A short-convolution
-    layer's view is the slot's state row: read as the chunk's left edge where
-    ``start > 0`` (zeros at 0, whatever the slot held), written back as the
-    chunk's last gated inputs."""
+    admission-error isolation as the dense prefill. A stateful layer's view
+    is the slot's own state (a short convolution's rows; a delta-rule layer's
+    matrix and convolution inputs): read as the chunk's left edge where
+    ``start > 0`` (zeros at 0, whatever the slot held, so a recycled slot
+    never reads its predecessor's), written back as the state to the right of
+    the chunk."""
     from transformer_tpu.kernels.kv_pool import gather_block_views, scatter_rows
 
     row = jax.lax.dynamic_slice_in_dim(table, slot, 1, axis=0)  # (1, nmax)
@@ -527,7 +542,7 @@ def _slot_prefill_paged(
             new[key] = scatter_rows(layer[key], rids, rows)
         for key in state_buffer_keys(layer):
             new[key] = jax.lax.dynamic_update_slice_in_dim(
-                layer[key], c[key], slot, axis=0
+                layer[key], c[key].astype(layer[key].dtype), slot, axis=0
             )
         new_pool.append(new)
     return logits, new_pool
@@ -767,8 +782,9 @@ class SlotPool:
     """A fixed pool of per-slot decoder KV storage: stacked dense caches
     (``kv_layout="dense"``, the historical layout) or ONE block pool per
     attention layer shared by every slot through block tables (``"paged"``,
-    kernels/kv_pool.py — resident KV proportional to used tokens) beside a
-    fixed state row a slot for each short-convolution layer."""
+    kernels/kv_pool.py — resident KV proportional to used tokens; a latent
+    layer's pool holds one row a position) beside a fixed state a slot for
+    each layer that keeps one (``init_paged_pools``)."""
 
     def __init__(
         self,
@@ -897,27 +913,29 @@ class ContinuousScheduler:
                 "serve this config without --prefix_cache_mb"
             )
         if cfg.state_layers:
-            # A layer whose state is not KV rows (a short convolution's last
-            # gated inputs) has one state a slot, overwritten by every token:
-            # nothing below can bring an earlier one back.
+            # A layer whose state is not rows a position (a short
+            # convolution's last gated inputs, a delta-rule layer's matrix)
+            # has one state a slot, overwritten by every token: nothing below
+            # can bring an earlier one back.
             if speculate_k:
                 raise ValueError(
                     "speculative decoding cannot serve a model with a stateful "
-                    "layer: a rejected draft's tokens have already rolled the "
-                    "short-convolution state, and it cannot be rolled back; "
-                    "serve this config with speculate_k=0"
+                    "layer: a rejected draft's tokens have already moved the "
+                    "state a slot keeps in place of rows a position (a short "
+                    "convolution's inputs, a delta-rule matrix), and it cannot "
+                    "be rolled back; serve this config with speculate_k=0"
                 )
             if prefix_cache is not None:
                 raise ValueError(
                     "prefix cache cannot serve a model with a stateful layer: a "
-                    "cached prefix holds KV rows and no snapshot of the "
-                    "short-convolution state at its end; serve this config "
-                    "without --prefix_cache_mb"
+                    "cached prefix holds rows a position and no snapshot of "
+                    "the state a slot keeps beside them at the prefix's end; "
+                    "serve this config without --prefix_cache_mb"
                 )
             if mesh is not None:
                 raise ValueError(
                     "a sharded replica (--mesh) places the pool by its KV "
-                    "layout, and a model with a stateful layer has a state row "
+                    "layout, and a model with a stateful layer has a state "
                     "a slot beside it; serve this config on one device"
                 )
         self.params, self.cfg, self.tok = params, cfg, tokenizer
@@ -1031,9 +1049,12 @@ class ContinuousScheduler:
         )
         self._state_layers = len(cfg.state_layers)
         self._state_bytes = sum(
-            (cfg.layer_kind(i).conv_kernel - 1) * cfg.d_model
-            * cfg.compute_dtype.itemsize
+            x.size * x.dtype.itemsize
             for i in cfg.state_layers
+            for x in jax.eval_shape(lambda i=i: init_layer_state(cfg, i, 1)).values()
+        )
+        self._kda_layers = sum(
+            cfg.layer_kind(i).mixer == "kda" for i in cfg.state_layers
         )
         self._moe_layer = None
         if decode_kernel == "paged_flash" and cfg.moe_dispatch == "dropless":
@@ -1307,12 +1328,30 @@ class ContinuousScheduler:
             if self._state_layers:
                 reg.gauge(
                     "serve_state_layers",
-                    "layers whose per-slot state is not KV rows (short "
-                    "convolutions)").set(self._state_layers)
+                    "layers whose per-slot state is not rows a position "
+                    "(short convolutions, delta-rule layers)",
+                ).set(self._state_layers)
                 reg.gauge(
                     "serve_state_bytes_per_slot",
                     "bytes a slot holds for those layers' state",
                 ).set(self._state_bytes)
+            latent = [
+                k for k in map(cfg.layer_kind, range(cfg.num_layers))
+                if k.mixer == "mla"
+            ]
+            if latent:
+                reg.gauge(
+                    "serve_latent_layers",
+                    "layers whose pool holds one latent row a position",
+                ).set(len(latent))
+                reg.gauge(
+                    "serve_latent_bytes_per_position",
+                    "bytes a position holds in those layers' pools, as "
+                    "stored (a row padded to whole lane rows)",
+                ).set(cfg.compute_dtype.itemsize * sum(
+                    latent_width(k.latent_rank, k.latent_shared_dim)
+                    for k in latent
+                ))
             self._m_deadline = reg.counter(
                 "serve_deadline_expired_total",
                 "requests answered with a deadline error")
@@ -1445,8 +1484,9 @@ class ContinuousScheduler:
         if pairs and self._state_layers:
             raise ValueError(
                 "a copy-on-write fork cannot serve a model with a stateful "
-                "layer: the blocks a fork shares hold KV rows, and the "
-                "short-convolution state at the fork's position is not kept"
+                "layer: the blocks a fork shares hold rows a position, and "
+                "the state a slot keeps beside them at the fork's position "
+                "is not kept"
             )
         if pairs:
             src = jnp.asarray(_pow2_pad([s for s, _ in pairs]), jnp.int32)
@@ -2306,6 +2346,9 @@ class ContinuousScheduler:
             slot=slot, prefix_hit_tokens=st.prefix_hit, prompt_tokens=L,
             prefill_tokens=n_suffix,
         )
+        if self._kda_layers:
+            # Chunks the prefill's delta-rule scans walked, over its layers.
+            p.span_admit.set(kda_chunks=self._kda_layers * -(-n_suffix // KDA_CHUNK))
         if deadline is not None and time.perf_counter() >= deadline:
             # Prefill-boundary deadline check: the prompt ingest alone
             # consumed the budget — answer now instead of decoding tokens
