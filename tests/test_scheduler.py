@@ -309,10 +309,11 @@ def _children(spans, parent):
     ids=["dense", "chunked", "paged"],
 )
 def test_step_spans_phases_and_counts(lm, kw):
-    """Every ``scheduler.step`` span holds the five phases inside itself,
-    and its counts add up: a stepped slot either emitted a token or walked
-    a prompt-tail token, and the run's output tokens are the steps'
-    ``emitted`` plus the tokens picked at admission."""
+    """Every ``scheduler.step`` span holds its phases inside itself, the
+    enqueue of the next step before the fetch of the one in flight, and its
+    counts, which are the fetched step's, add up: a stepped slot either
+    emitted a token or walked a prompt-tail token, and the run's output
+    tokens are the steps' ``emitted`` plus the tokens picked at admission."""
     # Every request asks for a token or more and none can end early (no EOS
     # in a random model's greedy output is not guaranteed, so check below).
     reqs = [
@@ -334,25 +335,38 @@ def test_step_spans_phases_and_counts(lm, kw):
     for step in steps:
         kids = _children(spans, step)
         names = [k["name"] for k in kids]
-        for phase in STEP_PHASES:
-            assert phase in names, (phase, names)
         for k in kids:
             assert k["t0_mono"] >= step["t0_mono"]
             assert _end(k) <= _end(step) + 1e-6
             assert k["lane"] == "scheduler" and k["trace"] == step["trace"]
-        # The phases come in order; dispatch and fetch alternate, a pair a
-        # sampling group.
-        df = [n for n in names if n in ("step.dispatch", "step.fetch")]
-        assert df == ["step.dispatch", "step.fetch"] * (len(df) // 2)
+        # The phases come in order: a build and a dispatch an enqueued step
+        # (two where nothing was in flight, with a second prepare between
+        # them; a build and no dispatch where every slot's budget ends in
+        # flight), then the call's one fetch, of the step before.
         assert names[0] == "step.prepare" and names[1] == "step.build"
-        assert names[-1] == "step.bookkeep"
+        assert names[-2:] == ["step.fetch", "step.bookkeep"]
+        assert names.count("step.fetch") == names.count("step.bookkeep") == 1
+        enqueues = names[1:-2]
+        assert enqueues in (
+            ["step.build"],
+            ["step.build", "step.dispatch"],
+            ["step.build", "step.dispatch", "step.prepare", "step.build"],
+            ["step.build", "step.dispatch", "step.prepare", "step.build",
+             "step.dispatch"],
+        ), names
+        assert step["ahead"] == (names.count("step.prepare") == 1)
         assert step["continued"] <= step["emitted"]
         assert step["emitted"] + step["walked"] <= step["active"]
         if not ended_early:
             assert step["emitted"] + step["walked"] == step["active"]
+        # The choice of the input tokens, the pool step, and a pick a
+        # sampling group with a merge of the picks between two of them.
         dispatched = [k for k in kids if k["name"] == "step.dispatch"]
-        assert dispatched[0]["programs"] == 2
-        assert all(k["programs"] == 1 for k in dispatched[1:])
+        assert all(k["programs"] in (3, 5) for k in dispatched)
+    # Only the first call, and one after the pool drained, found nothing in
+    # flight.
+    assert steps[0]["ahead"] == 0 and steps[1]["ahead"] == 1
+    assert sum(s["overstepped"] for s in steps) >= 1  # a budget's end, at least
     admits = [s for s in spans if s["name"] == "serve.admit"]
     assert len(admits) == len(reqs)
     first_picks = sum(
@@ -419,9 +433,10 @@ def test_step_metrics_count_every_step(lm, kw, speculate_k):
         )
 
 
-def test_sampling_groups_alternate_dispatch_and_fetch(lm):
-    """Greedy and sampled requests side by side make two pick groups: the
-    step dispatches and fetches one after the other, in that order."""
+def test_sampling_groups_share_one_dispatch_and_one_fetch(lm):
+    """Greedy and sampled requests side by side make two pick groups: one
+    dispatch enqueues both picks and their merge, and the call fetches the
+    one merged vector."""
     reqs = [
         {"prompt": "ab cd ef", "max_new": 6},
         {"prompt": "gh ij", "max_new": 6, "temperature": 0.8, "seed": 5},
@@ -432,11 +447,15 @@ def test_sampling_groups_alternate_dispatch_and_fetch(lm):
     ]
     assert both
     for step in both:
-        names = [
+        kids = [
             k["name"] for k in _children(spans, step)
             if k["name"] in ("step.dispatch", "step.fetch")
         ]
-        assert names == ["step.dispatch", "step.fetch"] * 2
+        assert kids[-1] == "step.fetch" and kids.count("step.fetch") == 1
+    # The choice, the pool step, two picks and their merge: as often as a
+    # step fed both requests (the dispatch is a call earlier than the fetch).
+    programs = [s["programs"] for s in spans if s["name"] == "step.dispatch"]
+    assert programs.count(5) >= len(both) and set(programs) <= {3, 5}
 
 
 def test_admit_spans_and_request_gaps(lm):
@@ -445,11 +464,7 @@ def test_admit_spans_and_request_gaps(lm):
     gap between the steps that emitted the request's tokens."""
     # A prompt of a power of two of tokens (BOS included) is prefilled whole
     # and picks its first token at admission; any other walks its tail.
-    tok = lm[2]
-    prompt = next(
-        p for p in ("ab cd ef", "ab cd ef gh", "ab cd ef gh ij", "ab cd")
-        if prefill_len_for(1 + len(tok.encode(p))) == 1 + len(tok.encode(p)) >= 4
-    )
+    prompt = _whole_prompt(lm[2])
     reqs = [{"prompt": prompt, "max_new": 6}]
     _, spans, tapped = _buffered_run(lm, reqs, num_slots=1)
     (admit,) = [s for s in spans if s["name"] == "serve.admit"]
@@ -513,3 +528,320 @@ def test_idle_pool_leaves_no_step_span(lm):
     for _ in range(5):
         sched.step()
     assert len(buffer()) == 0
+
+
+# --------------------------------------------------------------------------
+# one decode step always in flight: step t + 1 is enqueued before step t's
+# picks are fetched, and every request still gets what ``generate`` gives it
+
+LAYOUTS = {
+    "dense": dict(),
+    "paged": dict(kv_layout="paged", kv_block=4),
+    "paged_flash": dict(
+        kv_layout="paged", kv_block=4, decode_kernel="paged_flash"
+    ),
+}
+layouts = pytest.mark.parametrize(
+    "layout", list(LAYOUTS.values()), ids=list(LAYOUTS)
+)
+
+
+def _tokens(tok, prompt):
+    return 1 + len(tok.encode(prompt))
+
+
+def _whole_prompt(tok):
+    """A prompt prefilled whole (a power of two of tokens, BOS included):
+    its first token is picked at admission, none of it walks."""
+    return next(
+        p for p in ("ab cd ef", "ab cd ef gh", "ab cd ef gh ij", "ab cd")
+        if prefill_len_for(_tokens(tok, p)) == _tokens(tok, p) >= 4
+    )
+
+
+PARITY_CASES = {
+    # Every prompt leaves a tail that walks one token a step.
+    "greedy_tail": lambda tok: [
+        {"prompt": _whole_prompt(tok) + " ij kl", "max_new": 7},
+        {"prompt": _whole_prompt(tok) + " mn", "max_new": 4},
+        {"prompt": _whole_prompt(tok) + " ab cd ef", "max_new": 5},
+    ],
+    # No tail: the step's first input is the token picked at admission.
+    "greedy_whole": lambda tok: [
+        {"prompt": _whole_prompt(tok), "max_new": 6},
+        {"prompt": _whole_prompt(tok), "max_new": 3},
+        {"prompt": _whole_prompt(tok), "max_new": 5},
+    ],
+    "sampled": lambda tok: [
+        {"prompt": "ab cd", "max_new": 8, "temperature": 0.9, "seed": 3},
+        {"prompt": _whole_prompt(tok), "max_new": 6, "temperature": 0.9,
+         "seed": 11},
+        {"prompt": "gh ij kl mn ab", "max_new": 5, "temperature": 0.9,
+         "seed": 2**31 + 5},
+    ],
+    # Greedy, sampled and sampled-with-top-k slots in one step.
+    "groups": lambda tok: [dict(r) for r in REQS],
+}
+
+
+@layouts
+@pytest.mark.parametrize("case", list(PARITY_CASES))
+def test_step_in_flight_matches_generate(lm, layout, case):
+    """Token for token what each request alone gets from ``generate``,
+    through 2 slots (so slots are recycled beside a step in flight)."""
+    params, cfg, tok = lm
+    reqs = PARITY_CASES[case](tok)
+    want = _sequential(params, cfg, tok, reqs)
+    sched = ContinuousScheduler(params, cfg, tok, num_slots=2, **layout)
+    got = sched.run([dict(r) for r in reqs])
+    assert [g.get("continuation") for g in got] == want
+    assert not sched.busy and not sched._flights and len(sched._free) == 2
+    if sched.paged:
+        sched.pool.alloc.check_consistency()
+        assert sched.pool.alloc.used_blocks == 0
+
+
+@pytest.mark.parametrize(
+    "seed", [0, 1, 7, 2**31 - 1, 2**31, 2**32 - 1, 2**32, 2**40 + 5, -1, -(2**31)]
+)
+def test_request_key_is_jax_prngkey(seed):
+    import numpy as np
+
+    from transformer_tpu.serve.scheduler import _request_key
+
+    want = np.asarray(jax.random.PRNGKey(seed))
+    got = _request_key(seed)
+    assert got.dtype == want.dtype and got.tolist() == want.tolist()
+
+
+class _EosAt:
+    """The tokenizer with another token for its EOS."""
+
+    def __init__(self, tok, eos_id):
+        self._tok, self.eos_id = tok, eos_id
+
+    def __getattr__(self, name):
+        return getattr(self._tok, name)
+
+
+def _emitted_ids(params, cfg, tok, req):
+    sched = ContinuousScheduler(params, cfg, tok, num_slots=1)
+    sched.submit(dict(req))
+    sched.admit()
+    (st,) = sched._active.values()
+    while sched.busy:
+        sched.step()
+    return list(st.emitted)
+
+
+@layouts
+def test_eos_is_seen_one_step_late_and_its_row_dropped(lm, layout):
+    """An EOS in mid-stream: the slot's row of the step already enqueued runs
+    and is dropped, and the slot's next occupant, admitted beside that step
+    in flight, never sees its pick."""
+    from transformer_tpu.obs import Telemetry
+
+    params, cfg, tok = lm
+    # Sampled: a random model's greedy output repeats one token.
+    ends = {"prompt": _whole_prompt(tok) + " ij", "max_new": 10,
+            "temperature": 0.9, "seed": 0}
+    ids = _emitted_ids(params, cfg, tok, ends)
+    # The first token that differs from every one before it, third or later.
+    k = next(i for i in range(2, len(ids)) if ids[i] not in ids[:i])
+    eos = _EosAt(tok, ids[k])
+    reqs = [
+        ends,                                              # ends at the EOS
+        {"prompt": "kl mn ab cd ef gh", "max_new": 14},    # decodes on
+        {"prompt": "mn ef", "max_new": 6},                 # the slot's next
+    ]
+    want = _sequential(params, cfg, eos, reqs)
+    assert want[0] == _sequential(params, cfg, tok, reqs[:1])[0][: len(want[0])]
+    tel = Telemetry()
+    tapped = []
+    sched = ContinuousScheduler(
+        params, cfg, eos, num_slots=2, telemetry=tel,
+        span_tap=tapped.append, **layout,
+    )
+    got = sched.run([dict(r) for r in reqs])
+    assert [g.get("continuation") for g in got] == want
+    assert sorted(tapped, key=lambda t: t["order"])[0]["new_tokens"] == k
+    # The EOS's row, and the row of each budget that ended.
+    assert tel.registry.counter("serve_oversteps_total").value >= 2
+    assert tel.registry.counter("serve_steps_ahead_total").value >= (
+        sched.stats["steps"] - 2
+    )
+
+
+@layouts
+def test_budget_end_leaves_the_published_prompt_blocks_alone(lm, layout):
+    """A slot whose budget ends in flight still runs a row of the next step:
+    behind its last valid row, never at position 0 of blocks that its
+    retirement publishes to the prefix cache."""
+    from transformer_tpu.serve.prefix_cache import PrefixCache
+
+    params, cfg, tok = lm
+    base = "ab cd ef gh ij kl mn ab cd"
+    reqs = [
+        {"prompt": base, "max_new": 3},
+        {"prompt": base + " ef gh", "max_new": 4},
+        {"prompt": base + " ij", "max_new": 2},
+    ]
+    want = _sequential(params, cfg, tok, reqs)
+    sched = ContinuousScheduler(
+        params, cfg, tok, num_slots=1,
+        prefix_cache=PrefixCache(cfg, block_tokens=4, budget_mb=1), **layout,
+    )
+    got = [sched.run([dict(r)])[0] for r in reqs]
+    assert [g.get("continuation") for g in got] == want
+    assert sched.stats["prefix_hit_tokens"] >= 8
+
+
+@layouts
+def test_request_that_ends_exactly_at_max_total(lm, layout):
+    """Prompt and answer fill the slot to its last token: the answer is whole
+    (its last token is never fed), and the row run past the budget's end
+    lands on the slot's last position, where nobody reads."""
+    params, cfg, tok = lm
+    prompt = _whole_prompt(tok) + " ij kl"
+    max_new = 6
+    reqs = [{"prompt": prompt, "max_new": max_new}] * 2
+    want = _sequential(params, cfg, tok, reqs)
+    sched = ContinuousScheduler(
+        params, cfg, tok, num_slots=2,
+        max_total=_tokens(tok, prompt) + max_new, **layout,
+    )
+    got = sched.run([dict(r) for r in reqs])
+    assert [g.get("continuation") for g in got] == want
+    # Asked for more, the budget is cut to what the slot holds.
+    more = sched.run([{"prompt": prompt, "max_new": max_new + 5}])
+    assert more[0]["continuation"] == want[0]
+
+
+@layouts
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+def test_slot_freed_beside_a_step_in_flight(lm, layout, how):
+    """A cancellation or an expiry with a step in flight: the slot's pick of
+    that step is dropped, its neighbour and its next occupant get their own
+    tokens."""
+    import time
+
+    params, cfg, tok = lm
+    stay = {"prompt": "kl mn ab cd ef gh", "max_new": 12}
+    nxt = {"prompt": "mn ef ab", "max_new": 5, "temperature": 0.8, "seed": 4}
+    want = _sequential(params, cfg, tok, [stay, nxt])
+    sched = ContinuousScheduler(params, cfg, tok, num_slots=2, **layout)
+    gone = sched.submit({"prompt": "ab cd ef", "max_new": 20})
+    sched.submit(dict(stay))
+    sched.admit()
+    sched.step()
+    sched.step()
+    assert sched._flights and sched.active_count == 2
+    if how == "cancel":
+        assert sched.cancel(gone)
+    else:
+        st = next(s for s in sched._active.values() if s.order == gone)
+        st.deadline = time.perf_counter() - 1.0
+    sched.submit(dict(nxt))
+    out = sched.run([])
+    assert out[0]["code"] == ("cancelled" if how == "cancel" else "deadline")
+    assert "partial" in out[0]
+    assert [o.get("continuation") for o in out[1:]] == want
+    assert not sched._flights and len(sched._free) == 2
+
+
+@pytest.mark.parametrize(
+    "layout", [LAYOUTS["paged"], LAYOUTS["paged_flash"]],
+    ids=["paged", "paged_flash"],
+)
+def test_pool_exhaustion_beside_a_step_in_flight(lm, layout):
+    """No block for the step ahead: that step is not enqueued, and the
+    preemption is decided with nothing in flight, as it always was. The
+    preempted request's partial answer is a prefix of its whole one, the
+    other request's is whole."""
+    params, cfg, tok = lm
+    reqs = [
+        {"prompt": "ab cd ef gh ij kl", "max_new": 14},
+        {"prompt": "mn ef cd ab kl ij", "max_new": 14},
+    ]
+    want = _sequential(params, cfg, tok, reqs)
+    # 5 blocks of 4 tokens for two requests of 21 tokens each.
+    sched = ContinuousScheduler(
+        params, cfg, tok, num_slots=2, max_total=32,
+        **{**layout, "kv_pool_blocks": 6}, admission_retries=0,
+    )
+    out = sched.run([dict(r) for r in reqs])
+    assert sched.stats["kv_preempted"] >= 1
+    codes = [o.get("code") for o in out]
+    assert "resource" in codes and None in codes
+    for o, w in zip(out, want):
+        if o.get("code") == "resource":
+            assert w.startswith(o.get("partial", ""))
+        else:
+            assert o["continuation"] == w
+    sched.pool.alloc.check_consistency()
+    assert sched.pool.alloc.used_blocks == 0 and not sched._flights
+
+
+@layouts
+def test_weights_staged_beside_a_step_in_flight(lm, layout):
+    """A stage with a step in flight: the requests in the pool finish on the
+    weights they were admitted under, the flip waits for the pool to drain
+    (and the step in flight with it), the next request runs on the new."""
+    params, cfg, tok = lm
+    new = transformer_init(jax.random.PRNGKey(1), cfg)
+    reqs = [
+        {"prompt": "ab cd ef gh ij", "max_new": 6},
+        {"prompt": "kl mn", "max_new": 9},
+    ]
+    after = {"prompt": "ab cd ef gh ij", "max_new": 6}
+    want_old = _sequential(params, cfg, tok, reqs)
+    want_new = _sequential(new, cfg, tok, [after])
+    assert want_new[0] != want_old[0]
+    sched = ContinuousScheduler(
+        params, cfg, tok, num_slots=2, weight_version="old", **layout
+    )
+    for r in reqs:
+        sched.submit(dict(r))
+    sched.admit()
+    sched.step()
+    assert sched._flights
+    sched.stage_params(new, "new")
+    sched.submit(dict(after))
+    out = sched.run([])
+    assert [o["weight_version"] for o in out] == ["old", "old", "new"]
+    assert [o["continuation"] for o in out] == want_old + want_new
+    assert sched.consume_swap_events() == [{"ok": True, "version": "new"}]
+
+
+@layouts
+def test_next_step_is_enqueued_before_the_fetch(lm, layout):
+    """The order itself: in every call after the first, the dispatch of the
+    next step closes before the fetch of the step in flight opens, and that
+    step had been enqueued ahead; one small program chooses every step's
+    input tokens, compiled once."""
+    from transformer_tpu.analysis.retrace import _cache_size
+    from transformer_tpu.serve import scheduler as smod
+
+    reqs = [
+        {"prompt": "ab cd ef gh ij", "max_new": 12},
+        {"prompt": "kl mn ab", "max_new": 12, "temperature": 0.7, "seed": 9},
+    ]
+    _buffered_run(lm, reqs, num_slots=2, **layout)  # compiles
+    compiled = _cache_size(smod._choose)
+    out, spans, _ = _buffered_run(lm, reqs, num_slots=2, **layout)
+    assert all("continuation" in o for o in out)
+    assert _cache_size(smod._choose) == compiled
+    steps = sorted(
+        (s for s in spans if s["name"] == "scheduler.step"),
+        key=lambda s: s["t0_mono"],
+    )
+    assert len(steps) >= 12
+    for i, step in enumerate(steps):
+        kids = _children(spans, step)
+        (fetch,) = [k for k in kids if k["name"] == "step.fetch"]
+        dispatches = [k for k in kids if k["name"] == "step.dispatch"]
+        assert all(_end(d) <= fetch["t0_mono"] for d in dispatches)
+        assert step["ahead"] == (i > 0)
+        # Two enqueued where nothing was in flight, one ever after, none
+        # once every budget ends in the step in flight.
+        assert len(dispatches) == (2 if i == 0 else 1) or i >= len(steps) - 2

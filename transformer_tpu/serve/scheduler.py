@@ -30,6 +30,14 @@ pool of KV-cache slots:
 - **Retirement at step boundaries**: a slot that emits EOS (or exhausts its
   ``max_new`` budget) is retired and recycled at the next step boundary; the
   remaining slots never wait for it.
+- **One step always in flight** (the plain path, every layout): a call of
+  ``step()`` enqueues pool step t+1 BEFORE it fetches step t's picks, so the
+  device never waits for the host's build, dispatch and slot walk. What step
+  t+1 feeds is known without those picks (positions advance by one, keys
+  fold the position in, budgets are counted); the tokens are chosen on the
+  device between the picks in flight and what the host supplies
+  (``_choose``). An EOS is seen one step late: the slot's extra row writes
+  behind its last valid row and its pick is dropped (docs/SERVING.md).
 - **Speculative decoding** (``speculate_k > 0``, ``serve/speculative.py``):
   each step becomes a verify step — every occupied slot feeds its pending
   token plus up to ``k`` lookahead tokens (un-ingested prompt tail first,
@@ -687,6 +695,26 @@ def _pick_one(logits, base_key, position, temperature, *, sample, top_k, top_p):
     )[0]
 
 
+@jax.jit
+def _choose(host, device, take):
+    """(N,) tokens, row by row: ``device`` where ``take``, else ``host``. The
+    one small program of the step kept in flight: a pool step's input tokens
+    out of the picks still on the device and the tokens the host knows (a
+    prompt tail's, a new admission's), and two sampling groups' picks into
+    one vector."""
+    return jnp.where(take, device, host)
+
+
+def _request_key(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)``'s words, made on the host: the jitted
+    seeding is a device program whose fetch would wait for everything
+    enqueued before it, a prefill and the step in flight."""
+    if jax.config.jax_default_prng_impl != "threefry2x32":
+        return np.asarray(jax.random.PRNGKey(seed))
+    high = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([high, seed & 0xFFFFFFFF], np.uint32)
+
+
 @dataclasses.dataclass
 class _Pending:
     """One queued (not-yet-admitted) request."""
@@ -741,6 +769,9 @@ class _Active:
     drafted: int = 0
     accepted: int = 0
     forwards: int = 0          # target-model decode forwards this request rode
+    # Plain pool steps enqueued for this slot whose picks are not bookkept
+    # yet: the next one feeds position ``pos + sent``.
+    sent: int = 0
     # Prefix cache: whether this request participates (per-request
     # "cache_prefix": false opts out of BOTH reading and feeding the trie)
     # and how many prompt positions were restored from stored blocks
@@ -776,6 +807,34 @@ class _Active:
     @property
     def trace_id(self) -> "str | None":
         return None if self.span_root is None else self.span_root.ctx.trace_id
+
+    @property
+    def last_feed(self) -> int:
+        """The position whose step picks the request's last token by its
+        budget (the last prompt token's where it asks for one or none): known
+        without any pick, unlike an EOS."""
+        return self.prompt_len + max(self.max_new, 1) - 2
+
+    @property
+    def spent(self) -> bool:
+        """Its budget's last step is already in flight: whatever row a
+        further step runs for it is dropped."""
+        return self.sent > 0 and self.pos + self.sent > self.last_feed
+
+
+@dataclasses.dataclass
+class _Flight:
+    """One pool step that is enqueued and whose picks are still on the
+    device."""
+
+    pairs: list                # (slot, _Active) it stepped, as at its dispatch
+    positions: np.ndarray      # (N,) the position each row fed
+    picks: jax.Array           # (N,) every sampling group's picks
+    dropped: int               # rows run for a slot whose budget ended a step before
+    ahead: bool                # dispatched while an earlier step was unfetched
+    # Every _MOE_READ_EVERY steps: (a copy of the expert counts as this step
+    # left them, the rows every step up to this one fed).
+    moe: "tuple | None" = None
 
 
 class SlotPool:
@@ -1042,8 +1101,9 @@ class ContinuousScheduler:
         # slot holds for them (constants, beside the expert counts). A dropless
         # expert model on the fused step: picks held here and experts hit,
         # accumulated on the device in the pool pytree (the pool programs
-        # return logits and pools, nothing else) and fetched every
-        # _MOE_READ_EVERY steps, after the step's own sync.
+        # return logits and pools, nothing else), copied every
+        # _MOE_READ_EVERY steps at a step's dispatch and fetched once that
+        # step's picks are.
         self._band = next(
             (k.window for k in cfg.attention_kinds if k.window), 0
         )
@@ -1064,7 +1124,9 @@ class ContinuousScheduler:
         if self._moe_layer is not None:
             self.pool.caches[self._moe_layer][MOE_COUNTS] = jnp.zeros((4,), jnp.int32)
         self._moe_read = np.zeros((4,), np.int64)  # the device's totals at the last fetch
-        self._moe_unread = [0, 0]  # steps and stepped slots since then
+        # Steps enqueued since the counts were last copied for a fetch; rows
+        # those steps fed, ever, and as of the last copy that was fetched.
+        self._moe_uncopied = self._moe_rows = self._moe_rows_read = 0
         self._kernel_interpret = jax.default_backend() != "tpu"
         # ---- program dispatch: module-level jits or sharded twins ---------
         # Unsharded schedulers dispatch the module-level programs (shared
@@ -1119,6 +1181,12 @@ class ContinuousScheduler:
         self.num_slots = num_slots
         self._free = list(range(num_slots))
         self._active: dict[int, _Active] = {}
+        # Plain pool steps enqueued and not fetched yet, oldest first: one
+        # between two calls of step(), two inside a call that found none;
+        # and the newest one's picks, the next step's input where a slot
+        # goes on decoding.
+        self._flights: deque[_Flight] = deque()
+        self._picks = jnp.zeros((num_slots,), jnp.int32)
         self._queue: deque[_Pending] = deque()
         self._done: dict[int, dict] = {}
         self._next_order = 0
@@ -1245,6 +1313,16 @@ class ContinuousScheduler:
                 "serve_errors_total", "requests answered with an error")
             self._m_steps = reg.counter(
                 "serve_steps_total", "pool decode steps executed")
+            self._m_steps_ahead = reg.counter(
+                "serve_steps_ahead_total",
+                "pool decode steps enqueued while an earlier one was still "
+                "unfetched (the device never waited for the host there)")
+            self._m_oversteps = reg.counter(
+                "serve_oversteps_total",
+                "rows a pool decode step ran for a slot that had ended in "
+                "the step before it (an EOS or a budget seen one step late, "
+                "a cancellation, an expiry, a preemption); their picks are "
+                "dropped")
             self._m_tokens = reg.counter(
                 "serve_generated_tokens_total", "tokens emitted to clients")
             self._m_queue_s = reg.histogram(
@@ -1541,25 +1619,36 @@ class ContinuousScheduler:
         # admissions and break the alias <= hit invariant.
         return aliased
 
-    def _paged_prepare(self, width: int) -> None:
-        """Before a paged step: every occupied slot needs blocks covering
-        its write range ``[pos, pos + width)``, CoW-split where shared.
-        Pool exhaustion (after the spill ladder) preempts the REQUESTING
-        slot with a structured ``resource`` answer carrying its partial
-        continuation — bounded degradation, never a corrupted neighbor."""
+    def _paged_prepare(self, width: int) -> bool:
+        """Before a paged step is enqueued: every occupied slot needs blocks
+        covering its write range ``[at, at + width)``, CoW-split where
+        shared, with ``at`` the position that step feeds (``pos`` plus the
+        steps in flight). Pool exhaustion (after the spill ladder) preempts
+        the REQUESTING slot with a structured ``resource`` answer carrying
+        its partial continuation — bounded degradation, never a corrupted
+        neighbor. That is decided only with nothing in flight, where every
+        finished slot has given its blocks back: a step ahead of one in
+        flight is simply not enqueued (False), and the next call asks again
+        from there."""
         from transformer_tpu.kernels.kv_pool import KVPoolExhausted
 
         for slot, st in list(self._active.items()):
+            if st.spent:
+                continue  # the row writes nothing kept
+            at = st.pos + st.sent
             try:
-                self._paged_ensure(slot, st.pos + width)
-                self._paged_cow(slot, st.pos, st.pos + width)
+                self._paged_ensure(slot, at + width)
+                self._paged_cow(slot, at, at + width)
             except KVPoolExhausted as e:
+                if self._flights:
+                    return False
                 self.stats["kv_preempted"] += 1
                 self._abort(
                     slot, st, "resource",
                     f"kv pool exhausted after {len(st.emitted)} of "
                     f"{st.max_new} tokens: {e}",
                 )
+        return True
 
     def _paged_gauges(self) -> None:
         if self.paged and self._tel is not None:
@@ -2132,7 +2221,11 @@ class ContinuousScheduler:
                 f"(serve_max_total) is {self.max_total}; shorten the prompt "
                 "or raise --serve_max_total"
             )
-        max_new = min(max_new, self.max_total - 1 - L)
+        # A slot holds ``max_total`` tokens, prompt and answer together. The
+        # answer's last token is never fed, so the last position written is
+        # ``L + max_new - 2``, and the row a step in flight runs past a
+        # budget's end lands on ``L + max_new - 1``: both inside the slot.
+        max_new = min(max_new, self.max_total - L)
         deadline = None
         if req.get("deadline_ms") is not None:
             # float() raising (e.g. "soon") answers a validation error for
@@ -2322,7 +2415,7 @@ class ContinuousScheduler:
         st = _Active(
             order=order, ids=ids, prompt_len=L, pos=n, cur=PAD_ID,
             emitted=[], max_new=max_new,
-            key=np.asarray(jax.random.PRNGKey(seed)),
+            key=_request_key(seed),
             sample=sample, temperature=temperature, top_k=top_k, top_p=top_p,
             seed=seed, spec=spec,
             use_prefix=use_prefix, prefix_hit=m, wv=self.weight_version,
@@ -2418,7 +2511,7 @@ class ContinuousScheduler:
         ) as step_span:
             with self._tracer.span("step.prepare", lane="scheduler") as sp:
                 preempted = self.stats["kv_preempted"]
-                self._step_prepare()
+                roomy = self._step_prepare()
                 sp.set(preempted=self.stats["kv_preempted"] - preempted)
             # The slots this step feeds (prepare may have expired some).
             step_span.set(active=len(self._active))
@@ -2430,18 +2523,28 @@ class ContinuousScheduler:
             elif self.speculate_k:
                 self._step_verify(step_span)
             else:
-                self._step_plain(step_span)
+                self._step_plain(step_span, roomy)
 
-    def _step_prepare(self) -> None:
+    def _step_prepare(self) -> bool:
+        """What comes before a step is enqueued; False where the paged pool
+        has no block for a step ahead of the one in flight."""
         self._expire(time.perf_counter())
+        if not self._active:
+            # A drained pool drops the picks of a step still in flight: every
+            # slot it stepped has answered.
+            self._flights.clear()
         # The step-boundary weight flip: no-op unless a verified stage is
-        # pending AND the expiry sweep just drained the last slot.
+        # pending AND the expiry sweep just drained the last slot (so nothing
+        # is in flight either).
         self._maybe_swap()
         if self._active and self.paged:
             # Paged capacity pass BEFORE the step arrays are built: a
             # pool-exhausted slot is preempted here (answered "resource")
             # and must not be stepped.
-            self._paged_prepare(self.speculate_k + 1 if self.speculate_k else 1)
+            return self._paged_prepare(
+                self.speculate_k + 1 if self.speculate_k else 1
+            )
+        return True
 
     def _step_publish(self) -> None:
         """The end of every step: gauges, the periodic sinks, the SLO tick."""
@@ -2475,64 +2578,45 @@ class ContinuousScheduler:
             toks, self.cfg,
         )
 
-    def _step_plain(self, step_span) -> None:
+    def _step_plain(self, step_span, roomy: bool) -> None:
+        """One call of the plain decode loop: enqueue the step AFTER the one
+        in flight, then fetch and bookkeep the one in flight, so the device
+        has its next step queued while the host does everything else. What
+        that step feeds is known without the picks in flight: positions go
+        up by one whatever was picked, a slot that goes on decoding takes its
+        token from those picks on the device (``_choose``), a prompt tail
+        and a new admission bring theirs from the host. What is not known is
+        an EOS: its slot runs one row too many, whose pick is dropped and
+        whose write lands behind the slot's last valid row. With nothing in
+        flight the call enqueues two steps, so every call retires one."""
         t_step = time.perf_counter()
         span = partial(self._tracer.span, lane="scheduler")
-        with span("step.build"):
-            N = self.num_slots
-            toks = np.full((N,), PAD_ID, np.int32)
-            keys = np.zeros((N, *np.shape(jax.random.PRNGKey(0))), np.uint32)
-            positions = np.zeros((N,), np.int32)
-            temps = np.ones((N,), np.float32)
-            groups: dict[tuple, list[int]] = {}
-            for slot, st in self._active.items():
-                toks[slot] = st.cur
-                keys[slot] = st.key
-                positions[slot] = st.pos
-                temps[slot] = st.temperature
-                groups.setdefault(
-                    (st.sample, st.top_k, st.top_p), []
-                ).append(slot)
-            if self.cfg.layer_pattern:
-                # Positions this step's attention reads: all of a slot's on a
-                # full layer, the band's on a window layer.
-                lengths = positions[list(self._active)].astype(np.int64) + 1
-                attended = {"attn_pos_full": int(lengths.sum())}
-                if self._band:
-                    attended["attn_pos_band"] = int(
-                        np.minimum(lengths, self._band).sum()
-                    )
-                step_span.set(**attended)
-            # Only what the pool step reads is copied before it is enqueued.
-            d_toks, d_positions = jnp.asarray(toks), jnp.asarray(positions)
-            table = self.pool.alloc.table_device() if self.paged else None
-        picks: dict[int, int] = {}
-        # One dispatch/fetch pair per sampling group; the first dispatch also
-        # enqueues the pool step, and copies the picks' inputs in AFTER it,
-        # while the device is already at work (a small host-to-device copy
-        # costs the host a quarter of a millisecond on the chip). A dispatch
-        # returns at enqueue, the fetch is the wait for the device.
-        for i, ((sample, top_k, top_p), slots) in enumerate(groups.items()):
-            with span("step.dispatch", programs=1 if i else 2):
-                if i == 0:
-                    logits, self.pool.caches = self._dispatch_pool_step(
-                        table, d_positions, d_toks
-                    )
-                    d_keys, d_temps = jnp.asarray(keys), jnp.asarray(temps)
-                pick = _pick_pool(
-                    logits, d_keys, d_positions, d_temps,
-                    sample=sample, top_k=top_k, top_p=top_p,
-                )
-            with span("step.fetch"):
-                out = np.asarray(pick)
-            for slot in slots:
-                picks[slot] = int(out[slot])
+        ahead = bool(self._flights)
+        if roomy:
+            self._enqueue_step(span)
+        if not ahead:
+            with span("step.prepare"):
+                roomy = not self.paged or self._paged_prepare(1)
+            if roomy:
+                self._enqueue_step(span)
+        flight = self._flights.popleft()
+        # The one wait for the device of the call: for the step before the
+        # one just enqueued, every sampling group's picks in one vector.
+        with span("step.fetch"):
+            out = np.asarray(flight.picks)
         with span("step.bookkeep") as sp:
             emitted = continued = walked = 0
-            stepped = len(self._active)
-            if self._moe_layer is not None:
-                self._read_moe_counts(step_span, stepped)
-            for slot, st in list(self._active.items()):
+            before = len(self._active)
+            live = []
+            for slot, st in flight.pairs:
+                if self._active.get(slot) is not st:
+                    # Ended since this step's dispatch (an EOS or a budget in
+                    # the step before, a cancellation, an expiry, a
+                    # preemption): the pick is nobody's, least of all the
+                    # slot's next occupant's.
+                    continue
+                live.append(slot)
+                st.sent -= 1
                 st.pos += 1
                 st.forwards += 1
                 if st.pos < st.prompt_len:
@@ -2549,32 +2633,137 @@ class ContinuousScheduler:
                     st.t_prefill = time.perf_counter()
                     self._trace_prefill_done(st)
                 first = not st.emitted
-                if self._consume_pick(slot, st, picks[slot]):
+                if self._consume_pick(slot, st, int(out[slot])):
                     emitted += 1
                     continued += not first
+            overstepped = flight.dropped + len(flight.pairs) - len(live)
+            if self.cfg.layer_pattern:
+                # Positions this step's attention read: all of a slot's on a
+                # full layer, the band's on a window layer.
+                lengths = flight.positions[live].astype(np.int64) + 1
+                attended = {"attn_pos_full": int(lengths.sum())}
+                if self._band:
+                    attended["attn_pos_band"] = int(
+                        np.minimum(lengths, self._band).sum()
+                    )
+                step_span.set(**attended)
+            if flight.moe is not None:
+                self._read_moe_counts(step_span, *flight.moe)
             self.stats["steps"] += 1
             if self._tel is not None:
-                # The np.asarray(pick) above was a real device sync, so this
-                # window is genuine step time, not dispatch time.
+                # The fetch above was a real device sync, so this window is
+                # genuine step time, not dispatch time.
                 dt_step = time.perf_counter() - t_step
                 self._m_step_s.observe(dt_step)
                 self._m_steps.inc()
+                if flight.ahead:
+                    self._m_steps_ahead.inc()
+                if overstepped:
+                    self._m_oversteps.inc(overstepped)
+            sp.set(retired=before - len(self._active))
+            if not self._active:
+                self._flights.clear()  # as in _step_prepare
             self._step_publish()
-            sp.set(retired=stepped - len(self._active))
-        # Slots that produced an output token, those of them for which it
-        # was not the first, and slots that only consumed a prompt-tail token.
-        step_span.set(emitted=emitted, continued=continued, walked=walked)
+        # All of the step just fetched: the slots it stepped that were still
+        # theirs; of those, the ones that produced an output token, the ones
+        # for which it was not the first, and the ones that only consumed a
+        # prompt-tail token; whether it was enqueued ahead; rows run and
+        # dropped.
+        step_span.set(
+            active=len(live), emitted=emitted, continued=continued,
+            walked=walked, ahead=int(flight.ahead), overstepped=overstepped,
+        )
 
-    def _read_moe_counts(self, step_span, stepped: int) -> None:
+    def _enqueue_step(self, span) -> None:
+        """Build and dispatch one pool step over the occupied slots, each at
+        ``pos`` plus its steps in flight; nothing here waits for the device.
+        No step where every occupied slot's budget ends in flight."""
+        with span("step.build"):
+            N = self.num_slots
+            toks = np.full((N,), PAD_ID, np.int32)
+            take = np.zeros((N,), bool)  # rows fed the pick in flight
+            keys = np.zeros((N, *_request_key(0).shape), np.uint32)
+            positions = np.zeros((N,), np.int32)
+            temps = np.ones((N,), np.float32)
+            groups: dict[tuple, list[int]] = {}
+            pairs = []
+            dropped = 0
+            for slot, st in self._active.items():
+                at = st.pos + st.sent
+                take[slot] = st.sent and at >= st.prompt_len
+                if st.spent:
+                    # Its budget ends in the step in flight. The row runs all
+                    # the same (one fixed shape), where an over-stepped EOS
+                    # would: behind the last valid row of its own blocks, or
+                    # in the sink where none is mapped. Position 0 would
+                    # overwrite a row that _finish is about to publish.
+                    positions[slot] = min(at, self.max_total - 1)
+                    dropped += 1
+                    continue
+                if at < st.prompt_len:
+                    toks[slot] = st.ids[at]  # the prompt tail
+                elif not st.sent:
+                    toks[slot] = st.cur  # picked at admission, or bookkept
+                keys[slot] = st.key
+                positions[slot] = at
+                temps[slot] = st.temperature
+                groups.setdefault(
+                    (st.sample, st.top_k, st.top_p), []
+                ).append(slot)
+                pairs.append((slot, st))
+            if not pairs:
+                return
+            d_positions = jnp.asarray(positions)
+            d_toks, d_take = jnp.asarray(toks), jnp.asarray(take)
+            table = self.pool.alloc.table_device() if self.paged else None
+        # The pool step goes out first and the picks' inputs are copied in
+        # AFTER it, while the device is already at work (a small
+        # host-to-device copy costs the host a quarter of a millisecond on
+        # the chip); then one pick a sampling group, merged into one vector.
+        with span("step.dispatch", programs=2 * len(groups) + 1):
+            logits, self.pool.caches = self._dispatch_pool_step(
+                table, d_positions, _choose(d_toks, self._picks, d_take)
+            )
+            moe = None
+            if self._moe_layer is not None:
+                self._moe_uncopied += 1
+                self._moe_rows += len(pairs) + dropped
+                if self._moe_uncopied >= _MOE_READ_EVERY:
+                    # The pool is donated to the next step: what is read
+                    # later has to be a copy made before that one goes out.
+                    moe = (
+                        jnp.copy(self.pool.caches[self._moe_layer][MOE_COUNTS]),
+                        self._moe_rows,
+                    )
+                    self._moe_uncopied = 0
+            d_keys, d_temps = jnp.asarray(keys), jnp.asarray(temps)
+            picks = None
+            for (sample, top_k, top_p), slots in groups.items():
+                pick = _pick_pool(
+                    logits, d_keys, d_positions, d_temps,
+                    sample=sample, top_k=top_k, top_p=top_p,
+                )
+                if picks is None:
+                    picks = pick
+                else:
+                    mine = np.zeros((N,), bool)
+                    mine[slots] = True
+                    picks = _choose(picks, pick, jnp.asarray(mine))
+            self._picks = picks
+        for _, st in pairs:
+            st.sent += 1
+        self._flights.append(
+            _Flight(pairs, positions, picks, dropped, bool(self._flights), moe)
+        )
+
+    def _read_moe_counts(self, step_span, counts, rows: int) -> None:
         """Every ``_MOE_READ_EVERY`` plain steps: one small fetch of the
-        expert layers' counts (the step's own fetch has just synced, so it
-        waits for nothing), onto the step's span and into the registry as
-        what was added since the last fetch."""
-        self._moe_unread[0] += 1
-        self._moe_unread[1] += stepped
-        if self._moe_unread[0] < _MOE_READ_EVERY:
-            return
-        total = np.asarray(self.pool.caches[self._moe_layer][MOE_COUNTS], np.int64)
+        expert layers' counts as that step left them (a copy made at its
+        dispatch, and its picks have just been fetched, so it waits for
+        nothing), onto the step's span and into the registry as what was
+        added since the last fetch; ``rows`` is what the steps up to that one
+        fed (a slot that had ended a step before is a row to the device too)."""
+        total = np.asarray(counts, np.int64)
         # The device's int32 totals are never reset and wrap (after hours of
         # serving): what was added since the last fetch is the difference
         # modulo 2**32, far above what 32 steps can add.
@@ -2584,13 +2773,13 @@ class ContinuousScheduler:
         self._moe_read = total
         step_span.set(
             moe_assign=assign, moe_hit=hit, moe_max_load=max_load,
-            moe_steps=steps, moe_tokens=self._moe_unread[1],
+            moe_steps=steps, moe_tokens=rows - self._moe_rows_read,
         )
+        self._moe_rows_read = rows
         if self._state_layers:
             step_span.set(
                 state_layers=self._state_layers, state_bytes=self._state_bytes
             )
-        self._moe_unread = [0, 0]
         if self._tel is not None:
             self._m_moe_assign.inc(assign)
             self._m_moe_hit.inc(hit)
