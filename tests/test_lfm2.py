@@ -401,7 +401,6 @@ def test_step_spans_count_positions_the_most_loaded_expert_and_the_state(cfg, pa
         layers, held, top_k = 6, 8, 2
         assert s["moe_assign"] == s["moe_tokens"] * layers * top_k  # every expert is here: no pick falls elsewhere
         assert s["moe_assign"] / held <= s["moe_max_load"] <= s["moe_tokens"] * layers
-        assert (s["state_layers"], s["state_bytes"]) == (6, 6 * 2 * 64 * 4)
     reg = tel.registry
     assert reg.counter("serve_moe_max_load_total").value == sum(s["moe_max_load"] for s in read)
     assert reg.gauge("serve_state_layers", "").value == 6

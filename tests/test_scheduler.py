@@ -354,7 +354,16 @@ def test_step_spans_phases_and_counts(lm, kw):
             ["step.build", "step.dispatch", "step.prepare", "step.build",
              "step.dispatch"],
         ), names
-        assert step["ahead"] == (names.count("step.prepare") == 1)
+        # The step fetched was enqueued with the device at work, or says
+        # why not: a call that found nothing in flight fetches the first of
+        # its own two; an earlier call's step is ahead unless an admission's
+        # first pick had waited for the device before it.
+        assert step["ahead"] == ("drain" not in step)
+        if names.count("step.prepare") == 2:
+            assert step["drain"] in ("idle", "spent", "no_block")
+        else:
+            assert step.get("drain", "first_pick") == "first_pick"
+        assert step["prefill_tokens"] >= step["prefills"] >= 0
         assert step["continued"] <= step["emitted"]
         assert step["emitted"] + step["walked"] <= step["active"]
         if not ended_early:
@@ -845,3 +854,182 @@ def test_next_step_is_enqueued_before_the_fetch(lm, layout):
         # Two enqueued where nothing was in flight, one ever after, none
         # once every budget ends in the step in flight.
         assert len(dispatches) == (2 if i == 0 else 1) or i >= len(steps) - 2
+
+
+# --------------------------------------------------------------------------
+# what lies on the device's queue between two steps: the prefills a step's
+# span counts, and why a step was not enqueued ahead (counts and strings,
+# never durations)
+
+two_layouts = pytest.mark.parametrize(
+    "layout", [LAYOUTS["dense"], LAYOUTS["paged"]], ids=["dense", "paged"]
+)
+
+
+def _steps_of(spans):
+    return sorted(
+        (s for s in spans if s["name"] == "scheduler.step"),
+        key=lambda s: s["t0_mono"],
+    )
+
+
+def _step_spans():
+    from transformer_tpu.obs.trace import buffer
+
+    return _steps_of(buffer().snapshot())
+
+
+def _finish(sched):
+    return [o.get("continuation") for o in sched.run([])]
+
+
+@two_layouts
+@pytest.mark.parametrize("k", [1, 3])
+def test_step_span_counts_the_prefills_enqueued_before_it(lm, layout, k):
+    """After k admissions between two steps the next step fetched carries
+    ``prefills == k`` and the sum of their prefilled tokens, the one after
+    carries 0; the first step of a run found the device idle."""
+    from transformer_tpu.obs.trace import buffer
+
+    params, cfg, tok = lm
+    first = {"prompt": _whole_prompt(tok) + " ij kl", "max_new": 12}
+    late = [
+        {"prompt": _whole_prompt(tok) + " mn", "max_new": 6},
+        {"prompt": "ab cd", "max_new": 5, "temperature": 0.9, "seed": 3},
+        {"prompt": _whole_prompt(tok) + " ab cd ef", "max_new": 4},
+    ][:k]
+    want = _sequential(params, cfg, tok, [first, *late])
+    buffer().clear()
+    sched = ContinuousScheduler(params, cfg, tok, num_slots=4, **layout)
+    sched.submit(dict(first))
+    sched.admit()
+    sched.step()  # enqueues two, fetches the first
+    sched.step()
+    for r in late:
+        sched.submit(dict(r))
+    sched.admit()  # k prefills behind the step in flight
+    assert sched._prefills_queued == k
+    for _ in range(3):
+        sched.step()
+    assert sched._prefills_queued == sched._prefill_tokens_queued == 0
+    steps = _step_spans()
+    assert len(steps) == 5
+    fed = [
+        prefill_len_for(_tokens(tok, r["prompt"])) for r in [first, *late]
+    ]
+    admits = [
+        s["prefill_tokens"] for s in buffer().snapshot()
+        if s["name"] == "serve.admit"
+    ]
+    assert sorted(admits) == sorted(fed)
+    assert [s["prefills"] for s in steps] == [1, 0, 0, k, 0]
+    assert [s["prefill_tokens"] for s in steps] == [fed[0], 0, 0, sum(fed[1:]), 0]
+    assert [s["ahead"] for s in steps] == [0, 1, 1, 1, 1]
+    assert steps[0]["drain"] == "idle"
+    assert all("drain" not in s for s in steps[1:])
+    assert _finish(sched) == want
+    later = _step_spans()[5:]
+    assert later and all(s["prefills"] == 0 for s in later)
+
+
+@two_layouts
+def test_first_pick_drains_the_flight(lm, layout):
+    """A prompt of a power of two of tokens is prefilled whole and its first
+    pick waits for the device, the step in flight included: the step
+    enqueued next is not ahead, says ``first_pick``, and
+    ``serve_steps_ahead_total`` does not count it."""
+    from transformer_tpu.obs import Telemetry
+    from transformer_tpu.obs.trace import buffer
+
+    params, cfg, tok = lm
+    reqs = [
+        {"prompt": _whole_prompt(tok) + " ij", "max_new": 12},
+        {"prompt": _whole_prompt(tok), "max_new": 6},
+    ]
+    want = _sequential(params, cfg, tok, reqs)
+    buffer().clear()
+    tel = Telemetry()
+    sched = ContinuousScheduler(
+        params, cfg, tok, num_slots=2, telemetry=tel, **layout
+    )
+    sched.submit(dict(reqs[0]))
+    sched.admit()
+    sched.step()
+    sched.step()
+    assert sched._flights and sched._drain is None
+    sched.submit(dict(reqs[1]))
+    sched.admit()
+    assert sched._drain == "first_pick"
+    sched.step()  # enqueues the drained step, fetches the one before it
+    assert sched._drain is None
+    sched.step()
+    steps = _step_spans()
+    assert [s["ahead"] for s in steps] == [0, 1, 1, 0]
+    assert [s.get("drain") for s in steps] == ["idle", None, None, "first_pick"]
+    assert [s["prefills"] for s in steps] == [1, 0, 0, 1]
+    assert _finish(sched) == want
+    steps = _step_spans()
+    assert all(s["ahead"] == ("drain" not in s) for s in steps)
+    assert tel.registry.counter("serve_steps_ahead_total").value == sum(
+        s["ahead"] for s in steps
+    ) == len(steps) - 2
+    # With nothing in flight the first pick drains nothing: the pool was
+    # idle (a serve loop's poll of the empty pool forgets the last cause).
+    sched.step()
+    buffer().clear()
+    sched.run([dict(reqs[1])])
+    assert _step_spans()[0]["drain"] == "idle"
+
+
+@two_layouts
+def test_budgets_that_all_end_in_flight_say_spent(lm, layout):
+    """Every occupied slot's last step is in flight: the call enqueues
+    nothing, and the step that feeds the slot's next occupant says so."""
+    params, cfg, tok = lm
+    reqs = [
+        {"prompt": "ab cd ef", "max_new": 4},
+        {"prompt": "kl mn ab", "max_new": 3},
+    ]
+    want = _sequential(params, cfg, tok, reqs)
+    out, spans, _ = _buffered_run(lm, reqs, num_slots=1, **layout)
+    assert [o["continuation"] for o in out] == want
+    steps = _steps_of(spans)
+    drains = [s["drain"] for s in steps if not s["ahead"]]
+    assert drains == ["idle", "spent"]
+    # The step that says it is the first the second request rode.
+    (spent,) = [s for s in steps if s.get("drain") == "spent"]
+    assert spent["prefills"] == 1
+    # The call that enqueued nothing built a step and dispatched none.
+    quiet = [
+        s for s in steps
+        if [k["name"] for k in _children(spans, s)].count("step.dispatch") == 0
+    ]
+    assert len(quiet) == 2  # one a request's end
+
+
+@pytest.mark.parametrize(
+    "layout", [LAYOUTS["paged"], LAYOUTS["paged_flash"]],
+    ids=["paged", "paged_flash"],
+)
+def test_no_block_to_step_ahead_says_no_block(lm, layout):
+    """The pool of ``test_pool_exhaustion_beside_a_step_in_flight``: the call
+    that found no block enqueued nothing, so the next step found the device
+    drained and names the cause."""
+    reqs = [
+        {"prompt": "ab cd ef gh ij kl", "max_new": 14},
+        {"prompt": "mn ef cd ab kl ij", "max_new": 14},
+    ]
+    out, spans, _ = _buffered_run(
+        lm, reqs, num_slots=2, max_total=32, admission_retries=0,
+        **{**layout, "kv_pool_blocks": 6},
+    )
+    assert "resource" in [o.get("code") for o in out]
+    steps = _steps_of(spans)
+    drains = [s["drain"] for s in steps if not s["ahead"]]
+    assert drains[0] == "idle" and "no_block" in drains
+    assert set(drains) <= {"idle", "no_block", "spent"}
+    # The step before a no_block one was fetched by a call that dispatched
+    # nothing.
+    i = next(i for i, s in enumerate(steps) if s.get("drain") == "no_block")
+    names = [k["name"] for k in _children(spans, steps[i - 1])]
+    assert "step.dispatch" not in names

@@ -148,7 +148,6 @@ from transformer_tpu.ops.attention import (
     kv_buffer_keys,
     slice_kv_blocks,
 )
-from transformer_tpu.ops.kda import CHUNK as KDA_CHUNK
 from transformer_tpu.ops.mla import latent_width
 from transformer_tpu.ops.short_conv import state_buffer_keys
 from transformer_tpu.serve.resilience import (
@@ -825,13 +824,22 @@ class _Active:
 @dataclasses.dataclass
 class _Flight:
     """One pool step that is enqueued and whose picks are still on the
-    device."""
+    device. ``drain``, where the step was not ``ahead``: ``idle`` (nothing was
+    in flight: the pool had been empty), ``no_block`` (the call before found
+    no block to step ahead, ``_paged_prepare``), ``spent`` (the call before
+    enqueued nothing: every occupied slot's budget ended in flight) or
+    ``first_pick`` (an admission's first pick waited for its prefill and so
+    for the step in flight, and the device stood empty while the host
+    finished the admission and built this step)."""
 
     pairs: list                # (slot, _Active) it stepped, as at its dispatch
     positions: np.ndarray      # (N,) the position each row fed
     picks: jax.Array           # (N,) every sampling group's picks
     dropped: int               # rows run for a slot whose budget ended a step before
-    ahead: bool                # dispatched while an earlier step was unfetched
+    ahead: bool                # the device had work queued when it was enqueued
+    drain: "str | None"        # where not ahead, why the device had none
+    prefills: int              # prefills enqueued between the step before and it
+    prefill_tokens: int        # the tokens those fed
     # Every _MOE_READ_EVERY steps: (a copy of the expert counts as this step
     # left them, the rows every step up to this one fed).
     moe: "tuple | None" = None
@@ -1098,7 +1106,7 @@ class ContinuousScheduler:
         # The positions its full layers attend and, with a windowed kind,
         # those its window layers do (host arithmetic over the step's
         # positions); the layers whose state is no KV rows and the bytes a
-        # slot holds for them (constants, beside the expert counts). A dropless
+        # slot holds for them (constants of a run: two gauges). A dropless
         # expert model on the fused step: picks held here and experts hit,
         # accumulated on the device in the pool pytree (the pool programs
         # return logits and pools, nothing else), copied every
@@ -1112,9 +1120,6 @@ class ContinuousScheduler:
             x.size * x.dtype.itemsize
             for i in cfg.state_layers
             for x in jax.eval_shape(lambda i=i: init_layer_state(cfg, i, 1)).values()
-        )
-        self._kda_layers = sum(
-            cfg.layer_kind(i).mixer == "kda" for i in cfg.state_layers
         )
         self._moe_layer = None
         if decode_kernel == "paged_flash" and cfg.moe_dispatch == "dropless":
@@ -1187,6 +1192,14 @@ class ContinuousScheduler:
         # goes on decoding.
         self._flights: deque[_Flight] = deque()
         self._picks = jnp.zeros((num_slots,), jnp.int32)
+        # What the device's queue holds behind the newest pool step: the
+        # prefills dispatched since that step was enqueued and the tokens
+        # they feed (host ints, moved onto the next _Flight); and, where the
+        # next step will find the device drained for another cause than an
+        # empty pool, that cause (_Flight.drain).
+        self._prefills_queued = 0
+        self._prefill_tokens_queued = 0
+        self._drain: "str | None" = None
         self._queue: deque[_Pending] = deque()
         self._done: dict[int, dict] = {}
         self._next_order = 0
@@ -1315,8 +1328,9 @@ class ContinuousScheduler:
                 "serve_steps_total", "pool decode steps executed")
             self._m_steps_ahead = reg.counter(
                 "serve_steps_ahead_total",
-                "pool decode steps enqueued while an earlier one was still "
-                "unfetched (the device never waited for the host there)")
+                "pool decode steps enqueued while the device still had work "
+                "queued (an earlier step unfetched and nothing waited for "
+                "since: the device never waited for the host there)")
             self._m_oversteps = reg.counter(
                 "serve_oversteps_total",
                 "rows a pool decode step ran for a slot that had ended in "
@@ -2407,6 +2421,10 @@ class ContinuousScheduler:
         self.stats["prefill_forwards"] += (
             -(-n_suffix // chunk) if chunk > 0 else 1
         )
+        # On the device's queue behind the newest pool step (a restore is no
+        # prefill); the next step enqueued takes both.
+        self._prefills_queued += 1
+        self._prefill_tokens_queued += n_suffix
         if m and self._tel is not None and self.prefix_cache is not None:
             self._m_prefix_hit.inc(m)
             if aliased:
@@ -2439,9 +2457,6 @@ class ContinuousScheduler:
             slot=slot, prefix_hit_tokens=st.prefix_hit, prompt_tokens=L,
             prefill_tokens=n_suffix,
         )
-        if self._kda_layers:
-            # Chunks the prefill's delta-rule scans walked, over its layers.
-            p.span_admit.set(kda_chunks=self._kda_layers * -(-n_suffix // KDA_CHUNK))
         if deadline is not None and time.perf_counter() >= deadline:
             # Prefill-boundary deadline check: the prompt ingest alone
             # consumed the budget — answer now instead of decoding tokens
@@ -2471,6 +2486,10 @@ class ContinuousScheduler:
                             sample=st.sample, top_k=st.top_k, top_p=st.top_p,
                         )
                     )
+                if self._flights:
+                    # The wait was for the step in flight too: the device is
+                    # empty until the next step is built and enqueued.
+                    self._drain = "first_pick"
             except Exception:
                 # The pick failing must not leak the slot: restore the pool
                 # so the error answers this request alone (admit() catches;
@@ -2501,7 +2520,9 @@ class ContinuousScheduler:
         pool is idle."""
         if not self._active:
             # An idle pool leaves no span: a serve loop polls it thousands
-            # of times a second.
+            # of times a second. Whatever drained the device last, the next
+            # step finds it idle.
+            self._drain = None
             self._step_prepare()
             self._step_publish()
             return
@@ -2591,14 +2612,16 @@ class ContinuousScheduler:
         flight the call enqueues two steps, so every call retires one."""
         t_step = time.perf_counter()
         span = partial(self._tracer.span, lane="scheduler")
-        ahead = bool(self._flights)
+        in_flight = bool(self._flights)
         if roomy:
             self._enqueue_step(span)
-        if not ahead:
+        if not in_flight:
             with span("step.prepare"):
                 roomy = not self.paged or self._paged_prepare(1)
             if roomy:
                 self._enqueue_step(span)
+        if not roomy:
+            self._drain = "no_block"
         flight = self._flights.popleft()
         # The one wait for the device of the call: for the step before the
         # one just enqueued, every sampling group's picks in one vector.
@@ -2667,12 +2690,17 @@ class ContinuousScheduler:
         # All of the step just fetched: the slots it stepped that were still
         # theirs; of those, the ones that produced an output token, the ones
         # for which it was not the first, and the ones that only consumed a
-        # prompt-tail token; whether it was enqueued ahead; rows run and
-        # dropped.
+        # prompt-tail token; whether the device had work queued when it was
+        # enqueued (where not, why); rows run and dropped; the prefills that
+        # lie on the device's queue between the step before and it (always
+        # set: 0 is none, absent an older program) and the tokens they fed.
         step_span.set(
             active=len(live), emitted=emitted, continued=continued,
             walked=walked, ahead=int(flight.ahead), overstepped=overstepped,
+            prefills=flight.prefills, prefill_tokens=flight.prefill_tokens,
         )
+        if flight.drain is not None:
+            step_span.set(drain=flight.drain)
 
     def _enqueue_step(self, span) -> None:
         """Build and dispatch one pool step over the occupied slots, each at
@@ -2712,6 +2740,7 @@ class ContinuousScheduler:
                 ).append(slot)
                 pairs.append((slot, st))
             if not pairs:
+                self._drain = "spent"
                 return
             d_positions = jnp.asarray(positions)
             d_toks, d_take = jnp.asarray(toks), jnp.asarray(take)
@@ -2752,9 +2781,17 @@ class ContinuousScheduler:
             self._picks = picks
         for _, st in pairs:
             st.sent += 1
+        # Ahead where a step is unfetched and nothing has waited for it since.
+        ahead = bool(self._flights) and self._drain is None
         self._flights.append(
-            _Flight(pairs, positions, picks, dropped, bool(self._flights), moe)
+            _Flight(
+                pairs, positions, picks, dropped, ahead,
+                None if ahead else self._drain or "idle",
+                self._prefills_queued, self._prefill_tokens_queued, moe,
+            )
         )
+        self._drain = None
+        self._prefills_queued = self._prefill_tokens_queued = 0
 
     def _read_moe_counts(self, step_span, counts, rows: int) -> None:
         """Every ``_MOE_READ_EVERY`` plain steps: one small fetch of the
@@ -2776,10 +2813,6 @@ class ContinuousScheduler:
             moe_steps=steps, moe_tokens=rows - self._moe_rows_read,
         )
         self._moe_rows_read = rows
-        if self._state_layers:
-            step_span.set(
-                state_layers=self._state_layers, state_bytes=self._state_bytes
-            )
         if self._tel is not None:
             self._m_moe_assign.inc(assign)
             self._m_moe_hit.inc(hit)
