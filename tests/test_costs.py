@@ -280,6 +280,40 @@ def test_pool_verify_donates_pool(canned):
     assert step.peak_bytes < step.arg_bytes + 2 * pool_kv
 
 
+@pytest.mark.parametrize("donated", [True, False], ids=["donated", "control"])
+def test_slot_prefill_paged_donates_pool(donated):
+    """The paged prefill's peak holds ONE pool: donated, as the program is
+    (PR 38), each layer's pool dies as the slot's rows are scattered into
+    it, so the peak stays under the arguments plus one pool; the same
+    program undonated ends holding the old pool and the new one and does
+    not. Priced at 33 blocks of 8, where the pool and not the forward's
+    temporaries makes the peak, as on a deployed chip (at the canned 5
+    blocks the two read the same)."""
+    from transformer_tpu.analysis.costs import kv_pool_bytes
+    from transformer_tpu.models.transformer import transformer_init
+    from transformer_tpu.serve.scheduler import (
+        _slot_prefill_paged,
+        abstract_paged_pool,
+    )
+
+    cfg = FAST_MATRIX["lm_bf16"]
+    blocks, block, total = 33, 8, 32
+    params = jax.eval_shape(
+        lambda k: transformer_init(k, cfg), jax.random.PRNGKey(0)
+    )
+    pool, table, _ = abstract_paged_pool(cfg, 2, total, blocks, block)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)  # noqa: E731
+    raw = _slot_prefill_paged.__wrapped__
+    r = program_costs(
+        "prefill",
+        lambda p, c, tb, s, pr, st: raw(p, c, tb, s, pr, st, cfg, 0, block, total),
+        params, pool, table, i32(), i32(1, 8), i32(),
+        donate_argnums=(1,) if donated else (),
+    )
+    one_pool = kv_pool_bytes(cfg, total, 2, blocks, block)["pool_bytes"]
+    assert (r.peak_bytes < r.arg_bytes + one_pool) == donated
+
+
 # --------------------------------------------------------------------------
 # baseline workflow
 

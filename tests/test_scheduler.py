@@ -1033,3 +1033,126 @@ def test_no_block_to_step_ahead_says_no_block(lm, layout):
     i = next(i for i, s in enumerate(steps) if s.get("drain") == "no_block")
     names = [k["name"] for k in _children(spans, steps[i - 1])]
     assert "step.dispatch" not in names
+
+
+# --------------------------------------------------------------------------
+# the paged prefill donates its pool: a slot's rows and state are written in
+# place, and a failure after the pool was handed over is the pool's
+
+DONATING_POOLS = {
+    # K/V heads of 128 lanes, as starcoder2-3b keeps them.
+    "kv128": ("sc2-3b.chat-saturated", dict(d_model=256, num_heads=2, dtype="bfloat16")),
+    # 64-wide heads two a lane row beside short-convolution state, as lfm2-8b-a1b.
+    "kv64_conv": (
+        "lfm2-8b-a1b.longform-saturated",
+        dict(num_heads=8, num_kv_heads=8, head_size=64, dtype="bfloat16"),
+    ),
+    # A latent pool beside delta-rule matrix states, as kimi-linear-48b-a3b.
+    "latent_kda": ("kimi-linear-48b-a3b.reasoning-saturated", {}),
+}
+
+
+@pytest.mark.parametrize("pool_layout", list(DONATING_POOLS))
+def test_paged_prefill_aliases_every_pool_leaf(pool_layout):
+    """Compiled from abstract shapes, the prefill's ``input_output_alias``
+    maps every leaf of its pool argument onto the pool it returns: a lost
+    donation fails here, where on the chip it costs a copy of every pool an
+    admission (PR 38)."""
+    import json
+    import os
+    import re
+
+    import jax.numpy as jnp
+
+    from transformer_tpu.serve import scheduler as S
+
+    cell_name, widths = DONATING_POOLS[pool_layout]
+    root = os.path.join(os.path.dirname(__file__), "..", "perfbench")
+    with open(os.path.join(root, "workloads", cell_name + ".json")) as f:
+        cell = json.load(f)
+    with open(os.path.join(root, "configs", cell["config"] + ".json")) as f:
+        model = json.load(f)["model"]
+    cfg = ModelConfig(**{**model, **cell["rehearse"]["model"], **widths})
+    params = jax.eval_shape(lambda k: transformer_init(k, cfg), jax.random.PRNGKey(0))
+    pool, table, _ = S.abstract_paged_pool(cfg, 2, 32, 5, 16)
+    leaves = jax.tree.leaves(pool)
+    if pool_layout == "kv64_conv":
+        assert {leaf.shape[2:] for leaf in leaves if leaf.ndim == 4} == {(4, 128)}
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    head = S._slot_prefill_paged.lower(
+        params, pool, table, scalar, jax.ShapeDtypeStruct((1, 16), jnp.int32),
+        scalar, cfg, 8, 16, 32,
+    ).compile().as_text().split("\n", 1)[0]
+    aliased = {
+        int(param) for param in re.findall(
+            r"\((\d+), \{\}, (?:may|must)-alias\)",
+            head.split("input_output_alias=", 1)[1].split("}, entry", 1)[0],
+        )
+    }
+    first = len(jax.tree.leaves(params))  # the pool's leaves follow the params'
+    assert aliased == set(range(first, first + len(leaves)))
+
+
+def _raising_after(real, n_ok: int, *, donate: bool):
+    """``real``, but for call ``n_ok + 1``, which raises, having run the real
+    program first (``donate``) or before it could be enqueued."""
+    calls = []
+
+    def prefill(params, pool_caches, *args):
+        calls.append(len(calls))
+        if len(calls) != n_ok + 1:
+            return real(params, pool_caches, *args)
+        if donate:
+            real(params, pool_caches, *args)
+            raise RuntimeError("lost after the dispatch")
+        # jit refuses an argument that is no array before it runs anything
+        return real(params, pool_caches, object(), *args[1:])
+
+    return prefill
+
+
+def test_prefill_refused_before_enqueue_answers_alone(lm):
+    """A prefill whose dispatch raises before the program is enqueued left
+    the donated pool whole: that one request answers an admission error and
+    every other one what it gets alone from ``generate``."""
+    from transformer_tpu.obs import Telemetry
+
+    params, cfg, tok = lm
+    tel = Telemetry()
+    sched = ContinuousScheduler(
+        params, cfg, tok, num_slots=2, telemetry=tel, **LAYOUTS["paged_flash"]
+    )
+    sched._fn_slot_prefill_paged = _raising_after(
+        sched._fn_slot_prefill_paged, 2, donate=False
+    )
+    got = sched.run([dict(r) for r in REQS])
+    failed = [i for i, g in enumerate(got) if "error" in g]
+    assert len(failed) == 1 and got[failed[0]]["code"] == "validation"
+    assert "TypeError" in got[failed[0]]["error"]
+    want = _sequential(params, cfg, tok, REQS)
+    assert [g.get("continuation") for g in got] == [
+        None if i in failed else w for i, w in enumerate(want)
+    ]
+    assert tel.registry.counter("serve_admit_pool_lost_total").value == 0
+    assert len(sched._free) == 2 and not sched.busy
+
+
+def test_prefill_that_took_the_pool_stops_the_scheduler(lm):
+    """A prefill that failed after its program took the donated pool leaves
+    no pool to serve from: the serving loop raises the failure instead of
+    answering it as that request's admission error, and
+    ``serve_admit_pool_lost_total`` counts it."""
+    from transformer_tpu.obs import Telemetry
+
+    params, cfg, tok = lm
+    tel = Telemetry()
+    sched = ContinuousScheduler(
+        params, cfg, tok, num_slots=2, telemetry=tel, **LAYOUTS["paged_flash"]
+    )
+    sched._fn_slot_prefill_paged = _raising_after(
+        sched._fn_slot_prefill_paged, 2, donate=True
+    )
+    with pytest.raises(RuntimeError, match="lost after the dispatch"):
+        sched.run([dict(r) for r in REQS])
+    assert tel.registry.counter("serve_admit_pool_lost_total").value == 1
+    assert not sched.drain_ready()  # nothing was answered as its error

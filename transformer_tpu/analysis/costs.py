@@ -565,6 +565,7 @@ def canned_cost_reports() -> tuple[list[CostReport], list[str]]:
                 p, c, tb, s, pr, st, cfg, 0, _PAGED_BLOCK, _SERVE_TOTAL
             ),
             params, pool, table, i32(), i32(1, _PREFILL_LEN), i32(),
+            donate_argnums=(1,),
         )
     )
 

@@ -258,12 +258,10 @@ def _slot_prefill(
     (``_slot_restore``) and prefills only the unmatched suffix from there.
     Returns ((1, V) logits for the next position, updated pool caches).
 
-    NOT donated, unlike ``_pool_step``: an execution-time failure here (e.g.
-    device OOM on a long prompt) is answered as a per-request admission
-    error and the pool keeps serving — donated inputs would already be
-    invalidated, so the next step would dereference deleted buffers and kill
-    every in-flight request. ``_pool_step`` failures are fatal anyway, so
-    the hot per-token path keeps the in-place donation win."""
+    NOT donated, unlike the paged ``_slot_prefill_paged``, so a failure of
+    the call leaves the pool whole and answers that request alone; the price
+    is a copy of the whole pool an admission. No benchmark cell serves the
+    dense layout, so it keeps that price until one does (ROADMAP S5, D1)."""
     slot_caches = jax.tree.map(lambda x: x[slot], pool_caches)
     slot_caches = [dict(c, index=jnp.asarray(start, jnp.int32)) for c in slot_caches]
     logits, slot_caches = transformer_prefill(
@@ -285,8 +283,9 @@ def _slot_restore(pool_caches, slot, blocks):
     block)), never one per hit length; zero pad rows land at positions the
     offset causal mask hides until the suffix prefill overwrites them.
     Cache ``index`` is untouched here — ``_slot_prefill`` resets it to the
-    restored width when it ingests the suffix. NOT donated, for the same
-    per-request admission-error isolation as ``_slot_prefill``."""
+    restored width when it ingests the suffix. NOT donated, like
+    ``_slot_prefill`` and for the same reason: the dense layout's programs
+    keep their per-call isolation until a cell serves that layout."""
     slot_caches = jax.tree.map(lambda x: x[slot], pool_caches)
     slot_caches = [
         insert_kv_blocks(c, b, 0) for c, b in zip(slot_caches, blocks)
@@ -502,7 +501,9 @@ def _pool_verify_paged_flash(
 
 
 @partial(
-    jax.jit, static_argnames=("cfg", "chunk", "block_tokens", "buf_len")
+    jax.jit,
+    static_argnames=("cfg", "chunk", "block_tokens", "buf_len"),
+    donate_argnums=(1,),
 )
 def _slot_prefill_paged(
     params, pool_caches, table, slot, prompt, start, cfg: ModelConfig,
@@ -511,8 +512,12 @@ def _slot_prefill_paged(
     """Paged ``_slot_prefill``: one slot's gathered view through the same
     chunked prefill, then scatter the written suffix rows ``[start, start
     + n)`` into the slot's blocks. ``slot`` and ``start`` stay traced (no
-    recompile per slot or hit length); NOT donated, for the same
-    admission-error isolation as the dense prefill. A stateful layer's view
+    recompile per slot or hit length). The pool is DONATED, as the pool
+    step's is: the slot's rows and state are written in place, where an
+    undonated pool made XLA copy every K/V pool and state buffer whole first
+    (PR 38). A failure raised before the program is enqueued leaves the pool
+    whole and answers that request alone; one after it has taken the pool is
+    the pool's, and stops the scheduler (``admit``). A stateful layer's view
     is the slot's own state (a short convolution's rows; a delta-rule layer's
     matrix and convolution inputs): read as the chunk's left edge where
     ``start > 0`` (zeros at 0, whatever the slot held, so a recycled slot
@@ -1455,6 +1460,10 @@ class ContinuousScheduler:
             self._m_retries = reg.counter(
                 "serve_admission_retries_total",
                 "transient admission faults retried with backoff")
+            self._m_admit_pool_lost = reg.counter(
+                "serve_admit_pool_lost_total",
+                "admissions that failed after their prefill took the donated "
+                "pool: the scheduler stops (0 while it serves)")
 
     # ---- request intake ---------------------------------------------------
 
@@ -1995,6 +2004,8 @@ class ContinuousScheduler:
             try:
                 self._start(p)
             except TransientError as e:
+                if self._pool_lost():
+                    raise
                 if p.attempts < self.admission_retries:
                     p.attempts += 1
                     wait_ms = backoff_ms(
@@ -2026,7 +2037,9 @@ class ContinuousScheduler:
                         self._tel.emit("serve.retry", **retry_ev)
                     continue
                 self._answer_admission_error(p, e, now)
-            except Exception as e:  # noqa: BLE001  # tpa: disable=TPA006 — per-request isolation: ANY admission failure must answer this request alone, never kill co-batched ones
+            except Exception as e:  # noqa: BLE001  # tpa: disable=TPA006 — per-request isolation: ANY admission failure must answer this request alone, never kill co-batched ones; a pool lost to the prefill is re-raised, it is no request's
+                if self._pool_lost():
+                    raise
                 self._answer_admission_error(p, e, now)
         # Backoff-deferred entries return to the FRONT in arrival order:
         # output order is fixed by `order` anyway, this just keeps queue
@@ -2037,6 +2050,19 @@ class ContinuousScheduler:
                 self._queued_deadlines += sum(
                     1 for p in deferred if p.deadline is not None
                 )
+
+    def _pool_lost(self) -> bool:
+        """Whether the failed admission took the pool with it: the paged
+        prefill is donated, so a leaf deleted means the pool went to a
+        program that did not return its successor. No step can run on, and
+        the failure is the pool's, not the request's, so ``admit`` raises
+        it as a failed pool step would. A failure raised before the program
+        was enqueued leaves every leaf alive and answers its request alone.
+        A lost pool counts in ``serve_admit_pool_lost_total``."""
+        lost = any(leaf.is_deleted() for leaf in jax.tree.leaves(self.pool.caches))
+        if lost and self._tel is not None:
+            self._m_admit_pool_lost.inc()
+        return lost
 
     def _answer_admission_error(
         self, p: _Pending, e: BaseException, now: float

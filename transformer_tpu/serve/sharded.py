@@ -174,7 +174,7 @@ class ShardedPrograms:
         )
         self.slot_prefill_paged = twin(
             smod._slot_prefill_paged,
-            statics=("cfg", "chunk", "block_tokens", "buf_len"),
+            statics=("cfg", "chunk", "block_tokens", "buf_len"), donate=(1,),
             ins=(PS, L, R, R, R, R), outs=(R, L),
         )
         self.pool_write_blocks = twin(
