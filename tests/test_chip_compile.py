@@ -266,6 +266,44 @@ def test_paged_latent_attention_at_kimi_widths(one_chip):
     assert re.search(r"%paged_latent_attention[\w.]* = [^\n]*custom-call\(", text) and text.count("tpu_custom_call") == 1
 
 
+def test_paged_latent_attention_at_ax_k1_widths(one_chip):
+    """The same kernel with 64 query heads (A.X K1's cell: 256 slots, a table
+    of 384 pages of 16 for 6,144 positions, 46,704 pages)."""
+    from transformer_tpu.kernels.paged_latent import paged_latent_attention
+    from transformer_tpu.ops.mla import latent_width
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    n, width = 256, latent_width(512, 64)
+
+    def fn(q, pool, table, lengths):
+        return paged_latent_attention(q, pool, table, lengths, rank=512, interpret=False)
+
+    text = _compile(fn, sds((n, 64, width), BF16), sds((46704, 16, width), BF16), sds((n, 384), jnp.int32),
+                    sds((n,), jnp.int32)).as_text()
+    assert re.search(r"%paged_latent_attention[\w.]* = [^\n]*custom-call\(", text) and text.count("tpu_custom_call") == 1
+
+
+def test_moe_expert_ffn_at_ax_k1_widths(one_chip):
+    """The dropless layer at A.X K1's decode shapes: 12 held experts of 3 x
+    7168 x 2048 (whole experts do not fit the kernel's VMEM budget, so it
+    walks slices of their columns), 8 picks a token over 192 sigmoid scores in
+    8 groups of which 4 are kept, 256 slots a step."""
+    from transformer_tpu.kernels.moe_ffn import expert_slice_width
+    from transformer_tpu.ops.moe import moe_apply_dropless, moe_init
+
+    assert expert_slice_width(7168, 2048, 2) == 512  # 48 MB / (2 x 3 x 7168 x 2 B) = 585 columns: a lane tile of 512
+    p = jax.eval_shape(lambda: moe_init(jax.random.PRNGKey(0), 7168, 2048, 192, BF16, experts_held=12,
+                                        activation="swiglu", shared_dff=2048))
+    x = jax.ShapeDtypeStruct((256, 7168), BF16, sharding=one_chip)
+
+    def fn(p, x):
+        return moe_apply_dropless(p, x, num_experts=192, top_k=8, routed_scale=2.5, score="sigmoid",
+                                  renorm_epsilon=1e-20, groups=8, topk_groups=4, interpret=False)
+
+    text = _compile(fn, _placed(p, one_chip), x).as_text()
+    assert re.search(r"%moe_expert_ffn[\w.]* = [^\n]*custom-call\(", text) and text.count("tpu_custom_call") == 1
+
+
 def test_fused_ln_ffn(one_chip):
     from transformer_tpu.ops.ffn import ffn_init, fused_ln_ffn
     from transformer_tpu.ops.nn import layernorm_init

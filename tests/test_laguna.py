@@ -189,7 +189,7 @@ def test_no_token_is_dropped_when_every_token_picks_one_expert(tokens):
     p["router"] = {"kernel": jnp.zeros((32, 8)).at[:, 5].set(1.0)}
     x = jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (tokens, 32))) + 0.1  # every logit of expert 5 is the largest
     y, counts = moe_apply_dropless(p, x, num_experts=8, top_k=1)
-    assert counts.tolist() == [tokens, 1, tokens]
+    assert counts.tolist() == [tokens, 1, tokens, tokens]
     one = {n: {"kernel": p[n]["kernel"][5]} for n in ("gate", "in", "out")}
     np.testing.assert_allclose(y, ffn_apply(one, x, "swiglu"), atol=2e-6)  # the capacity path would drop most of them
 
@@ -201,7 +201,7 @@ def test_a_masked_token_is_routed_nowhere():
     y, counts = moe_apply_dropless(p, x, num_experts=8, top_k=2, token_mask=mask)
     assert int(counts[0]) == 6 and not np.asarray(y)[~np.asarray(mask)].any()
     _, none = moe_apply_dropless(p, x, num_experts=8, top_k=2, token_mask=jnp.zeros(6, bool))
-    assert none.tolist() == [0, 0, 0]
+    assert none.tolist() == [0, 0, 0, 0]
 
 
 def test_tile_rows_follow_the_rows_an_expert_expects():
@@ -313,7 +313,7 @@ def test_step_spans_count_attended_positions_and_expert_routing(cfg, params, mon
     sched = _scheduler(cfg, params, telemetry=tel)
     # The device's int32 totals are never reset: a server hours old has them
     # just below 2**31, and the next steps wrap them.
-    sched.pool.caches[sched._moe_layer][S.MOE_COUNTS] = jnp.full((4,), counted_before, jnp.int32)
+    sched.pool.caches[sched._moe_layer][S.MOE_COUNTS] = jnp.full((5,), counted_before, jnp.int32)
     sched._moe_read[:] = counted_before
     before = len(buffer().snapshot())
     rng = np.random.default_rng(2)
@@ -333,6 +333,8 @@ def test_step_spans_count_attended_positions_and_expert_routing(cfg, params, mon
         assert 0 < s["moe_hit"] <= s["moe_steps"] * layers * held
         # The most-loaded expert's rows: no fewer than an even share, no more than every slot's.
         assert s["moe_assign"] / held <= s["moe_max_load"] <= s["moe_tokens"] * layers
+        # Tokens with a pick here, a layer: no more than the rows, no more than the picks.
+        assert 0 < s["moe_tokens_here"] <= min(s["moe_tokens"] * layers, s["moe_assign"])
     reg = tel.registry
     assert reg.counter("serve_moe_assignments_total").value == sum(s["moe_assign"] for s in read)
     assert reg.counter("serve_moe_experts_hit_total").value == sum(s["moe_hit"] for s in read)

@@ -225,7 +225,7 @@ def test_no_token_is_dropped_when_every_token_picks_one_expert(tokens):
     p = _sigmoid_layer(bias)
     x = jax.random.normal(jax.random.PRNGKey(2), (tokens, 32))
     y, counts = moe_apply_dropless(p, x, num_experts=8, top_k=1, score="sigmoid", renorm_epsilon=0.0)
-    assert counts.tolist() == [tokens, 1, tokens]
+    assert counts.tolist() == [tokens, 1, tokens, tokens]
     one = {n: {"kernel": p[n]["kernel"][5]} for n in ("gate", "in", "out")}
     np.testing.assert_allclose(y, ffn_apply(one, x, "swiglu"), atol=3e-6)  # weight s / s = 1: the expert alone
 

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 from typing import TYPE_CHECKING, Any, Mapping
 
 if TYPE_CHECKING:
@@ -68,12 +69,21 @@ class AttentionKind:
     kda_conv_kernel: int = 4
     kda_gate_rank: int = 0
     # Latent attention (MLA): the latent's rank, a head's key part made from
-    # the latent, the key part all heads share (no rotation is applied), and
-    # a head's value width. Rank 0 = not such a layer.
+    # the latent, the key part all heads share, and a head's value width.
+    # Rank 0 = not such a layer. ``latent_query_rank`` > 0 compresses the
+    # query through a normalised latent of that rank (DeepSeek-V2's
+    # ``q_lora_rank``; 0 = one projection). ``latent_rotary`` rotates the
+    # shared key part and each head's matching query part at their positions
+    # with this kind's ``rope_base`` / ``yarn_*`` frequencies (off: no
+    # rotation anywhere, ``mla_use_nope``). ``yarn_mscale_all_dim`` > 0 with
+    # ``yarn_factor`` > 1 multiplies every score by ``latent_score_factor``.
     latent_rank: int = 0
     latent_nope_dim: int = 0
     latent_shared_dim: int = 0
     latent_value_dim: int = 0
+    latent_query_rank: int = 0
+    latent_rotary: bool = False
+    yarn_mscale_all_dim: float = 0.0
     # Seeded (not loaded) weights only, as ``ModelConfig.moe_router_init_scale``:
     # a factor on the Glorot draw of a latent layer's query kernel. A trained
     # attention is peaked; a Glorot draw's scores are so flat that over
@@ -90,6 +100,15 @@ class AttentionKind:
         if self.kda_heads:
             return "kda"
         return "mla" if self.latent_rank else "attention"
+
+    @property
+    def latent_score_factor(self) -> float:
+        """YaRN's factor on a latent layer's scores, ``m**2`` with ``m = 0.1 *
+        yarn_mscale_all_dim * ln(yarn_factor) + 1`` (DeepSeek-V2's
+        ``yarn_get_mscale``); 1 without YaRN or without ``mscale_all_dim``."""
+        if self.yarn_factor <= 1 or not self.yarn_mscale_all_dim:
+            return 1.0
+        return (0.1 * self.yarn_mscale_all_dim * math.log(self.yarn_factor) + 1.0) ** 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -219,6 +238,13 @@ class ModelConfig:
     moe_score: str = "softmax"  # "softmax" | "sigmoid"
     moe_select_bias: bool = False
     moe_renorm_epsilon: float = 1e-6
+    # Group-limited routing (DeepSeek-V3's ``n_group`` / ``topk_group``, the
+    # sigmoid form): the experts fall into ``moe_groups`` groups of equal
+    # size; a group's score is the sum of its two largest choice scores, and
+    # a token picks its top-k among the experts of its ``moe_topk_groups``
+    # best groups alone. 0 groups = no limit.
+    moe_groups: int = 0
+    moe_topk_groups: int = 0
     # RMSNorm over each head's channels of q and of k (own scales), before
     # the rotation; its epsilon is ``layernorm_epsilon``.
     qk_norm: bool = False
@@ -272,6 +298,11 @@ class ModelConfig:
                 )
             if k.latent_query_init_scale <= 0:
                 raise ValueError(f"layer kind {k.name!r}: latent_query_init_scale must be > 0")
+            if k.latent_query_rank < 0 or (k.latent_rotary and int(k.latent_shared_dim * k.rotary_share) % 2):
+                raise ValueError(
+                    f"layer kind {k.name!r}: latent_query_rank must be >= 0, and a rotated "
+                    "shared key part must turn an even number of channels"
+                )
         if self.moe_score not in ("softmax", "sigmoid"):
             raise ValueError(f"moe_score must be 'softmax' or 'sigmoid', got {self.moe_score!r}")
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -349,6 +380,21 @@ class ModelConfig:
             )
         if self.moe_select_bias and self.moe_score != "sigmoid":
             raise ValueError("moe_select_bias goes with moe_score='sigmoid'")
+        if self.moe_groups or self.moe_topk_groups:
+            per_group = self.moe_experts // max(self.moe_groups, 1)
+            if (
+                self.moe_score != "sigmoid" or self.moe_groups < 2
+                or self.moe_experts % self.moe_groups or per_group < 2
+                or not 1 <= self.moe_topk_groups <= self.moe_groups
+                or self.moe_top_k > self.moe_topk_groups * per_group
+            ):
+                raise ValueError(
+                    "group-limited routing needs moe_score='sigmoid', moe_groups "
+                    "(2 or more) that divide moe_experts into groups of 2 or more, "
+                    "and 1 <= moe_topk_groups <= moe_groups whose experts hold "
+                    f"moe_top_k (got {self.moe_groups}/{self.moe_topk_groups} over "
+                    f"{self.moe_experts}, top {self.moe_top_k})"
+                )
         if self.moe_router_init_scale <= 0 or self.moe_out_init_scale <= 0:
             raise ValueError(
                 "moe_router_init_scale and moe_out_init_scale must be > 0 (got "

@@ -8,8 +8,11 @@ the projections absorbed into the query, head ``h``'s score against position
 ``s`` is ``q~_h . row_s`` and its context ``sum_s p_h(s) c_s``: the row is key
 and, by its first ``rank`` channels, value. So where ``paged_flash_attention``
 reads a K page and a V page a KV head, this kernel reads one page and serves
-all the heads from it: 32 query rows against 1,152 bytes a position, about 60
-FLOP a byte.
+all the heads from it: one query row a head against 1,152 bytes a position,
+about 60 FLOP a byte at 32 heads and 121 at 64, both under v5e's ridge of
+about 240, so the kernel is bound by the bytes it reads. Where the layer
+rotates its shared key part the pool holds it turned and the query comes
+turned (``ops/mla.py``): the kernel is the same.
 
 The schedule is ``kernels/paged_flash.py``'s streamed route, cut to this
 shape. The grid walks the slots; the pool stays in HBM (``memory_space=ANY``);

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from transformer_tpu.config import ModelConfig
 from transformer_tpu.ops.attention import init_cache, mha_apply, mha_init
 from transformer_tpu.ops.kda import init_kda_state, kda_apply, kda_init
-from transformer_tpu.ops.mla import init_latent_cache, mla_apply, mla_init
+from transformer_tpu.ops.mla import init_latent_cache, latent_position_args, mla_apply, mla_init
 from transformer_tpu.ops.short_conv import (
     init_conv_state,
     short_conv_apply,
@@ -66,7 +66,7 @@ def decoder_layer_init(
         mixer = {"mla": mla_init(
             k1, cfg.d_model, kind.num_heads, kind.latent_rank, kind.latent_nope_dim,
             kind.latent_shared_dim, kind.latent_value_dim, cfg.params_dtype,
-            query_scale=kind.latent_query_init_scale,
+            query_scale=kind.latent_query_init_scale, query_rank=kind.latent_query_rank,
         )}
     else:
         mixer = {"self_mha": attention_init(k1, cfg, layer_index)}
@@ -144,7 +144,10 @@ def decoder_layer_apply(
         return out
 
     def latent_attn(h):
-        out, boxes[2] = mla_apply(params["mla"], h, cache, cfg.layernorm_epsilon)
+        out, boxes[2] = mla_apply(
+            params["mla"], h, cache, cfg.layernorm_epsilon,
+            **latent_position_args(cfg.layer_kind(layer_index)),
+        )
         return out
 
     def self_attn(h):

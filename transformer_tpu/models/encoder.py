@@ -122,6 +122,7 @@ def dropless_moe(moe_params: Params, h: jax.Array, cfg: ModelConfig, token_mask,
         num_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
         expert_offset=cfg.moe_expert_offset, routed_scale=cfg.moe_routed_scale,
         score=cfg.moe_score, renorm_epsilon=cfg.moe_renorm_epsilon,
+        groups=cfg.moe_groups, topk_groups=cfg.moe_topk_groups,
         activation=cfg.ffn_activation, token_mask=token_mask, interpret=interpret,
     )
 

@@ -44,7 +44,8 @@ the matrix state ``kda_state`` (N, H, D, D) float32 and the three
 convolutions' ``kda_conv`` rows; the step runs the projections and the
 one-token convolutions in XLA and ``kernels/kda_step.py`` over the states,
 in place, the live slots alone. A latent layer (``ops/mla.py``) has a pool of
-ONE row a position (``ckv``): the step scatters the new row, absorbs the
+ONE row a position (``ckv``): the step scatters the new row (its shared key
+part turned at the slot's position where the kind rotates), absorbs the
 projections into the queries and reads the pool through
 ``kernels/paged_latent.py``.
 
@@ -85,15 +86,15 @@ from transformer_tpu.ops.attention import (
 )
 from transformer_tpu.ops.ffn import fused_ln_ffn
 from transformer_tpu.ops.kda import kda_inputs, kda_output
-from transformer_tpu.ops.mla import absorbed_output, absorbed_queries, latent_rows
+from transformer_tpu.ops.mla import absorbed_output, absorbed_queries, latent_position_args, latent_rows
 from transformer_tpu.ops.nn import Params, norm_apply
 from transformer_tpu.ops.positional import apply_rope
 from transformer_tpu.ops.short_conv import short_conv_apply
 
 # Key of the pool pytree (in the first expert layer's dict) under which a
 # dropless model's decode steps accumulate int32 [picks held here, experts
-# hit, rows of the most-loaded expert, steps]: the programs return (logits,
-# pools) and nothing else.
+# hit, rows of the most-loaded expert, tokens with a pick here, steps]: the
+# programs return (logits, pools) and nothing else.
 MOE_COUNTS = "moe_counts"
 
 
@@ -238,15 +239,18 @@ def paged_decode_forward(
             }
             return kda_output(layer["kda"], h, o[:, None], cfg.layernorm_epsilon)
 
-        def latent_attn(h, layer=layer, pool_box=pool_box, rank=cfg.layer_kind(i).latent_rank):
+        def latent_attn(h, layer=layer, pool_box=pool_box, kind=cfg.layer_kind(i)):
             if s_q != 1:
                 raise ValueError("a latent layer's paged kernel reads one query position a slot")
-            mp = layer["mla"]
-            rows = latent_rows(mp, h, cfg.layernorm_epsilon).astype(pool_box[0]["ckv"].dtype)
+            mp, eps = layer["mla"], cfg.layernorm_epsilon
+            at = latent_position_args(kind)
+            # Each slot's row and query turn at the slot's own position.
+            positions = index[:, None] + jnp.arange(s_q) if at["rope"] else None
+            rows = latent_rows(mp, h, eps, positions, at["rope"]).astype(pool_box[0]["ckv"].dtype)
             pool_box[0] = {"ckv": scatter_rows(pool_box[0]["ckv"], rids, rows[:, 0])}
             ctx = paged_latent_attention(
-                absorbed_queries(mp, h)[:, 0], pool_box[0]["ckv"], table, lengths,
-                rank=rank, interpret=interpret,
+                absorbed_queries(mp, h, eps, positions, **at)[:, 0], pool_box[0]["ckv"], table, lengths,
+                rank=kind.latent_rank, interpret=interpret,
             )
             return absorbed_output(mp, ctx[:, None])
 

@@ -1,18 +1,24 @@
 """Latent attention (MLA): softmax attention whose cache is ONE row a position.
 
 No reference counterpart. The layer is DeepSeek-V2's multi-head latent
-attention (arXiv:2405.04434) as Kimi Linear (arXiv:2510.26692) uses it: no
-query compression and NO rotation anywhere (``mla_use_nope``), so the
-"rotary" channels are a key part the heads share and nothing else. ``x`` is
-the normalised input, ``H`` heads, latent rank ``r``, a head's key part made
-from the latent ``n`` wide, the shared key part ``p`` wide, a head's value
-``v`` wide:
+attention (arXiv:2405.04434). ``x`` is the normalised input, ``H`` heads,
+latent rank ``r``, a head's key part made from the latent ``n`` wide, the
+shared key part ``p`` wide, a head's value ``v`` wide:
 
-    [qn_h ; qp_h] = x Wq                (H heads x (n + p))
+    [qn_h ; qp_h] = x Wq, or RMSNorm_q(x Wqa) Wqb where the query is compressed (H heads x (n + p))
     [c ; kp]      = x Wkva              (r + p);   c = RMSNorm_r(c)      <- the cached row
     [kn_h ; v_h]  = c Wkvb_h            (n + v) a head
-    score_h(t, s) = (qn_h(t) . kn_h(s) + qp_h(t) . kp(s)) * (n + p)**-0.5,   causal softmax over s <= t
+    score_h(t, s) = (qn_h(t) . kn_h(s) + qp_h(t) . kp(s)) * (n + p)**-0.5 * f,   causal softmax over s <= t
     out           = concat_h(sum_s p_h(t, s) v_h(s)) Wo
+
+Two forms of it run. Kimi Linear's (arXiv:2510.26692; ``mla_use_nope``): one
+query projection and NO rotation anywhere, so the "rotary" channels are a key
+part the heads share and nothing else, ``f = 1``. DeepSeek-V3's, as A.X K1
+configures it: the query compressed to rank ``q`` (``q_lora_rank``), ``kp``
+turned at its own position and each ``qp_h`` at the query's by the kind's
+rotary frequencies (YaRN's blend), ``kp`` cached turned, and ``f`` YaRN's
+``mscale**2`` (``AttentionKind.latent_score_factor``). ``latent_position_args``
+says which, from the layer's kind.
 
 What a sequence carries: the row ``[c ; kp]`` a position, ``r + p`` channels
 (512 + 64: 1,152 bytes in bfloat16, where 32 heads of keys and values would be
@@ -40,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from transformer_tpu.ops.nn import Params, glorot_uniform
+from transformer_tpu.ops.positional import apply_rope, kind_rope
 
 _LANES = 128
 _QUERY_BLOCK = 512
@@ -53,24 +60,44 @@ def latent_width(rank: int, shared: int) -> int:
 
 def mla_init(
     key: jax.Array, d_model: int, heads: int, rank: int, nope: int, shared: int,
-    value: int, param_dtype=jnp.float32, query_scale: float = 1.0,
+    value: int, param_dtype=jnp.float32, query_scale: float = 1.0, query_rank: int = 0,
 ) -> Params:
-    """``query`` (M, H, n + p); ``kv_a`` (M, r + p); ``kv_norm``'s scale (r,);
-    ``kv_b`` (r, H, n + v): a head's key part then its value; ``out`` (H, v, M).
-    No bias. ``query_scale`` multiplies the Glorot draw of the query kernel
-    (``AttentionKind.latent_query_init_scale``: seeded weights whose attention
-    is peaked as a checkpoint's is)."""
+    """``query`` (M, H, n + p), or with ``query_rank`` > 0 the compressed
+    query: ``q_a`` (M, q), ``q_norm``'s scale (q,), ``q_b`` (q, H, n + p);
+    ``kv_a`` (M, r + p); ``kv_norm``'s scale (r,); ``kv_b`` (r, H, n + v): a
+    head's key part then its value; ``out`` (H, v, M). No bias.
+    ``query_scale`` multiplies the Glorot draw of the query kernel (of ``q_b``
+    where the query is compressed: ``AttentionKind.latent_query_init_scale``,
+    seeded weights whose attention is peaked as a checkpoint's is)."""
     kq, ka, kb, ko = jax.random.split(key, 4)
-    query = glorot_uniform(kq, (d_model, heads, nope + shared), param_dtype, d_model, heads * (nope + shared))
+    fan_in = query_rank or d_model
+    query = glorot_uniform(kq, (fan_in, heads, nope + shared), param_dtype, fan_in, heads * (nope + shared))
     if query_scale != 1.0:
         query = (query.astype(jnp.float32) * query_scale).astype(param_dtype)
+    if query_rank:
+        kqa = jax.random.fold_in(key, 5)
+        queries = {
+            "q_a": {"kernel": glorot_uniform(kqa, (d_model, query_rank), param_dtype, d_model, query_rank)},
+            "q_norm": {"scale": jnp.ones((query_rank,), param_dtype)},
+            "q_b": {"kernel": query},
+        }
+    else:
+        queries = {"query": {"kernel": query}}
     return {
-        "query": {"kernel": query},
+        **queries,
         "kv_a": {"kernel": glorot_uniform(ka, (d_model, rank + shared), param_dtype, d_model, rank + shared)},
         "kv_norm": {"scale": jnp.ones((rank,), param_dtype)},
         "kv_b": {"kernel": glorot_uniform(kb, (rank, heads, nope + value), param_dtype, rank, heads * (nope + value))},
         "out": {"kernel": glorot_uniform(ko, (heads, value, d_model), param_dtype, heads * value, d_model)},
     }
+
+
+def latent_position_args(kind) -> dict[str, Any]:
+    """What a latent layer of ``config.AttentionKind`` ``kind`` takes besides
+    its parameters: ``rope``, ``apply_rope``'s arguments where the kind
+    rotates its shared key part (else None), and ``score_factor``."""
+    return {"rope": kind_rope(kind) if kind.latent_rotary else None,
+            "score_factor": kind.latent_score_factor}
 
 
 def init_latent_cache(batch: int, max_len: int, rank: int, shared: int, dtype=jnp.bfloat16) -> dict[str, Any]:
@@ -92,31 +119,67 @@ def _sizes(params: Params) -> tuple[int, int, int]:
     """(rank, a head's key part from the latent, the shared key part)."""
     rank = params["kv_norm"]["scale"].shape[0]
     shared = params["kv_a"]["kernel"].shape[1] - rank
-    return rank, params["query"]["kernel"].shape[2] - shared, shared
+    query = params["q_b"] if "q_b" in params else params["query"]
+    return rank, query["kernel"].shape[2] - shared, shared
 
 
-def latent_rows(params: Params, h: jax.Array, epsilon: float) -> jax.Array:
+def _rms(x: jax.Array, scale: jax.Array, epsilon: float, dtype) -> jax.Array:
+    x = x.astype(jnp.float32)
+    x = x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + epsilon)
+    return (x * scale.astype(jnp.float32)).astype(dtype)
+
+
+def _rotate(x: jax.Array, positions: jax.Array, rope: dict) -> jax.Array:
+    """(B, S, H, D) turned at ``positions`` (B, S) by ``apply_rope``."""
+    return jax.vmap(lambda t, pos: apply_rope(t[None], pos, **rope)[0])(x, positions)
+
+
+def _queries(params: Params, h: jax.Array, epsilon: float, positions, rope) -> jax.Array:
+    """(B, S, M) -> (B, S, H, n + p): ``[qn_h ; qp_h]``, from ``h`` or from its
+    normalised compression, the shared part turned at ``positions``."""
+    dtype = h.dtype
+    if "q_a" in params:
+        c = jnp.einsum("bsm,mr->bsr", h, params["q_a"]["kernel"].astype(dtype))
+        c = _rms(c, params["q_norm"]["scale"], epsilon, dtype)
+        q = jnp.einsum("bsr,rhd->bshd", c, params["q_b"]["kernel"].astype(dtype))
+    else:
+        q = jnp.einsum("bsm,mhd->bshd", h, params["query"]["kernel"].astype(dtype))
+    if not rope:
+        return q
+    _, nope, _ = _sizes(params)
+    return jnp.concatenate([q[..., :nope], _rotate(q[..., nope:], positions, rope)], axis=-1)
+
+
+def latent_rows(
+    params: Params, h: jax.Array, epsilon: float, positions: jax.Array | None = None, rope: dict | None = None
+) -> jax.Array:
     """(B, S, M) normalised input -> the rows to cache (B, S, lanes):
-    ``[RMSNorm(c) ; kp ; 0]`` in ``h``'s dtype."""
+    ``[RMSNorm(c) ; kp ; 0]`` in ``h``'s dtype, ``kp`` turned at
+    ``positions`` (B, S) where ``rope`` is given."""
     rank, _, shared = _sizes(params)
     a = jnp.einsum("bsm,mf->bsf", h, params["kv_a"]["kernel"].astype(h.dtype))
-    c = a[..., :rank].astype(jnp.float32)
-    c = c * jax.lax.rsqrt((c * c).mean(-1, keepdims=True) + epsilon)
-    c = (c * params["kv_norm"]["scale"].astype(jnp.float32)).astype(h.dtype)
+    c = _rms(a[..., :rank], params["kv_norm"]["scale"], epsilon, h.dtype)
+    kp = a[..., rank:]
+    if rope:
+        kp = _rotate(kp[:, :, None], positions, rope)[:, :, 0]
     pad = latent_width(rank, shared) - rank - shared
-    return jnp.concatenate([c, a[..., rank:], jnp.zeros((*a.shape[:-1], pad), h.dtype)], axis=-1)
+    return jnp.concatenate([c, kp, jnp.zeros((*a.shape[:-1], pad), h.dtype)], axis=-1)
 
 
-def absorbed_queries(params: Params, h: jax.Array) -> jax.Array:
+def absorbed_queries(
+    params: Params, h: jax.Array, epsilon: float, positions: jax.Array | None = None,
+    rope: dict | None = None, score_factor: float = 1.0,
+) -> jax.Array:
     """(B, S, M) -> (B, S, H, lanes): ``[Wkvb_h^K qn_h ; qp_h ; 0]`` times the
-    softmax scale, so a score is a plain dot with a cached row."""
+    softmax scale (and ``score_factor``), so a score is a plain dot with a
+    cached row; ``qp_h`` turned at ``positions`` (B, S) where ``rope`` is given."""
     rank, nope, shared = _sizes(params)
     dtype = h.dtype
-    q = jnp.einsum("bsm,mhd->bshd", h, params["query"]["kernel"].astype(dtype))
+    q = _queries(params, h, epsilon, positions, rope)
     up = jnp.einsum("bshd,rhd->bshr", q[..., :nope], params["kv_b"]["kernel"][..., :nope].astype(dtype))
     pad = latent_width(rank, shared) - rank - shared
     full = jnp.concatenate([up, q[..., nope:], jnp.zeros((*q.shape[:-1], pad), dtype)], axis=-1)
-    return (full.astype(jnp.float32) * (nope + shared) ** -0.5).astype(dtype)
+    return (full.astype(jnp.float32) * ((nope + shared) ** -0.5 * score_factor)).astype(dtype)
 
 
 def absorbed_output(params: Params, ctx: jax.Array) -> jax.Array:
@@ -128,17 +191,21 @@ def absorbed_output(params: Params, ctx: jax.Array) -> jax.Array:
     return jnp.einsum("bshd,hdm->bsm", o, params["out"]["kernel"].astype(dtype))
 
 
-def _attend_rows(params: Params, h: jax.Array, rows: jax.Array, first) -> jax.Array:
+def _attend_rows(
+    params: Params, h: jax.Array, rows: jax.Array, first, epsilon: float,
+    rope: dict | None = None, score_factor: float = 1.0,
+) -> jax.Array:
     """The unabsorbed form: queries of the chunk ``h`` (B, S, M), sitting at
     positions ``first .. first + S - 1``, against the latent rows (B, L,
     lanes) of positions 0 .. L - 1, causally. Returns (B, S, H, v)."""
     rank, nope, shared = _sizes(params)
     dtype = h.dtype
     s, length = h.shape[1], rows.shape[1]
-    q = jnp.einsum("bsm,mhd->bshd", h, params["query"]["kernel"].astype(dtype))
+    turned_at = jnp.broadcast_to(first + jnp.arange(s), h.shape[:2]) if rope else None
+    q = _queries(params, h, epsilon, turned_at, rope)
     kv = jnp.einsum("blr,rhd->blhd", rows[..., :rank], params["kv_b"]["kernel"].astype(dtype))
     kn, v, kp = kv[..., :nope], kv[..., nope:], rows[..., rank : rank + shared]
-    scale = (nope + shared) ** -0.5
+    scale = (nope + shared) ** -0.5 * score_factor
 
     def block(args):
         qb, pos = args  # (B, Q, H, n + p), (Q,)
@@ -160,24 +227,30 @@ def _attend_rows(params: Params, h: jax.Array, rows: jax.Array, first) -> jax.Ar
 
 
 def mla_apply(
-    params: Params, h: jax.Array, cache: dict[str, Any] | None = None, epsilon: float = 1e-5
+    params: Params, h: jax.Array, cache: dict[str, Any] | None = None, epsilon: float = 1e-5,
+    rope: dict | None = None, score_factor: float = 1.0,
 ) -> tuple[jax.Array, dict[str, Any] | None]:
     """(B, S, M) normalised input -> ((B, S, M) output, the cache with the
     chunk's rows written at ``cache["index"]`` and the index moved on; ``None``
-    without a cache: the chunk then starts the sequence)."""
-    rows = latent_rows(params, h, epsilon)
+    without a cache: the chunk then starts the sequence). ``rope`` and
+    ``score_factor``: ``latent_position_args`` of the layer's kind."""
+    positions = None
+    if rope:
+        start = 0 if cache is None else cache["index"]
+        positions = jnp.broadcast_to(start + jnp.arange(h.shape[1]), h.shape[:2])
+    rows = latent_rows(params, h, epsilon, positions, rope)
     if cache is None:
-        heads = _attend_rows(params, h, rows, 0)
+        heads = _attend_rows(params, h, rows, 0, epsilon, rope, score_factor)
         return jnp.einsum("bshd,hdm->bsm", heads, params["out"]["kernel"].astype(h.dtype)), None
     idx = cache["index"]
     buf = jax.lax.dynamic_update_slice_in_dim(cache["ckv"], rows.astype(cache["ckv"].dtype), idx, axis=1)
     new_cache = {"ckv": buf, "index": idx + h.shape[1]}
     seen = buf.astype(h.dtype)
     if h.shape[1] > 1:
-        heads = _attend_rows(params, h, seen, idx)
+        heads = _attend_rows(params, h, seen, idx, epsilon, rope, score_factor)
         return jnp.einsum("bshd,hdm->bsm", heads, params["out"]["kernel"].astype(h.dtype)), new_cache
     rank, _, _ = _sizes(params)
-    q = absorbed_queries(params, h)  # (B, 1, H, lanes)
+    q = absorbed_queries(params, h, epsilon, positions, rope, score_factor)  # (B, 1, H, lanes)
     scores = jnp.einsum("bshw,blw->bhsl", q, seen, preferred_element_type=jnp.float32)
     visible = jnp.arange(buf.shape[1])[None, None, None, :] <= idx
     w = jax.nn.softmax(jnp.where(visible, scores, _MASKED), axis=-1)

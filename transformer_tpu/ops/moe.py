@@ -145,7 +145,8 @@ def expert_capacity(
 
 
 def _route(
-    params: Params, x: jax.Array, k: int, score: str = "softmax", renorm_epsilon: float = 1e-6
+    params: Params, x: jax.Array, k: int, score: str = "softmax", renorm_epsilon: float = 1e-6,
+    groups: int = 0, topk_groups: int = 0,
 ):
     """The router both dispatches share, in fp32 from the start: softmax over
     all experts, the ``k`` largest, renormalised to sum 1 (GShard's top-2
@@ -156,7 +157,11 @@ def _route(
     the sigmoid of its logit; the ``k`` experts with the largest ``score +
     bias`` are chosen (the router's ``bias`` leaf, where it has one, takes
     part in the choice and in nothing else), and the gates are the chosen
-    SCORES over their sum plus ``renorm_epsilon``."""
+    SCORES over their sum plus ``renorm_epsilon``. ``groups`` > 1 limits the
+    choice (DeepSeek-V3's group-limited routing): the experts fall into
+    ``groups`` equal groups, a group scores the sum of its two largest
+    ``score + bias``, and the ``k`` are chosen among the experts of the
+    ``topk_groups`` best groups alone."""
     logits = jnp.einsum(
         "...m,me->...e", x.astype(jnp.float32), params["router"]["kernel"].astype(jnp.float32)
     )
@@ -164,6 +169,11 @@ def _route(
         scores = jax.nn.sigmoid(logits)
         bias = params["router"].get("bias")
         choice = scores if bias is None else scores + bias.astype(jnp.float32)
+        if groups > 1:
+            by_group = choice.reshape(*choice.shape[:-1], groups, -1)
+            _, best = jax.lax.top_k(jax.lax.top_k(by_group, 2)[0].sum(-1), topk_groups)
+            kept = jax.nn.one_hot(best, groups, dtype=jnp.bool_).any(-2)  # (..., groups)
+            choice = jnp.where(kept[..., None], by_group, -jnp.inf).reshape(choice.shape)
         _, indices = jax.lax.top_k(choice, k)
         gates = jnp.take_along_axis(scores, indices, axis=-1)
         gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + renorm_epsilon)
@@ -281,17 +291,20 @@ def moe_apply_dropless(
     routed_scale: float = 1.0,
     score: str = "softmax",
     renorm_epsilon: float = 1e-6,
+    groups: int = 0,
+    topk_groups: int = 0,
     activation: str = "swiglu",
     token_mask: jax.Array | None = None,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """(..., M) -> ((..., M), int32 [picks that landed here, experts hit,
-    rows of the most-loaded expert]).
+    rows of the most-loaded expert, tokens with a pick here]).
 
     A routed layer of gated experts that drops no token, told which experts
     it holds: the stacks in ``params`` are experts ``expert_offset ..
     expert_offset + E_held - 1`` of the router's ``num_experts``. Every token
-    is routed over ALL experts (``_route``, in the form ``score`` names); its picks that fall on an expert
+    is routed over ALL experts (``_route``, in the form ``score`` names, in
+    ``groups`` where given); its picks that fall on an expert
     held here are grouped by expert (a stable sort by expert id, group sizes,
     each group padded to whole row tiles) and go through one grouped gated
     FFN (``kernels/moe_ffn.py``) that reads only the experts hit; picks that
@@ -309,7 +322,7 @@ def moe_apply_dropless(
     T = xt.shape[0]
     k = min(top_k, num_experts)
     held = params["in"]["kernel"].shape[0]
-    _, gates, indices = _route(params, xt, k, score, renorm_epsilon)  # (T, k)
+    _, gates, indices = _route(params, xt, k, score, renorm_epsilon, groups, topk_groups)  # (T, k)
     local = indices - expert_offset
     here = (local >= 0) & (local < held)
     if token_mask is not None:
@@ -353,5 +366,8 @@ def moe_apply_dropless(
     if "shared" in params:
         y = y + ffn_apply(params["shared"], xt, activation)
     # A grouped product's time follows its longest group: the third count.
-    counts = jnp.stack([jnp.sum(here), jnp.sum(sizes > 0), jnp.max(sizes)]).astype(jnp.int32)
+    # The fourth: the tokens a dispatch that sends a token once a chip sends here.
+    counts = jnp.stack(
+        [jnp.sum(here), jnp.sum(sizes > 0), jnp.max(sizes), jnp.sum(jnp.any(here, axis=-1))]
+    ).astype(jnp.int32)
     return y.reshape(*lead, m), counts

@@ -1132,8 +1132,10 @@ class ContinuousScheduler:
                 (i for i in range(cfg.num_layers) if layer_uses_moe(cfg, i)), None
             )
         if self._moe_layer is not None:
-            self.pool.caches[self._moe_layer][MOE_COUNTS] = jnp.zeros((4,), jnp.int32)
-        self._moe_read = np.zeros((4,), np.int64)  # the device's totals at the last fetch
+            # Made by the fetch's own copy, so that it is compiled here and
+            # not at the first fetch, mid-traffic.
+            self.pool.caches[self._moe_layer][MOE_COUNTS] = jnp.copy(jnp.zeros((5,), jnp.int32))
+        self._moe_read = np.zeros((5,), np.int64)  # the device's totals at the last fetch
         # Steps enqueued since the counts were last copied for a fetch; rows
         # those steps fed, ever, and as of the last copy that was fetched.
         self._moe_uncopied = self._moe_rows = self._moe_rows_read = 0
@@ -2830,13 +2832,14 @@ class ContinuousScheduler:
         # The device's int32 totals are never reset and wrap (after hours of
         # serving): what was added since the last fetch is the difference
         # modulo 2**32, far above what 32 steps can add.
-        assign, hit, max_load, steps = (
+        assign, hit, max_load, here, steps = (
             (total - self._moe_read) & 0xFFFFFFFF
         ).tolist()
         self._moe_read = total
         step_span.set(
             moe_assign=assign, moe_hit=hit, moe_max_load=max_load,
-            moe_steps=steps, moe_tokens=rows - self._moe_rows_read,
+            moe_tokens_here=here, moe_steps=steps,
+            moe_tokens=rows - self._moe_rows_read,
         )
         self._moe_rows_read = rows
         if self._tel is not None:
