@@ -360,6 +360,34 @@ def test_a_second_prefill_chunk_at_an_offset_then_the_step(model, cfg, params):
         _close(_step(sched, toks, index)[[0, 2]], want[:, t])
 
 
+def test_the_paged_prefill_walks_its_keys_to_the_reference(model, cfg, params, monkeypatch):
+    """Blocks of 16 against the 64-position slot buffer: a prefill of 16 from
+    0, then 32 from 16 (two query blocks reaching 2 and 3 key blocks of 4),
+    through the paged prefill program's own body (jitted afresh, so the
+    module's program and its cached traces keep blocks of 512), to the
+    reference's logits."""
+    from transformer_tpu.ops import mla as mla_ops
+    from transformer_tpu.serve import scheduler as S
+
+    monkeypatch.setattr(mla_ops, "_QUERY_BLOCK", 16)
+    monkeypatch.setattr(mla_ops, "_KEY_BLOCK", 16)
+    def body(*args):  # a function of its own: jit caches its traces by the function traced
+        return S._slot_prefill_paged.__wrapped__(*args)
+
+    prefill = jax.jit(body, static_argnums=(6, 7, 8, 9))
+    sched = _scheduler(cfg, params)
+    pool = sched.pool
+    ids = np.random.default_rng(7).integers(3, VOCAB, (1, 48)).astype(np.int32)
+    want = np.asarray(mla_moe_lm.logits(params, ids, model))
+    for first, n in ((0, 16), (16, 32)):
+        assert mla_ops.latent_keys_read(first, n, pool.buf_len) < 1.0
+        sched._paged_ensure(1, first + n)
+        logits, pool.caches = prefill(
+            sched.params, pool.caches, pool.alloc.table_device(), jnp.int32(1), jnp.asarray(ids[:, first : first + n]),
+            jnp.int32(first), cfg, 0, pool.block_tokens, pool.buf_len)
+        _close(np.asarray(logits[0], np.float32), want[0, first + n - 1])
+
+
 @pytest.mark.parametrize("deployment", [
     {"kv_layout": "paged", "decode_kernel": "paged_flash"},
     {"kv_layout": "paged", "decode_kernel": "xla"},

@@ -283,6 +283,29 @@ def test_paged_latent_attention_at_ax_k1_widths(one_chip):
     assert re.search(r"%paged_latent_attention[\w.]* = [^\n]*custom-call\(", text) and text.count("tpu_custom_call") == 1
 
 
+def test_latent_prefill_attention_walks_its_keys_at_ax_k1_widths(one_chip):
+    """The unabsorbed form of a 2,048-token chunk from a traced start against
+    A.X K1's 6,144-position slot buffer, 64 heads: query blocks of 512 walk
+    key blocks of 512 in a ``while`` (no XLA kernel is asked for, so no custom
+    call), and no block's scores span the whole buffer (`f32[64,512,6144]`,
+    805 MB, where every block scored all of it)."""
+    from transformer_tpu.ops import mla as mla_ops
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    p = jax.eval_shape(lambda: mla_ops.mla_init(jax.random.PRNGKey(0), 7168, 64, 512, 128, 64, 128, BF16,
+                                                query_rank=1536))
+    rope = {"base": 10000.0}
+
+    def fn(p, h, rows, first):
+        return mla_ops._attend_rows(p, h, rows, first, 1e-6, rope, 1.8)
+
+    compiled = jax.jit(fn).lower(_placed(p, one_chip), sds((1, 2048, 7168), BF16),
+                                 sds((1, 6144, mla_ops.latent_width(512, 64)), BF16), sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert " while(" in text and "f32[64,512,6144]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 512e6
+
+
 def test_moe_expert_ffn_at_ax_k1_widths(one_chip):
     """The dropless layer at A.X K1's decode shapes: 12 held experts of 3 x
     7168 x 2048 (whole experts do not fit the kernel's VMEM budget, so it
