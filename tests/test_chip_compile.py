@@ -327,6 +327,49 @@ def test_moe_expert_ffn_at_ax_k1_widths(one_chip):
     assert re.search(r"%moe_expert_ffn[\w.]* = [^\n]*custom-call\(", text) and text.count("tpu_custom_call") == 1
 
 
+def test_ssm_step_at_nemotron_widths(one_chip):
+    """The state-space state update at Nemotron-3-Super's widths: 128 heads of
+    64 x 128 float32 (4.19 MB a slot), 8 groups of B and C, under the cell's
+    256 slots, one slot's state a block, the state aliased input to output.
+    The kernel is named."""
+    from transformer_tpu.kernels.ssm_step import ssm_step
+
+    sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    n, h, p, g, d = 256, 128, 64, 8, 128
+
+    def fn(state, x, dt, a, b, c, live):
+        return ssm_step(state, x, dt, a, b, c, live, interpret=False)
+
+    compiled = _compile(fn, sds((n, h, p, d)), sds((n, h, p)), sds((n, h)), sds((h,)), sds((n, g, d)), sds((n, g, d)),
+                        sds((n,), jnp.int32), donate_argnums=(0,))
+    text = compiled.as_text()
+    assert re.search(r"%ssm_step[\w.]* = [^\n]*custom-call\(", text) and text.count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().alias_size_in_bytes >= n * h * p * d * 4  # in place: no second 1.07 GB
+
+
+def test_latent_experts_at_nemotron_widths(one_chip):
+    """The latent expert layer at Nemotron-3-Super's decode shapes: 128 held
+    ungated relu2 experts of 2 x 1024 x 2688 (whole experts fit the kernel's
+    VMEM budget), the 4096-wide input projected to the 1024-wide latent and
+    back, 22 picks a token over 512 sigmoid scores with a selection bias, a
+    shared expert of 5376, 256 slots a step."""
+    from transformer_tpu.kernels.moe_ffn import expert_slice_width
+    from transformer_tpu.ops.moe import moe_apply_dropless, moe_init
+
+    assert expert_slice_width(1024, 2688, 2, 2) == 2688
+    p = jax.eval_shape(lambda: moe_init(jax.random.PRNGKey(0), 4096, 2688, 512, BF16, experts_held=128,
+                                        activation="relu2", shared_dff=5376, select_bias=True, dropless=True,
+                                        latent_dim=1024))
+    x = jax.ShapeDtypeStruct((256, 4096), BF16, sharding=one_chip)
+
+    def fn(p, x):
+        return moe_apply_dropless(p, x, num_experts=512, top_k=22, routed_scale=5.0, score="sigmoid",
+                                  renorm_epsilon=1e-20, activation="relu2", interpret=False)
+
+    text = _compile(fn, _placed(p, one_chip), x).as_text()
+    assert re.search(r"%moe_expert_ffn[\w.]* = [^\n]*custom-call\(", text) and text.count("tpu_custom_call") == 1
+
+
 def test_fused_ln_ffn(one_chip):
     from transformer_tpu.ops.ffn import ffn_init, fused_ln_ffn
     from transformer_tpu.ops.nn import layernorm_init

@@ -361,15 +361,15 @@ def test_scheduler_answers_are_the_full_forwards_greedy_tokens(cfg, params, depl
 def test_prefix_cache_speculation_forks_and_a_mesh_refuse_a_stateful_layer(cfg, params):
     from transformer_tpu.serve.prefix_cache import PrefixCache
 
-    with pytest.raises(ValueError, match="cached prefix holds rows a position and no snapshot"):
+    with pytest.raises(ValueError, match="cached prefix holds rows a position and no snapshot.*a state-space layer's state"):
         PrefixCache(cfg, block_tokens=16)
     plain = ModelConfig(num_layers=1, d_model=32, num_heads=2, dff=64, input_vocab_size=64, target_vocab_size=64,
                         decoder_only=True, position_scheme="rope", dtype="float32", max_position=64)
     with pytest.raises(ValueError, match="no snapshot of the state a slot keeps beside them"):
         _scheduler(cfg, params, prefix_cache=PrefixCache(plain, block_tokens=16))
-    with pytest.raises(ValueError, match="rejected draft.*cannot be rolled back"):
+    with pytest.raises(ValueError, match="rejected draft.*a state-space layer's state.*cannot be rolled back"):
         _scheduler(cfg, params, speculate_k=2)
-    with pytest.raises(ValueError, match="state a slot beside it"):
+    with pytest.raises(ValueError, match="a short convolution, a delta-rule or a state-space layer.*state a slot beside it"):
         _scheduler(cfg, params, kv_layout="dense", decode_kernel="xla", mesh=2)
     sched = _scheduler(cfg, params)
     sched._paged_ensure(0, 16)

@@ -1049,6 +1049,9 @@ DONATING_POOLS = {
     ),
     # A latent pool beside delta-rule matrix states, as kimi-linear-48b-a3b.
     "latent_kda": ("kimi-linear-48b-a3b.reasoning-saturated", {}),
+    # State-space matrix states beside one K/V pool and layers that hold no
+    # pool entry at all, as nemotron-3-super.
+    "kv_ssm": ("nemotron-3-super.chat-saturated", {}),
 }
 
 

@@ -1247,6 +1247,28 @@ def _canned_entries() -> dict[str, Callable[[], tuple[Callable, tuple]]]:
         vec = f32(3, 4, 128)
         return fn, (f32(3, 4, 128, 128), vec, vec, vec, vec, f32(3, 4), i32(3))
 
+    def moe_ffn_ungated():
+        # The same grouped product with two matrices an expert (relu2).
+        from transformer_tpu.kernels.moe_ffn import moe_expert_ffn
+
+        group = jnp.asarray([0, 0, 1, 3, 3, 3], jnp.int32)
+        live = jnp.asarray(4, jnp.int32)
+        fn = lambda x, wi, wo: moe_expert_ffn(  # noqa: E731
+            x, None, wi, wo, group, live, tile_rows=16, block_dff=128, activation="relu2", interpret=True
+        )
+        return fn, (bf16(96, 128), bf16(4, 128, 256), bf16(4, 256, 128))
+
+    def ssm_state_step():
+        # The state-space layer's decode step: 3 slots (one free) of 8 heads
+        # of 16 x 128 in 2 groups, the state aliased input to output.
+        from transformer_tpu.kernels.ssm_step import ssm_step
+
+        f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+        fn = lambda s, x, dt, a, b, c, live: ssm_step(  # noqa: E731
+            s, x, dt, a, b, c, live, interpret=True
+        )
+        return fn, (f32(3, 8, 16, 128), f32(3, 8, 16), f32(3, 8), f32(8), f32(3, 2, 128), f32(3, 2, 128), i32(3))
+
     def paged_latent():
         # The latent layer's decode attention: 2 slots, 8 heads against rows
         # of 256 lanes (rank 128), a table of 8 pages of 16 in one block.
@@ -1294,7 +1316,9 @@ def _canned_entries() -> dict[str, Callable[[], tuple[Callable, tuple]]]:
         "ffn.fused[relu,bf16]": ffn_relu,
         "ffn.fused[swiglu,bf16]": ffn_swiglu,
         "moe.expert_ffn[swiglu,bf16]": moe_ffn,
+        "moe.expert_ffn[relu2,bf16]": moe_ffn_ungated,
         "kda.step[fp32]": kda_state_step,
+        "ssm.step[fp32]": ssm_state_step,
         "paged_latent[bf16]": paged_latent,
     }
     for variant in ("lm_bf16", "lm_int8_cache", "lm_gqa"):

@@ -22,7 +22,7 @@ FLAGS = flags.FLAGS
 # Literal so flag definition stays jax-import-free (the CLIs defer `import
 # jax` into main() on purpose — env/platform setup must run first);
 # tests/test_flags.py pins this against ops.ffn.FFN_ACTIVATIONS.
-_FFN_ACTIVATION_NAMES = ("geglu", "gelu", "reglu", "relu", "silu", "swiglu")
+_FFN_ACTIVATION_NAMES = ("geglu", "gelu", "reglu", "relu", "relu2", "silu", "swiglu")
 
 # The one table of the presets: the reference's five configurations
 # (BASELINE.json "configs") by name. Values land on flags the user did NOT

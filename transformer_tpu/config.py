@@ -22,6 +22,9 @@ if TYPE_CHECKING:
 # pad = 0; BOS = subword_vocab_size; EOS = subword_vocab_size + 1, so a model's
 # embedding table has subword_vocab_size + 2 rows (reference ``train.py:232-233``).
 PAD_ID = 0
+# The mixers whose state a sequence carries is a fixed state a slot, not rows
+# a position (``ModelConfig.state_layers``).
+STATE_MIXERS = ("conv", "kda", "ssm")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -30,7 +33,7 @@ class AttentionKind:
     (``ModelConfig.attention_kinds`` / ``layer_pattern``): a self-attention
     layer's query heads, its causal window and its rotary frequencies (every
     attention kind shares the model's KV heads and head size, so all of them
-    fit one KV pool), or one of three other mixers in the attention
+    fit one KV pool), or one of four other mixers in the attention
     sublayer's place (``mixer`` says which): with ``conv_kernel``, a gated
     short convolution (``ops/short_conv.py``), whose state is
     ``conv_kernel - 1`` rows a sequence and no KV rows at all; with
@@ -39,7 +42,13 @@ class AttentionKind:
     last ``kda_conv_kernel - 1`` inputs of three short convolutions; with
     ``latent_rank``, latent attention (``ops/mla.py``), which keeps ONE row
     of ``latent_rank + latent_shared_dim`` channels a position that every
-    head reads, in a pool entry of its own."""
+    head reads, in a pool entry of its own; with ``ssm_heads``, a Mamba-2
+    state-space mixer (``ops/ssd.py``), whose state is a ``ssm_head_dim`` x
+    ``ssm_state`` float32 matrix a head and the last ``ssm_conv_kernel - 1``
+    inputs of its convolution. ``sublayers`` says whether a layer of the
+    kind holds its mixer and a feed-forward part (``"both"``), the mixer
+    alone (``"mixer"``) or the feed-forward part alone (``"ffn"``: no mixer,
+    whatever the fields above say)."""
 
     name: str
     num_heads: int = 0  # query heads; 0 = ModelConfig.num_heads
@@ -48,7 +57,8 @@ class AttentionKind:
     # ``ModelConfig.attention_window``, which also makes the dense cache roll.
     window: int = 0
     rope_base: float = 10000.0
-    # Share of the head that is rotated (its first channels); the rest passes.
+    # Share of the head that is rotated (its first channels); the rest
+    # passes. 0 = an attention layer that turns nothing.
     rotary_share: float = 1.0
     # YaRN (arXiv:2309.00071) frequency blend; factor 0 = plain rotary.
     yarn_factor: float = 0.0
@@ -90,16 +100,40 @@ class AttentionKind:
     # thousands of positions the values average away and nothing the scores
     # are made of shows in the layer's output.
     latent_query_init_scale: float = 1.0
+    # The same factor on an attention layer's query kernel (GQA or MHA).
+    query_init_scale: float = 1.0
+    # Mamba-2 state-space mixer (SSD, arXiv:2405.21060): heads of
+    # ``ssm_head_dim`` channels; ``ssm_groups`` groups of the input and
+    # output projections B and C, ``ssm_state`` channels each, head h reading
+    # group h // (heads / groups); the taps of the causal depthwise
+    # convolution over x, B and C; the positions of one chunk of the
+    # prefill's scan. 0 heads = not such a layer.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
+    sublayers: str = "both"  # "both" | "mixer" | "ffn"
 
     @property
     def mixer(self) -> str:
         """What stands in the attention sublayer: ``"attention"``,
-        ``"conv"``, ``"kda"`` or ``"mla"``."""
+        ``"conv"``, ``"kda"``, ``"mla"``, ``"ssm"``, or ``"none"`` (a layer
+        that is a feed-forward part alone)."""
+        if self.sublayers == "ffn":
+            return "none"
         if self.conv_kernel:
             return "conv"
         if self.kda_heads:
             return "kda"
+        if self.ssm_heads:
+            return "ssm"
         return "mla" if self.latent_rank else "attention"
+
+    @property
+    def has_ffn(self) -> bool:
+        return self.sublayers != "mixer"
 
     @property
     def latent_score_factor(self) -> float:
@@ -220,6 +254,11 @@ class ModelConfig:
     moe_expert_offset: int = 0
     moe_routed_scale: float = 1.0  # multiplies the renormalised top-k weights
     moe_leading_dense: int = 0  # leading layers that keep the dense FFN
+    # Width of the latent the routed experts work in (0 = d_model): the
+    # layer projects its input down to it before the experts and their
+    # weighted sum back up after them (LatentMoE); the router and the
+    # shared expert read the full width.
+    moe_latent_dim: int = 0
     # Seeded (not loaded) weights only: factors on the Glorot draw of the
     # router's kernel and of the routed experts' out kernels. A trained
     # router is peaked (a token's first pick carries most of its weight) and
@@ -278,14 +317,24 @@ class ModelConfig:
             raise ValueError("attention kinds (causal windows, heads by layer) are a decoder-only model's")
         for k in self.attention_kinds:
             heads = k.num_heads or self.num_heads
-            if heads % self.kv_heads or k.window < 0 or not 0.0 < k.rotary_share <= 1.0:
+            if heads % self.kv_heads or k.window < 0 or not 0.0 <= k.rotary_share <= 1.0:
                 raise ValueError(f"attention kind {k} does not fit this model")
             if int(self.head_dim * k.rotary_share) % 2:
                 raise ValueError(f"attention kind {k.name!r} rotates an odd number of channels")
             if k.conv_kernel < 0 or k.conv_kernel == 1:
                 raise ValueError(f"layer kind {k.name!r}: conv_kernel is 0 (attention) or 2 and more taps")
-            if sum(map(bool, (k.conv_kernel, k.kda_heads, k.latent_rank))) > 1:
+            if sum(map(bool, (k.conv_kernel, k.kda_heads, k.latent_rank, k.ssm_heads))) > 1:
                 raise ValueError(f"layer kind {k.name!r} names more than one mixer")
+            if k.sublayers not in ("both", "mixer", "ffn"):
+                raise ValueError(f"layer kind {k.name!r}: sublayers must be 'both', 'mixer' or 'ffn'")
+            if k.ssm_heads and (
+                min(k.ssm_head_dim, k.ssm_state, k.ssm_groups, k.ssm_chunk) < 1
+                or k.ssm_heads % k.ssm_groups or k.ssm_conv_kernel < 2
+            ):
+                raise ValueError(
+                    f"layer kind {k.name!r}: a state-space layer needs ssm_head_dim, "
+                    "ssm_state, a chunk, groups that divide its heads and 2 and more taps"
+                )
             if k.kda_heads and (k.kda_head_dim < 1 or k.kda_conv_kernel < 2 or k.kda_gate_rank < 0):
                 raise ValueError(
                     f"layer kind {k.name!r}: a delta-rule layer needs kda_head_dim "
@@ -296,8 +345,10 @@ class ModelConfig:
                     f"layer kind {k.name!r}: a latent layer needs latent_nope_dim, "
                     "latent_shared_dim and latent_value_dim"
                 )
-            if k.latent_query_init_scale <= 0:
-                raise ValueError(f"layer kind {k.name!r}: latent_query_init_scale must be > 0")
+            if k.latent_query_init_scale <= 0 or k.query_init_scale <= 0:
+                raise ValueError(
+                    f"layer kind {k.name!r}: latent_query_init_scale and query_init_scale must be > 0"
+                )
             if k.latent_query_rank < 0 or (k.latent_rotary and int(k.latent_shared_dim * k.rotary_share) % 2):
                 raise ValueError(
                     f"layer kind {k.name!r}: latent_query_rank must be >= 0, and a rotated "
@@ -356,28 +407,29 @@ class ModelConfig:
         if self.ffn_activation not in FFN_ACTIVATIONS:
             raise ValueError(f"unknown ffn_activation {self.ffn_activation!r}")
         dropless = self.moe_dispatch == "dropless"
-        # Nothing in the mathematics ties the expert's form to the dispatch:
-        # each dispatch computes the one form its user has (the capacity
-        # einsums the reference's biased ungated FFN, the grouped kernel
-        # ``moe_expert_ffn`` three bias-free matrices), so the other two
-        # pairs are refused here rather than computed as something else.
-        if self.moe_experts and is_gated(self.ffn_activation) != dropless:
+        # The capacity einsums compute the reference's biased ungated FFN
+        # alone; the grouped kernel ``moe_expert_ffn`` computes bias-free
+        # experts gated (three matrices) or not (two). A gated capacity
+        # expert is refused here rather than computed as something else.
+        if self.moe_experts and not dropless and is_gated(self.ffn_activation):
             raise ValueError(
-                "capacity-dispatch experts are ungated and dropless experts "
-                f"gated: got ffn_activation={self.ffn_activation!r} with "
-                f"moe_dispatch={self.moe_dispatch!r}"
+                "capacity-dispatch experts are ungated: got "
+                f"ffn_activation={self.ffn_activation!r} with moe_dispatch='capacity'"
             )
         held = self.experts_held
         if not dropless and (
             self.moe_experts_held or self.moe_expert_offset or self.moe_shared_dff
             or self.moe_dff or self.moe_routed_scale != 1.0
             or self.moe_score != "softmax" or self.moe_select_bias
+            or self.moe_latent_dim
         ):
             raise ValueError(
                 "a share of the experts, a shared expert, an expert width, a "
-                "routed scale, sigmoid scores and a selection bias need "
-                "moe_dispatch='dropless'"
+                "routed scale, sigmoid scores, a selection bias and a latent "
+                "width need moe_dispatch='dropless'"
             )
+        if self.moe_latent_dim < 0:
+            raise ValueError(f"moe_latent_dim must be >= 0, got {self.moe_latent_dim}")
         if self.moe_select_bias and self.moe_score != "sigmoid":
             raise ValueError("moe_select_bias goes with moe_score='sigmoid'")
         if self.moe_groups or self.moe_topk_groups:
@@ -441,11 +493,11 @@ class ModelConfig:
     @property
     def state_layers(self) -> tuple[int, ...]:
         """Layers whose state a sequence carries is not rows a position: the
-        short-convolution and the delta-rule layers (a latent layer keeps a
-        row a position and is not one)."""
+        short-convolution, the delta-rule and the state-space layers (a
+        latent layer keeps a row a position and is not one)."""
         return tuple(
             i for i in range(self.num_layers)
-            if self.layer_kind(i).mixer in ("conv", "kda")
+            if self.layer_kind(i).mixer in STATE_MIXERS
         )
 
     @property

@@ -1,4 +1,5 @@
-"""Grouped gated feed-forward over the experts that received tokens.
+"""Grouped feed-forward over the experts that received tokens, gated (three
+matrices an expert) or not (two).
 
 The routed half of a dropless expert layer (``ops/moe.py``
 ``moe_apply_dropless``): rows arrive sorted by expert, each expert's rows
@@ -13,10 +14,10 @@ Tiles past the live count (the grid is sized for the worst case) resolve to
 the blocks of the last live step, which the pipeline does not fetch again,
 and skip their compute; their output lands in a spare tile nobody reads.
 
-Numerics follow ``ffn_apply``'s gated branch: each product accumulates in
-fp32 and is rounded to the compute dtype where ``dense_apply`` rounds; the
-activation and the gate product run in fp32 and are rounded once, and the
-sum over the expert width is taken slice by slice in fp32.
+Numerics follow ``ffn_apply``: each product accumulates in fp32 and is
+rounded to the compute dtype where ``dense_apply`` rounds; the activation
+(and the gate product) run in fp32 and are rounded once, and the sum over the
+expert width is taken slice by slice in fp32.
 """
 
 from __future__ import annotations
@@ -28,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from transformer_tpu.ops.ffn import _GATED_ACTIVATIONS, _ffn_tile
+from transformer_tpu.ops.ffn import _ACTIVATIONS, _GATED_ACTIVATIONS, _ffn_tile, is_gated
 
 # Double-buffered weight slices of 1,024 columns (a whole expert of that
 # width: on the chip 8 % faster at decode than slices of 256 or 512, PERF.md
@@ -39,12 +40,12 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 _SLICE_BUDGET = 48 * 1024 * 1024
 
 
-def expert_slice_width(m: int, dff: int, itemsize: int) -> int:
+def expert_slice_width(m: int, dff: int, itemsize: int, matrices: int = 3) -> int:
     """Columns of the expert width one grid step reads: the whole expert
-    where its three matrices, double-buffered, fit ``_SLICE_BUDGET`` (one
+    where its ``matrices``, double-buffered, fit ``_SLICE_BUDGET`` (one
     step an expert: no slice's fixed cost paid twice), else the widest lane
     tile that divides the width and does."""
-    fits = _SLICE_BUDGET // (2 * 3 * m * itemsize)
+    fits = _SLICE_BUDGET // (2 * matrices * m * itemsize)
     return dff if dff <= fits else _ffn_tile(dff, fits)
 
 
@@ -52,18 +53,15 @@ def _kernel(
     group_ref,  # (tiles,) int32 SMEM: expert of each row tile
     live_ref,   # (1,) int32 SMEM: row tiles that hold rows
     x_ref,      # (tm, M) rows of this tile
-    wg_ref,     # (1, M, bf) gate slice of the tile's expert
-    wi_ref,     # (1, M, bf)
-    wo_ref,     # (1, bf, M)
-    out_ref,    # (tm, M)
-    acc_ref,    # (tm, M) fp32
-    *,
+    *refs,      # [wg_ref (1, M, bf) gate slice of the tile's expert,] wi_ref
+                # (1, M, bf), wo_ref (1, bf, M), out_ref (tm, M), acc_ref (tm, M) fp32
     activation: str,
 ):
     del group_ref
+    gated = is_gated(activation)
+    wg_ref, (wi_ref, wo_ref, out_ref, acc_ref) = (refs[0], refs[1:]) if gated else (None, refs)
     t, f = pl.program_id(0), pl.program_id(1)
     dtype = x_ref.dtype
-    act = _GATED_ACTIVATIONS[activation]
 
     @pl.when(t < live_ref[0])
     def _tile():
@@ -79,7 +77,10 @@ def _kernel(
                 preferred_element_type=jnp.float32,
             ).astype(dtype).astype(jnp.float32)
 
-        h = (act(dense(wg_ref)) * dense(wi_ref)).astype(dtype)
+        if gated:
+            h = (_GATED_ACTIVATIONS[activation](dense(wg_ref)) * dense(wi_ref)).astype(dtype)
+        else:
+            h = _ACTIVATIONS[activation](dense(wi_ref)).astype(dtype)
         acc_ref[...] += jax.lax.dot_general(
             h, wo_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -92,7 +93,7 @@ def _kernel(
 
 def moe_expert_ffn(
     x: jax.Array,
-    w_gate: jax.Array,
+    w_gate: jax.Array | None,
     w_in: jax.Array,
     w_out: jax.Array,
     tile_group: jax.Array,
@@ -103,13 +104,15 @@ def moe_expert_ffn(
     block_dff: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """``act(x @ w_gate[e]) * (x @ w_in[e]) @ w_out[e]`` for each row tile's
-    expert ``e = tile_group[t]``.
+    """``act(x @ w_gate[e]) * (x @ w_in[e]) @ w_out[e]`` (a gated
+    ``activation``) or ``act(x @ w_in[e]) @ w_out[e]`` (``w_gate`` None) for
+    each row tile's expert ``e = tile_group[t]``.
 
     Args:
       x: (tiles * tile_rows, M) rows grouped by expert, each group padded to
         whole tiles (padding rows are zero and give zero).
-      w_gate, w_in: (E, M, F); w_out: (E, F, M) stacked expert weights.
+      w_gate, w_in: (E, M, F); w_out: (E, F, M) stacked expert weights
+        (``w_gate`` None where ``activation`` is not gated).
       tile_group: (tiles,) int32, the expert of each tile; entries at or past
         ``live_tiles`` repeat the last live tile's.
       live_tiles: () int32, how many leading tiles hold rows.
@@ -122,9 +125,12 @@ def moe_expert_ffn(
     """
     rows, m = x.shape
     tiles = rows // tile_rows
-    dff = w_gate.shape[2]
+    dff = w_in.shape[2]
+    if (w_gate is not None) != is_gated(activation):
+        raise ValueError(f"a gated activation takes w_gate and no other does: got {activation!r}")
+    weights = [w for w in (w_gate, w_in) if w is not None]
     if block_dff is None:
-        block_dff = expert_slice_width(m, dff, jnp.dtype(w_gate.dtype).itemsize)
+        block_dff = expert_slice_width(m, dff, jnp.dtype(w_in.dtype).itemsize, len(weights) + 1)
     bf = _ffn_tile(dff, block_dff)
     nf = dff // bf
     if interpret is None:
@@ -155,8 +161,7 @@ def moe_expert_ffn(
             grid=(tiles, nf),
             in_specs=[
                 pl.BlockSpec((tile_rows, m), at_rows),
-                pl.BlockSpec((1, m, bf), at_cols),
-                pl.BlockSpec((1, m, bf), at_cols),
+                *[pl.BlockSpec((1, m, bf), at_cols) for _ in weights],
                 pl.BlockSpec((1, bf, m), at_out_rows),
             ],
             out_specs=pl.BlockSpec((tile_rows, m), at_result),
@@ -170,4 +175,4 @@ def moe_expert_ffn(
         interpret=bool(interpret),
         name="moe_expert_ffn",
     )(tile_group.astype(jnp.int32), live_tiles.astype(jnp.int32).reshape(1),
-      x, w_gate, w_in, w_out)
+      x, *weights, w_out)

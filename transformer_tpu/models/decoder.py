@@ -20,10 +20,11 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from transformer_tpu.config import ModelConfig
+from transformer_tpu.config import STATE_MIXERS, ModelConfig
 from transformer_tpu.ops.attention import init_cache, mha_apply, mha_init
 from transformer_tpu.ops.kda import init_kda_state, kda_apply, kda_init
 from transformer_tpu.ops.mla import init_latent_cache, latent_position_args, mla_apply, mla_init
+from transformer_tpu.ops.ssd import init_ssm_state, ssm_apply, ssm_init
 from transformer_tpu.ops.short_conv import (
     init_conv_state,
     short_conv_apply,
@@ -54,8 +55,11 @@ def decoder_layer_init(
 ) -> Params:
     k1, k2, k3 = jax.random.split(key, 3)
     kind = cfg.layer_kind(layer_index)
-    # The layer's mixer: self-attention, or one of the three in its place.
-    if kind.mixer == "conv":
+    # The layer's mixer: self-attention, or one of the four in its place, or
+    # none (a layer that is a feed-forward part alone).
+    if kind.mixer == "none":
+        mixer = {}
+    elif kind.mixer == "conv":
         mixer = {"conv": short_conv_init(k1, cfg.d_model, kind.conv_kernel, cfg.params_dtype)}
     elif kind.mixer == "kda":
         mixer = {"kda": kda_init(
@@ -68,14 +72,21 @@ def decoder_layer_init(
             kind.latent_shared_dim, kind.latent_value_dim, cfg.params_dtype,
             query_scale=kind.latent_query_init_scale, query_rank=kind.latent_query_rank,
         )}
+    elif kind.mixer == "ssm":
+        mixer = {"ssm": ssm_init(
+            k1, cfg.d_model, kind.ssm_heads, kind.ssm_head_dim, kind.ssm_groups,
+            kind.ssm_state, kind.ssm_conv_kernel, cfg.params_dtype,
+        )}
     else:
         mixer = {"self_mha": attention_init(k1, cfg, layer_index)}
-    params: Params = {
-        **mixer,
-        **_ffn_sublayer_init(k3, cfg, layer_uses_moe(cfg, layer_index)),
-        "ln1": norm_init(cfg.d_model, cfg.params_dtype, cfg.norm),
-        "ln_ffn": norm_init(cfg.d_model, cfg.params_dtype, cfg.norm),
-    }
+    params: Params = dict(mixer)
+    # A layer holds the norm of each sublayer it has: ``ln1`` its mixer's,
+    # ``ln_ffn`` its feed-forward part's.
+    if mixer:
+        params["ln1"] = norm_init(cfg.d_model, cfg.params_dtype, cfg.norm)
+    if kind.has_ffn:
+        params.update(_ffn_sublayer_init(k3, cfg, layer_uses_moe(cfg, layer_index)))
+        params["ln_ffn"] = norm_init(cfg.d_model, cfg.params_dtype, cfg.norm)
     if not cfg.decoder_only:
         params["cross_mha"] = mha_init(
             k2, cfg.d_model, cfg.num_heads, cfg.params_dtype,
@@ -111,9 +122,12 @@ def decoder_layer_apply(
     model's layers differ: its window and its rotary frequencies (its heads
     are the parameters' shape). A stateful layer's cache is its state and
     ``index`` (``{"conv_state"}``; a delta-rule layer's ``{"kda_state",
-    "kda_conv"}``); it reads the state only past position 0, so whatever an
-    earlier sequence left in it is never seen. A latent layer's is
-    ``{"ckv", "index"}``. The mixer is picked by the parameters' key.
+    "kda_conv"}``; a state-space layer's ``{"ssm_state", "ssm_conv"}``); it
+    reads the state only past position 0, so whatever an earlier sequence
+    left in it is never seen. A latent layer's is ``{"ckv", "index"}``; a
+    layer without a mixer keeps ``{"index"}`` alone. The mixer is picked by
+    the parameters' key; a sublayer whose norm (``ln1``, ``ln_ffn``) the
+    layer lacks is not there.
     """
     r1, r2, r3 = (None, None, None) if rng is None else jax.random.split(rng, 3)
     boxes: list[Any] = [None, None, None]
@@ -136,6 +150,25 @@ def decoder_layer_apply(
                 for key in state_buffer_keys(cache)
             }
         out, state = kda_apply(params["kda"], h, state, cfg.layernorm_epsilon)
+        if cache is not None:
+            boxes[2] = {
+                **{key: state[key].astype(cache[key].dtype) for key in state},
+                "index": cache["index"] + h.shape[1],
+            }
+        return out
+
+    def state_space(h):
+        kind = cfg.layer_kind(layer_index)
+        state = None
+        if cache is not None:
+            state = {
+                key: jnp.where(cache["index"] > 0, cache[key], 0)
+                for key in state_buffer_keys(cache)
+            }
+        out, state = ssm_apply(
+            params["ssm"], h, state, groups=kind.ssm_groups, state_size=kind.ssm_state,
+            chunk=kind.ssm_chunk, epsilon=cfg.layernorm_epsilon,
+        )
         if cache is not None:
             boxes[2] = {
                 **{key: state[key].astype(cache[key].dtype) for key in state},
@@ -166,9 +199,12 @@ def decoder_layer_apply(
         boxes[0], boxes[2] = w, new_cache
         return out
 
-    mixers = {"conv": short_conv, "kda": kda, "mla": latent_attn}
+    mixers = {"conv": short_conv, "kda": kda, "mla": latent_attn, "ssm": state_space}
     mixer = next((fn for key, fn in mixers.items() if key in params), self_attn)
-    x = _sublayer(cfg, params["ln1"], x, mixer, r1, deterministic)
+    if "ln1" in params:
+        x = _sublayer(cfg, params["ln1"], x, mixer, r1, deterministic)
+    elif cache is not None:
+        boxes[2] = {"index": cache["index"] + x.shape[1]}
 
     if not cfg.decoder_only:
         if enc_out is None:
@@ -186,13 +222,14 @@ def decoder_layer_apply(
 
         x = _sublayer(cfg, params["ln2"], x, cross_attn, r2, deterministic)
 
-    x = _sublayer(
-        cfg, params["ln_ffn"], x,
-        lambda h: _ffn_sublayer_apply(
-            params, h, cfg, aux_box, _token_mask_from(self_mask)
-        ),
-        r3, deterministic,
-    )
+    if "ln_ffn" in params:
+        x = _sublayer(
+            cfg, params["ln_ffn"], x,
+            lambda h: _ffn_sublayer_apply(
+                params, h, cfg, aux_box, _token_mask_from(self_mask)
+            ),
+            r3, deterministic,
+        )
     return x, boxes[0], boxes[1], boxes[2], aux_box[0]
 
 
@@ -326,13 +363,16 @@ def init_decoder_caches(
     """One self-attention KV cache per decoder layer (int8-quantized when
     ``cfg.kv_cache_int8``; a rolling O(window) buffer when
     ``cfg.attention_window``); for a latent layer, its cache of latent rows;
-    for a stateful layer (a short convolution, a delta-rule layer), its state
-    and the position it stands at. Caches start at position 0; fill the
+    for a stateful layer (a short convolution, a delta-rule layer, a
+    state-space layer), its state and the position it stands at; for a layer
+    without a mixer, the position alone. Caches start at position 0; fill the
     prompt in one pass with ``decoder_prefill`` and decode incrementally from
     there (``transformer_decode_step``)."""
     def one(i):
         kind = cfg.layer_kind(i)
-        if kind.mixer in ("conv", "kda"):
+        if kind.mixer == "none":
+            return {"index": jnp.array(0, dtype=jnp.int32)}
+        if kind.mixer in STATE_MIXERS:
             return {
                 **init_layer_state(cfg, i, batch_size),
                 "index": jnp.array(0, dtype=jnp.int32),
@@ -360,6 +400,11 @@ def init_layer_state(cfg: ModelConfig, layer_index: int, batch_size: int) -> dic
         return init_kda_state(
             batch_size, kind.kda_heads, kind.kda_head_dim, kind.kda_conv_kernel,
             cfg.compute_dtype,
+        )
+    if kind.mixer == "ssm":
+        return init_ssm_state(
+            batch_size, kind.ssm_heads, kind.ssm_head_dim, kind.ssm_groups,
+            kind.ssm_state, kind.ssm_conv_kernel, cfg.compute_dtype,
         )
     return {
         "conv_state": init_conv_state(

@@ -38,28 +38,38 @@ def layer_uses_moe(cfg: ModelConfig, layer_index: int) -> bool:
     """Whether layer ``layer_index`` (0-based) carries a MoE FFN: every
     ``moe_every``-th layer counting from the top of the cadence (GShard
     alternates, Switch uses every layer — ``cfg.moe_every`` choses), after
-    the ``cfg.moe_leading_dense`` layers that keep the dense FFN."""
+    the ``cfg.moe_leading_dense`` layers that keep the dense FFN; never a
+    layer whose kind holds a mixer alone."""
     return (
         cfg.moe_experts > 0
         and layer_index >= cfg.moe_leading_dense
         and (layer_index + 1) % cfg.moe_every == 0
+        and cfg.layer_kind(layer_index).has_ffn
     )
 
 
 def attention_init(key: jax.Array, cfg: ModelConfig, layer_index: int) -> Params:
-    """Self-attention parameters of layer ``layer_index``: its kind's heads."""
-    return mha_init(
-        key, cfg.d_model, cfg.layer_kind(layer_index).num_heads, cfg.params_dtype,
+    """Self-attention parameters of layer ``layer_index``: its kind's heads,
+    the query kernel's draw times its ``query_init_scale``."""
+    kind = cfg.layer_kind(layer_index)
+    params = mha_init(
+        key, cfg.d_model, kind.num_heads, cfg.params_dtype,
         num_kv_heads=cfg.kv_heads, head_dim=cfg.head_dim, use_bias=cfg.use_bias,
         gate=cfg.attention_gate == "per_head", qk_norm=cfg.qk_norm,
     )
+    if kind.query_init_scale != 1.0:
+        q = params["query"]["kernel"]
+        params["query"]["kernel"] = (q.astype(jnp.float32) * kind.query_init_scale).astype(q.dtype)
+    return params
 
 
 def layer_rope(cfg: ModelConfig, layer_index: int) -> bool | dict:
-    """``mha_apply``'s ``rope`` for layer ``layer_index``: off, or
-    ``apply_rope``'s arguments for its kind (base, rotated share, YaRN; for a
-    model of one kind, the plain rotation at base 10,000)."""
-    return cfg.position_scheme == "rope" and kind_rope(cfg.layer_kind(layer_index))
+    """``mha_apply``'s ``rope`` for layer ``layer_index``: off (also for a
+    kind that rotates no share of the head), or ``apply_rope``'s arguments
+    for its kind (base, rotated share, YaRN; for a model of one kind, the
+    plain rotation at base 10,000)."""
+    kind = cfg.layer_kind(layer_index)
+    return cfg.position_scheme == "rope" and kind.rotary_share > 0 and kind_rope(kind)
 
 
 def _ffn_sublayer_init(key: jax.Array, cfg: ModelConfig, use_moe: bool) -> dict:
@@ -71,6 +81,7 @@ def _ffn_sublayer_init(key: jax.Array, cfg: ModelConfig, use_moe: bool) -> dict:
                 activation=cfg.ffn_activation, shared_dff=cfg.moe_shared_dff,
                 router_scale=cfg.moe_router_init_scale, out_scale=cfg.moe_out_init_scale,
                 select_bias=cfg.moe_select_bias,
+                dropless=cfg.moe_dispatch == "dropless", latent_dim=cfg.moe_latent_dim,
             )
         }
     return {

@@ -47,7 +47,13 @@ in place, the live slots alone. A latent layer (``ops/mla.py``) has a pool of
 ONE row a position (``ckv``): the step scatters the new row (its shared key
 part turned at the slot's position where the kind rotates), absorbs the
 projections into the queries and reads the pool through
-``kernels/paged_latent.py``.
+``kernels/paged_latent.py``. A state-space layer (``ops/ssd.py``) has no
+pool either: its entry is the float32 state ``ssm_state`` (N, H, P, N_s) and
+the convolution's ``ssm_conv`` rows; the step runs the projections and the
+one-token convolution in XLA and ``kernels/ssm_step.py`` over the states, in
+place, the live slots alone. A layer that is a mixer alone or a feed-forward
+part alone runs that sublayer alone (its pool entry holds nothing where it
+has no mixer).
 
 Scope guards (the gather path remains the general fallback): decoder-only
 LM configs, no model-wide ``attention_window`` (that option makes the dense
@@ -69,6 +75,7 @@ from transformer_tpu.kernels.flash_attention import paged_attention
 from transformer_tpu.kernels.kda_step import kda_step
 from transformer_tpu.kernels.kv_pool import block_row_ids, scatter_rows
 from transformer_tpu.kernels.paged_latent import paged_latent_attention
+from transformer_tpu.kernels.ssm_step import ssm_step
 from transformer_tpu.models.encoder import (
     _ffn_sublayer_apply,
     _sublayer,
@@ -90,12 +97,35 @@ from transformer_tpu.ops.mla import absorbed_output, absorbed_queries, latent_po
 from transformer_tpu.ops.nn import Params, norm_apply
 from transformer_tpu.ops.positional import apply_rope
 from transformer_tpu.ops.short_conv import short_conv_apply
+from transformer_tpu.ops.ssd import ssm_inputs, ssm_output
 
 # Key of the pool pytree (in the first expert layer's dict) under which a
 # dropless model's decode steps accumulate int32 [picks held here, experts
 # hit, rows of the most-loaded expert, tokens with a pick here, steps]: the
 # programs return (logits, pools) and nothing else.
 MOE_COUNTS = "moe_counts"
+
+
+def ssm_pool_step(
+    params: Params, h: jax.Array, old: dict[str, jax.Array], live: jax.Array,
+    groups: int, epsilon: float, interpret: bool | None = None,
+) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """One position of a state-space layer over every slot: (N, 1, M)
+    normalised input and the layer's pool entry ``{"ssm_state", "ssm_conv"}``
+    -> ((N, 1, M) output, the new entry). As ``kda``: a slot that is not
+    ``live`` reads zeros and keeps what it held."""
+    z, xs, bmat, cmat, dt, conv = ssm_inputs(
+        params, h, jnp.where(live[:, None, None], old["ssm_conv"], 0), groups
+    )
+    a = -jnp.exp(params["A_log"].astype(jnp.float32))
+    y, state = ssm_step(
+        old["ssm_state"], xs[:, 0], dt[:, 0], a, bmat[:, 0], cmat[:, 0], live, interpret=interpret,
+    )
+    new = {
+        "ssm_state": state,
+        "ssm_conv": jnp.where(live[:, None, None], conv.astype(old["ssm_conv"].dtype), old["ssm_conv"]),
+    }
+    return ssm_output(params, h, y[:, None], xs, z, groups, epsilon), new
 
 
 def check_paged_flash_config(cfg: ModelConfig) -> None:
@@ -254,11 +284,22 @@ def paged_decode_forward(
             )
             return absorbed_output(mp, ctx[:, None])
 
-        mixers = {"conv": short_conv, "kda": kda, "mla": latent_attn}
+        def state_space(h, layer=layer, pool_box=pool_box, kind=cfg.layer_kind(i)):
+            if s_q != 1:
+                raise ValueError("a state-space layer steps one position at a time")
+            out, pool_box[0] = ssm_pool_step(
+                layer["ssm"], h, pool_box[0], index > 0, kind.ssm_groups, cfg.layernorm_epsilon, interpret
+            )
+            return out
+
+        mixers = {"conv": short_conv, "kda": kda, "mla": latent_attn, "ssm": state_space}
         mixer = next((fn for key, fn in mixers.items() if key in layer), self_attn)
-        x = _sublayer(cfg, layer["ln1"], x, mixer, None, True)
+        if "ln1" in layer:
+            x = _sublayer(cfg, layer["ln1"], x, mixer, None, True)
         new_pools.append(pool_box[0])
 
+        if "ln_ffn" not in layer:
+            continue  # a mixer alone
         if "moe" in layer and cfg.moe_dispatch == "dropless":
             counts_box: list = [None]
 

@@ -480,10 +480,11 @@ def kv_buffer_keys(cache: dict[str, Any]) -> tuple[str, ...]:
     layer's entry (``ops/mla.py``) keeps ONE buffer, ``ckv``: a row a position
     that is key and value at once. An entry that holds a fixed state a
     sequence (``ops.short_conv.state_buffer_keys``: a short convolution's
-    rows, a delta-rule layer's matrix) holds no such rows."""
+    rows, a delta-rule or a state-space layer's matrix), and the entry of a
+    layer without a mixer, hold no such rows."""
     from transformer_tpu.ops.short_conv import state_buffer_keys
 
-    if state_buffer_keys(cache):
+    if state_buffer_keys(cache) or "k" not in cache and "ckv" not in cache:
         return ()
     if "ckv" in cache:
         return ("ckv",)

@@ -23,10 +23,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from transformer_tpu.ops.nn import Params, dense_apply, dense_init
 
+def _relu2(x):
+    """Squared ReLU (Primer, arXiv:2109.08668): Nemotron-H's ``relu2``."""
+    return jnp.square(jax.nn.relu(x))
+
+
 _ACTIVATIONS = {
     "relu": jax.nn.relu,
     "gelu": jax.nn.gelu,
     "silu": jax.nn.silu,
+    "relu2": _relu2,
 }
 
 # Gated variants: activation applied to the GATE branch.

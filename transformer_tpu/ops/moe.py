@@ -87,6 +87,8 @@ def moe_init(
     router_scale: float = 1.0,
     out_scale: float = 1.0,
     select_bias: bool = False,
+    dropless: bool = False,
+    latent_dim: int = 0,
 ) -> Params:
     """Router plus the experts' FFNs stacked on a leading E axis. Per-expert
     fan-in/fan-out matches ``ffn_init`` so a 1-expert MoE is
@@ -95,7 +97,11 @@ def moe_init(
     1,536 draws to compile).
 
     A gated ``activation`` (the dropless layer's experts) gives a third,
-    ``gate`` stack and no biases; ``experts_held`` (0 = all) stacks only this
+    ``gate`` stack and no biases; ``dropless`` experts of an ungated one have
+    two stacks and no biases, where the capacity layer's have biases;
+    ``latent_dim`` (dropless) gives the experts that width in place of
+    ``d_model`` and the projections ``latent_down`` (d_model -> latent) and
+    ``latent_up`` (latent -> d_model) around them; ``experts_held`` (0 = all) stacks only this
     chip's share while the router keeps its full width; ``shared_dff`` adds
     the shared expert every token takes, a plain gated FFN without biases.
     ``router_scale`` and ``out_scale`` multiply the Glorot draw of the router's
@@ -107,6 +113,7 @@ def moe_init(
 
     k_router, k_in, k_out = jax.random.split(key, 3)
     E = experts_held or num_experts
+    d_expert = latent_dim or d_model
 
     def scaled(kernel, scale):
         return kernel if scale == 1.0 else (kernel.astype(jnp.float32) * scale).astype(param_dtype)
@@ -117,16 +124,22 @@ def moe_init(
     router = glorot_uniform(k_router, (d_model, num_experts), param_dtype, d_model, num_experts)
     params = {
         "router": {"kernel": scaled(router, router_scale)},
-        "in": {"kernel": stacked(k_in, d_model, dff)},
-        "out": {"kernel": stacked(k_out, dff, d_model, out_scale)},
+        "in": {"kernel": stacked(k_in, d_expert, dff)},
+        "out": {"kernel": stacked(k_out, dff, d_expert, out_scale)},
     }
     if select_bias:
         params["router"]["bias"] = jnp.zeros((num_experts,), jnp.float32)
-    if not is_gated(activation):
+    if not is_gated(activation) and not dropless:
         params["in"]["bias"] = jnp.zeros((E, dff), param_dtype)
         params["out"]["bias"] = jnp.zeros((E, d_model), param_dtype)
         return params
-    params["gate"] = {"kernel": stacked(jax.random.fold_in(key, 3), d_model, dff)}
+    if is_gated(activation):
+        params["gate"] = {"kernel": stacked(jax.random.fold_in(key, 3), d_expert, dff)}
+    if latent_dim:
+        params["latent_down"] = {"kernel": glorot_uniform(
+            jax.random.fold_in(key, 5), (d_model, latent_dim), param_dtype, d_model, latent_dim)}
+        params["latent_up"] = {"kernel": glorot_uniform(
+            jax.random.fold_in(key, 6), (latent_dim, d_model), param_dtype, latent_dim, d_model)}
     if shared_dff:
         params["shared"] = ffn_init(
             jax.random.fold_in(key, 4), d_model, shared_dff, param_dtype,
@@ -300,17 +313,20 @@ def moe_apply_dropless(
     """(..., M) -> ((..., M), int32 [picks that landed here, experts hit,
     rows of the most-loaded expert, tokens with a pick here]).
 
-    A routed layer of gated experts that drops no token, told which experts
-    it holds: the stacks in ``params`` are experts ``expert_offset ..
+    A routed layer of bias-free experts, gated or not (``activation``), that
+    drops no token, told which experts it holds: the stacks in ``params`` are experts ``expert_offset ..
     expert_offset + E_held - 1`` of the router's ``num_experts``. Every token
     is routed over ALL experts (``_route``, in the form ``score`` names, in
     ``groups`` where given); its picks that fall on an expert
     held here are grouped by expert (a stable sort by expert id, group sizes,
-    each group padded to whole row tiles) and go through one grouped gated
-    FFN (``kernels/moe_ffn.py``) that reads only the experts hit; picks that
+    each group padded to whole row tiles) and go through one grouped FFN
+    (``kernels/moe_ffn.py``) that reads only the experts hit; picks that
     fall elsewhere add nothing (their chip adds them, in a deployment). The
     renormalised gate times ``routed_scale`` weighs each expert's OUTPUT; the
-    shared expert, where the layer has one, is added unweighted.
+    shared expert, where the layer has one, is added unweighted. Where the
+    layer has ``latent_down`` and ``latent_up``, the experts see the input
+    projected down and their weighted sum is projected back up; the router
+    and the shared expert read the input itself.
 
     ``token_mask`` (...,) bool, False = not a token (a free slot): routed
     nowhere, counted nowhere.
@@ -351,18 +367,24 @@ def moe_apply_dropless(
     source = jnp.full((tiles * tm,), T, jnp.int32).at[dest_sorted].set(
         (order // k).astype(jnp.int32), mode="drop"
     )
-    x_pad = jnp.concatenate([xt, jnp.zeros((1, m), xt.dtype)])[source]
+    xe = xt
+    if "latent_down" in params:
+        xe = jnp.einsum("tm,ml->tl", xt, params["latent_down"]["kernel"].astype(xt.dtype))
+    x_pad = jnp.concatenate([xe, jnp.zeros((1, xe.shape[-1]), xt.dtype)])[source]
     out = moe_expert_ffn(
-        x_pad, params["gate"]["kernel"].astype(xt.dtype), params["in"]["kernel"].astype(xt.dtype),
+        x_pad, params["gate"]["kernel"].astype(xt.dtype) if "gate" in params else None,
+        params["in"]["kernel"].astype(xt.dtype),
         params["out"]["kernel"].astype(xt.dtype), tile_group, live_tiles,
         tile_rows=tm, activation=activation, interpret=interpret,
     )
     # Back to (token, pick) order: each pick reads its expert's row; a pick
     # not held here reads row 0, which nobody may have written, and drops it.
     dest = jnp.zeros((picks,), jnp.int32).at[order].set(dest_sorted.astype(jnp.int32))
-    rows = out[jnp.where(here.reshape(-1), dest, 0)].reshape(T, k, m)
+    rows = out[jnp.where(here.reshape(-1), dest, 0)].reshape(T, k, xe.shape[-1])
     rows = jnp.where(here[..., None], rows.astype(jnp.float32), 0.0)
     y = jnp.einsum("tk,tkm->tm", weights, rows).astype(xt.dtype)
+    if "latent_up" in params:
+        y = jnp.einsum("tl,lm->tm", y, params["latent_up"]["kernel"].astype(xt.dtype))
     if "shared" in params:
         y = y + ffn_apply(params["shared"], xt, activation)
     # A grouped product's time follows its longest group: the third count.

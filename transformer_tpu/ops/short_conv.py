@@ -51,7 +51,7 @@ def init_conv_state(batch: int, d_model: int, kernel: int, dtype=jnp.bfloat16) -
 
 
 # Every key that holds a fixed state a sequence, whatever the layer's kind.
-_STATE_KEYS = ("conv_state", "kda_state", "kda_conv")
+_STATE_KEYS = ("conv_state", "kda_state", "kda_conv", "ssm_state", "ssm_conv")
 
 
 def state_buffer_keys(cache: dict) -> tuple[str, ...]:

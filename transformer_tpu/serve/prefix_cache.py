@@ -236,7 +236,8 @@ class PrefixCache:
                 "prefix cache cannot serve a model with a stateful layer: a "
                 "cached prefix holds rows a position and no snapshot of the "
                 "state a slot keeps beside them (a short convolution's "
-                "inputs, a delta-rule matrix) at the prefix's end"
+                "inputs, a delta-rule matrix, a state-space layer's state) at "
+                "the prefix's end"
             )
         if block_tokens < 1:
             raise ValueError(f"block_tokens must be >= 1, got {block_tokens}")

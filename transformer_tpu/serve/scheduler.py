@@ -128,7 +128,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from transformer_tpu.config import PAD_ID, ModelConfig
+from transformer_tpu.config import PAD_ID, STATE_MIXERS, ModelConfig
 from transformer_tpu.data.seeding import keyed_rng
 from transformer_tpu.models.decoder import init_decoder_caches, init_layer_state
 from transformer_tpu.models.encoder import layer_uses_moe
@@ -329,16 +329,20 @@ def init_paged_pools(
     of K and V rows for each attention layer, one of latent rows for each
     latent layer (``ops/mla.py``: a row of ``latent_width`` lanes stored once).
     A fixed state a slot, and no blocks at all, for each short-convolution
-    layer (``{"conv_state": (num_slots, L - 1, d_model)}``) and each delta-rule
+    layer (``{"conv_state": (num_slots, L - 1, d_model)}``), each delta-rule
     layer (``ops/kda.py``: ``kda_state`` (num_slots, H, D, D) float32 and the
-    three convolutions' ``kda_conv`` rows). The pool's bytes a token are the
-    attention and latent layers'."""
+    three convolutions' ``kda_conv`` rows) and each state-space layer
+    (``ops/ssd.py``: ``ssm_state`` (num_slots, H, P, N) float32 and the
+    convolution's ``ssm_conv`` rows). Nothing for a layer without a mixer.
+    The pool's bytes a token are the attention and latent layers'."""
     from transformer_tpu.ops.attention import init_block_pool
     from transformer_tpu.ops.mla import init_latent_pool
 
     def one(i):
         kind = cfg.layer_kind(i)
-        if kind.mixer in ("conv", "kda"):
+        if kind.mixer == "none":
+            return {}
+        if kind.mixer in STATE_MIXERS:
             return init_layer_state(cfg, i, num_slots)
         if kind.mixer == "mla":
             return init_latent_pool(
@@ -986,29 +990,33 @@ class ContinuousScheduler:
             )
         if cfg.state_layers:
             # A layer whose state is not rows a position (a short
-            # convolution's last gated inputs, a delta-rule layer's matrix)
-            # has one state a slot, overwritten by every token: nothing below
-            # can bring an earlier one back.
+            # convolution's last gated inputs, a delta-rule layer's matrix,
+            # a state-space layer's) has one state a slot, overwritten by
+            # every token: nothing below can bring an earlier one back.
             if speculate_k:
                 raise ValueError(
                     "speculative decoding cannot serve a model with a stateful "
                     "layer: a rejected draft's tokens have already moved the "
                     "state a slot keeps in place of rows a position (a short "
-                    "convolution's inputs, a delta-rule matrix), and it cannot "
-                    "be rolled back; serve this config with speculate_k=0"
+                    "convolution's inputs, a delta-rule matrix, a state-space "
+                    "layer's state), and it cannot be rolled back; serve this "
+                    "config with speculate_k=0"
                 )
             if prefix_cache is not None:
                 raise ValueError(
                     "prefix cache cannot serve a model with a stateful layer: a "
                     "cached prefix holds rows a position and no snapshot of "
-                    "the state a slot keeps beside them at the prefix's end; "
-                    "serve this config without --prefix_cache_mb"
+                    "the state a slot keeps beside them at the prefix's end "
+                    "(a short convolution's inputs, a delta-rule matrix, a "
+                    "state-space layer's state); serve this config without "
+                    "--prefix_cache_mb"
                 )
             if mesh is not None:
                 raise ValueError(
                     "a sharded replica (--mesh) places the pool by its KV "
-                    "layout, and a model with a stateful layer has a state "
-                    "a slot beside it; serve this config on one device"
+                    "layout, and a model with a stateful layer (a short "
+                    "convolution, a delta-rule or a state-space layer) has a "
+                    "state a slot beside it; serve this config on one device"
                 )
         self.params, self.cfg, self.tok = params, cfg, tokenizer
         # ---- live-weights control plane (serve/upgrade.py) ----------------
@@ -1121,6 +1129,10 @@ class ContinuousScheduler:
             (k.window for k in cfg.attention_kinds if k.window), 0
         )
         self._state_layers = len(cfg.state_layers)
+        # A state-space layer's prefill scans its prompt in chunks of this
+        # many positions (``ops/ssd.py``); 0 = no such layer.
+        ssm = [cfg.layer_kind(i) for i in cfg.state_layers if cfg.layer_kind(i).mixer == "ssm"]
+        self._ssm_layers, self._ssm_chunk = len(ssm), ssm[0].ssm_chunk if ssm else 0
         self._state_bytes = sum(
             x.size * x.dtype.itemsize
             for i in cfg.state_layers
@@ -1374,6 +1386,11 @@ class ContinuousScheduler:
                 self._m_prefix_evicted = reg.counter(
                     "serve_prefix_evicted_blocks_total",
                     "prefix-cache KV blocks evicted under the byte budget")
+            if self._ssm_layers:
+                self._m_ssm_chunks = reg.counter(
+                    "serve_ssm_chunks_scanned_total",
+                    "chunks the prefills' state-space scans walked, "
+                    "summed over the state-space layers")
             if self.paged:
                 self._m_pool_used = reg.gauge(
                     "serve_kv_pool_used_blocks",
@@ -2485,6 +2502,18 @@ class ContinuousScheduler:
             slot=slot, prefix_hit_tokens=st.prefix_hit, prompt_tokens=L,
             prefill_tokens=n_suffix,
         )
+        if self._ssm_layers:
+            # Chunks each state-space layer's scan walked: every prefill
+            # forward (``prefill_chunk`` positions at most) padded to whole
+            # chunks.
+            piece = chunk if chunk > 0 else max(n_suffix, 1)
+            walked = sum(
+                -(-min(piece, n_suffix - off) // self._ssm_chunk)
+                for off in range(0, n_suffix, piece)
+            )
+            p.span_admit.set(ssm_chunks=walked)
+            if self._tel is not None:
+                self._m_ssm_chunks.inc(walked * self._ssm_layers)
         if deadline is not None and time.perf_counter() >= deadline:
             # Prefill-boundary deadline check: the prompt ingest alone
             # consumed the budget — answer now instead of decoding tokens
