@@ -18,7 +18,6 @@ from perfbench import flops_bytes_kimi
 from perfbench.reference import kimi_linear_lm
 from transformer_tpu.config import AttentionKind, ModelConfig, config_from_json, config_to_json
 from transformer_tpu.kernels.kda_step import kda_step
-from transformer_tpu.kernels.paged_latent import paged_latent_attention
 from transformer_tpu.models.transformer import transformer_apply, transformer_init
 from transformer_tpu.ops.kda import CHUNK, init_kda_state, kda_apply, kda_init, kda_inputs, kda_output, kda_recurrent_step
 from transformer_tpu.ops.mla import init_latent_cache, latent_width, mla_apply, mla_init
@@ -274,25 +273,6 @@ def test_mla_blocks_its_query_rows_where_the_chunk_is_long(monkeypatch):
     monkeypatch.setattr(mla_ops, "_QUERY_BLOCK", 16)
     blocked, _ = mla_ops.mla_apply(p, h, epsilon=EPS)
     np.testing.assert_allclose(blocked, whole, atol=2e-6)
-
-
-@pytest.mark.parametrize("lengths", [[1, 37, 96], [16, 17, 33]])
-def test_paged_latent_attention_reads_each_row_once_through_the_table(lengths):
-    n, heads, width, rank, block, nmax = 3, 4, 256, 128, 16, 6
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    pool = jax.random.normal(ks[0], (40, block, width))
-    q = jax.random.normal(ks[1], (n, heads, width)) * 0.2
-    table = jax.random.permutation(ks[2], jnp.arange(1, 40))[: n * nmax].reshape(n, nmax)
-    lengths = jnp.asarray(lengths)
-    got = paged_latent_attention(q, pool, table, lengths, rank=rank)
-    rows = pool[table].reshape(n, nmax * block, width)
-    scores = jnp.einsum("nhw,nlw->nhl", q, rows, precision="highest")
-    scores = jnp.where(jnp.arange(nmax * block)[None, None] < lengths[:, None, None], scores, -1e9)
-    want = jnp.einsum("nhl,nlr->nhr", jax.nn.softmax(scores, -1), rows[..., :rank], precision="highest")
-    np.testing.assert_allclose(got, want, atol=2e-6)
-    # Entries past a slot's length are never dereferenced: hostile ids there change nothing.
-    hostile = jnp.where(jnp.arange(nmax)[None, :] * block >= lengths[:, None], 10**6, table)
-    np.testing.assert_array_equal(paged_latent_attention(q, pool, hostile, lengths, rank=rank), got)
 
 
 # ------------------------------------------------ the model, the reference
