@@ -174,8 +174,11 @@ def test_a_sequence_in_two_calls_is_the_sequence_in_one(params):
     assert set(init_ssm_state(3, 4, 16, 2, 16, 4)) == set(end) and end["ssm_conv"].shape == (2, 3, 128)
 
 
-def test_the_step_kernel_is_the_recurrence_and_keeps_a_free_slots_state():
-    x, dt, a, bmat, cmat, s0 = _ssm_inputs(2, 3, 1, heads=8, p=16, groups=2, n=128)
+@pytest.mark.parametrize("heads,p", [(8, 16), (32, 64)], ids=["toy", "nemotron_group"])
+def test_the_step_kernel_is_the_recurrence_and_keeps_a_free_slots_state(heads, p):
+    """Two groups of state 128 wide: 4 heads of 16 a group (a read-out row of
+    64 lanes), and Nemotron's group, 16 heads of 64 (a row of 1,024 lanes)."""
+    x, dt, a, bmat, cmat, s0 = _ssm_inputs(2, 3, 1, heads=heads, p=p, groups=2, n=128)
     live = jnp.array([True, False, True])
     y, state = ssm_step(s0, x[:, 0], dt[:, 0], a, bmat[:, 0], cmat[:, 0], live)
     with jax.default_matmul_precision("highest"):
@@ -184,6 +187,67 @@ def test_the_step_kernel_is_the_recurrence_and_keeps_a_free_slots_state():
     _close(state[::2], want_s[::2], 1e-5)
     np.testing.assert_array_equal(np.asarray(state[1]), np.asarray(s0[1]))
     np.testing.assert_array_equal(np.asarray(y[1]), 0.0)
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it (a jitted
+    call's, the kernel's body, its branches)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _equations(sub)
+
+
+def _loose_dots(jaxpr) -> list[str]:
+    """The products in ``jaxpr`` that are not float32 at HIGHEST precision on
+    both operands."""
+    loose = []
+    for eqn in _equations(jaxpr):
+        if eqn.primitive.name == "dot_general":
+            precision = eqn.params["precision"]
+            if not isinstance(precision, tuple):
+                precision = (precision, precision)
+            exact = all(p == jax.lax.Precision.HIGHEST for p in precision)
+            if not exact or eqn.params["preferred_element_type"] != jnp.float32 or eqn.outvars[0].aval.dtype != jnp.float32:
+                loose.append(str(eqn))
+    return loose
+
+
+def test_every_product_of_the_step_kernel_is_float32_at_highest_precision():
+    """The state and its recurrence are float32 as configured: a read-out on
+    the MXU in one bfloat16 pass (DEFAULT) or three (HIGH) is another result."""
+    x, dt, a, bmat, cmat, s0 = _ssm_inputs(2, 3, 1, heads=32, p=64, groups=2, n=128)
+    jaxpr = jax.make_jaxpr(ssm_step)(s0, x[:, 0], dt[:, 0], a, bmat[:, 0], cmat[:, 0], jnp.ones((3,), bool))
+    assert any(e.primitive.name == "pallas_call" for e in _equations(jaxpr.jaxpr))
+    assert _loose_dots(jaxpr.jaxpr) == []
+
+
+def _kernel_reading_out(precision, out):
+    """The jaxpr of a kernel whose read-out, inside a branch, is one product
+    of ``precision`` and result type ``out``."""
+    from jax.experimental import pallas as pl
+
+    def kernel(c_ref, s_ref, y_ref):
+        @pl.when(pl.program_id(0) >= 0)
+        def _read():
+            y = jax.lax.dot_general(c_ref[...], s_ref[...], (((1,), (1,)), ((), ())), precision=precision,
+                                    preferred_element_type=out)
+            y_ref[...] = y.astype(jnp.float32)
+
+    call = pl.pallas_call(kernel, grid=(1,), out_shape=jax.ShapeDtypeStruct((8, 1024), jnp.float32), interpret=True)
+    return jax.make_jaxpr(call)(jnp.ones((8, 128)), jnp.ones((1024, 128))).jaxpr
+
+
+@pytest.mark.parametrize("precision,out", [
+    (None, jnp.float32), (jax.lax.Precision.DEFAULT, jnp.float32), (jax.lax.Precision.HIGH, jnp.float32),
+    (jax.lax.Precision.HIGHEST, jnp.bfloat16),
+], ids=["unset", "default", "high", "bfloat16_result"])
+def test_the_precision_guard_refuses_a_loose_product(precision, out):
+    assert len(_loose_dots(_kernel_reading_out(precision, out))) == 1
+    assert _loose_dots(_kernel_reading_out(jax.lax.Precision.HIGHEST, jnp.float32)) == []
 
 
 # ------------------------------------------------------- the whole model

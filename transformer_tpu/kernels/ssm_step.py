@@ -14,14 +14,20 @@ fetches slot ``n + 1`` and writes slot ``n - 1`` back while slot ``n`` is
 updated.
 
 The products are on the VPU, none on the MXU: the decay and ``dt x`` are
-columns (a head's channels down) that multiply the state and the row ``B_g``,
-and ``S C_g`` is a multiply by a row and a sum across the lanes. The columns
-cannot be sliced out of a row on the chip, so the wrapper hands them over
-already transposed, ``dt x`` and the decay of all heads side by side as one
-(P, 2 H) matrix a slot, and the kernel takes head ``h``'s by a static lane
-slice in a loop unrolled over the heads, as ``kernels/kda_step.py`` does. Each
-head's ``y`` column is laid into lane ``h`` of a (P, H) block by a mask, so
-the output is stored once, whole.
+columns (a head's channels down) that multiply the state and the row ``B_g``.
+The columns cannot be sliced out of a row on the chip, so the wrapper hands
+them over already transposed, ``dt x`` and the decay of all heads side by side
+as one (P, 2 H) matrix a slot, and the kernel takes head ``h``'s by a static
+lane slice in a loop unrolled over the heads, as ``kernels/kda_step.py`` does.
+
+The read-out ``S C_g`` is one reduction a group. The group's heads share
+``C_g``, so their new states, read back as one (per_group P, N) matrix (the
+leading axes merge for free: P is a whole number of sublane tiles), times the
+row ``C_g`` is transposed on the XLU to (N, per_group P) and summed down the
+sublanes: adds across vregs, as ``kda_step`` reduces, and one lane-dense row
+of per_group P values (``y`` of head ``g per_group + j``, channel ``p`` at
+lane ``j P + p``; 1,024 lanes at Nemotron's 16 heads of 64) stored whole into
+a (G, per_group P) block. The wrapper reshapes it to (H, P) for free.
 
 A slot that is not live (a free slot: position 0, fed PAD) is written back as
 it was and reads out zeros: a freed slot's state is never read by the next
@@ -39,22 +45,24 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(live_ref, cols_ref, b_ref, c_ref, s_ref, y_ref, s_out_ref, *, heads: int, per_group: int):
+def _kernel(live_ref, cols_ref, b_ref, c_ref, s_ref, y_ref, s_out_ref, *, heads: int, groups: int):
     n = pl.program_id(0)
+    per_group = heads // groups
+    _, _, p, n_state = s_ref.shape
 
     @pl.when(live_ref[n] > 0)
     def _update():
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, heads), 1)
-        y = jnp.zeros(y_ref.shape[1:], jnp.float32)  # (P, H)
-        for h in range(heads):
-            g = h // per_group
-            dtx = cols_ref[0, :, h : h + 1]  # (P, 1)
-            decay = cols_ref[0, :, heads + h : heads + h + 1]  # (P, 1)
-            new = s_ref[0, h] * decay + dtx * b_ref[0, g : g + 1, :]
-            s_out_ref[0, h] = new
-            read = jnp.sum(new * c_ref[0, g : g + 1, :], axis=1, keepdims=True)  # (P, 1)
-            y = y + jnp.where(lanes == h, read, 0.0)
-        y_ref[0] = y
+        for g in range(groups):
+            first = g * per_group
+            for h in range(first, first + per_group):
+                dtx = cols_ref[0, :, h : h + 1]  # (P, 1)
+                decay = cols_ref[0, :, heads + h : heads + h + 1]  # (P, 1)
+                s_out_ref[0, h] = s_ref[0, h] * decay + dtx * b_ref[0, g : g + 1, :]
+            # The read-out a group: summed down the sublanes of the transposed
+            # product, so that it comes out as one lane-dense row.
+            new = s_out_ref[0, first : first + per_group].reshape(per_group * p, n_state)
+            read = (new * c_ref[0, g : g + 1, :]).T  # (N, per_group P)
+            y_ref[0, g : g + 1, :] = jnp.sum(read, axis=0, keepdims=True)
 
     @pl.when(live_ref[n] <= 0)
     def _keep():
@@ -90,8 +98,9 @@ def ssm_step(
     decay = jnp.broadcast_to(jnp.exp(dt * a.astype(f32))[:, None, :], (n, p, heads))
     cols = jnp.concatenate([dtx, decay], axis=2)
 
+    row = heads // groups * p  # a group's read-out: its heads' channels, head by head
     y, new_state = pl.pallas_call(
-        functools.partial(_kernel, heads=heads, per_group=heads // groups),
+        functools.partial(_kernel, heads=heads, groups=groups),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n,),
@@ -102,12 +111,12 @@ def ssm_step(
                 pl.BlockSpec((1, heads, p, n_state), lambda i, *_: (i, 0, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, p, heads), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((1, groups, row), lambda i, *_: (i, 0, 0)),
                 pl.BlockSpec((1, heads, p, n_state), lambda i, *_: (i, 0, 0, 0)),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((n, p, heads), f32),
+            jax.ShapeDtypeStruct((n, groups, row), f32),
             jax.ShapeDtypeStruct(state.shape, f32),
         ],
         # The state goes in as operand 4 (after the prefetched ``live``).
@@ -119,4 +128,4 @@ def ssm_step(
         interpret=bool(interpret),
         name="ssm_step",
     )(live.astype(jnp.int32), cols, bmat.astype(f32), cmat.astype(f32), state.astype(f32))
-    return jnp.swapaxes(y, 1, 2), new_state
+    return y.reshape(n, heads, p), new_state
